@@ -2,16 +2,19 @@
 
 Modes:
   python bench_1p3b.py cpu-mesh   — full GPT-3 1.3B hybrid (dp2 x mp2 x pp2,
-      ZeRO stage-2 over sdp where factored) ONE step on the 8-device virtual
-      CPU mesh at full layer/hidden dims, tiny batch: proves the sharded
-      compile + memory plan without TPU hardware.
+      ZeRO stage-2 over sdp where factored) ONE step on an 8-device mesh at
+      full layer/hidden dims, tiny batch: proves the sharded compile + memory
+      plan without TPU hardware. Run it with ``JAX_PLATFORMS=cpu
+      XLA_FLAGS=--xla_force_host_platform_device_count=8``; the platform is
+      never changed in code.
   python bench_1p3b.py tpu        — single real chip: 1.3B with selective
       remat + grad accumulation + bf16 AMP O2, measured tokens/sec/chip.
   python bench_1p3b.py tpu-ernie  — ERNIE-3.0-style hybrid config #5 proxy on
       one chip (same trunk machinery; mp/pp degrees are mesh-bound, so the
       single-chip number is the per-chip throughput of the dp slice).
 
-Each mode prints one JSON line.
+Each mode prints one JSON line that names the device it ran on; the ``tpu``
+modes fail without one.
 """
 from __future__ import annotations
 
@@ -23,13 +26,6 @@ import numpy as np
 
 
 def _cpu_mesh_step():
-    import os
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-
     import paddle_tpu as paddle
     from paddle_tpu.distributed import fleet
     from paddle_tpu.distributed.strategy import DistributedStrategy
@@ -56,16 +52,17 @@ def _cpu_mesh_step():
         "metric": "gpt3_1p3b_hybrid_cpu_mesh_step", "params": n_params,
         "mesh": "sdp2xmp2xpp2+zero2", "loss": round(loss, 4),
         "step_wall_s": round(time.time() - t0, 1), "ok": bool(np.isfinite(loss)),
+        "device": paddle.device.describe(),
     }))
 
 
 def _tpu_run(ernie=False):
-    import jax
-
     import paddle_tpu as paddle
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining, GPTPretrainingCriterion
 
+    if not paddle.device.is_tpu():
+        raise SystemExit(f"bench_1p3b: the tpu modes measure a chip; this is {paddle.device.describe()}")
     paddle.seed(0)
     rng = np.random.default_rng(0)
     if ernie:
@@ -120,7 +117,7 @@ def _tpu_run(ernie=False):
     print(json.dumps({
         "metric": name, "params": n_params,
         "value": round(batch * seq * iters / dt, 1), "unit": "tokens/sec/chip",
-        "config": config,
+        "config": config, "device": paddle.device.describe(),
     }))
 
 

@@ -8,8 +8,10 @@ measures the other configs on demand:
     python bench_suite.py bert             # BERT-base MLM tokens/sec (AMP O2)
     python bench_suite.py decode [batch]   # GPT-medium generate() tokens/sec
 
-Each subcommand prints one JSON line. Reference analog: the external
-benchmark suite cloned by tools/ci_model_benchmark.sh:50.
+Each subcommand prints one JSON line that names the device it ran on
+(whatever ``JAX_PLATFORMS`` gives it; sizes never change with the device).
+Reference analog: the external benchmark suite cloned by
+tools/ci_model_benchmark.sh:50.
 """
 from __future__ import annotations
 
@@ -136,6 +138,9 @@ def main():
         out = bench_decode(arg or 8)
     else:
         raise SystemExit(f"unknown benchmark {which!r}")
+    import paddle_tpu as paddle
+
+    out["device"] = paddle.device.describe()
     print(json.dumps(out))
 
 

@@ -75,6 +75,15 @@ class Fleet:
     def mesh(self):
         return self._hcg.mesh if self._hcg else None
 
+    @property
+    def multi_device_mesh(self):
+        """The fleet mesh when programs are being partitioned over it (more
+        than one device), else None. Pallas kernels ask: GSPMD cannot
+        partition a Mosaic kernel, so under such a mesh a kernel runs per
+        shard in a shard_map or leaves the call to its XLA composite."""
+        mesh = self.mesh
+        return mesh if mesh is not None and mesh.size > 1 else None
+
     # -- model/optimizer wrappers (paddle API parity) ----------------------
     def distributed_model(self, model):
         """Parity: fleet_base.py:946. Under GSPMD no wrapper is needed —

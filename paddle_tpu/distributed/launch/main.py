@@ -14,6 +14,9 @@ import sys
 import time
 from typing import List, Optional
 
+from ...device import chip_env, place_on_chips
+from ...framework.flags import ensure_compile_cache, flag
+
 
 class WorkerProc:
     def __init__(self, rank: int, proc: subprocess.Popen, log_path: Optional[str]):
@@ -51,10 +54,20 @@ class CollectiveController:
             "PADDLE_LOCAL_RANK": str(local_rank),
             "PADDLE_NNODES": str(nnodes),
             "FLAGS_selected_devices": str(local_rank),
+            "FLAGS_compile_cache_dir": flag("FLAGS_compile_cache_dir"),
         })
-        if a.devices:
-            env["CUDA_VISIBLE_DEVICES"] = a.devices  # accepted for API parity
+        if a.nproc_per_node > 1 and place_on_chips(a.nproc_per_node, "launch"):
+            # several workers on one TPU host: each is bound to the chip of
+            # its local rank (--devices picks which chips), and together they
+            # form one job; the port map leaves <master>..+2 to the
+            # coordinator, the bootstrap store and the membership registry
+            port = int(a.master.rsplit(":", 1)[1]) + 16
+            env.update(chip_env(self._chip(local_rank), a.nproc_per_node, port))
         return env
+
+    def _chip(self, local_rank: int) -> int:
+        devices = self.ctx.args.devices
+        return int(devices.split(",")[local_rank]) if devices else local_rank
 
     def spawn(self, nnodes=None, node_rank=None):
         a = self.ctx.args
@@ -243,6 +256,7 @@ class ServeController(CollectiveController):
 
         a = self.ctx.args
         base = (a.rank if node_rank is None else node_rank) * a.nproc_per_node
+        bind = place_on_chips(a.nproc_per_node, "launch --serve")
         self.procs = []
         for i in range(a.nproc_per_node):
             rid = base + i
@@ -251,9 +265,11 @@ class ServeController(CollectiveController):
             spec.setdefault("jax_config", current_jax_config())  # noqa: PTA104 (host-side, never traced)
             spec.update({"rid": rid, "endpoint": a.master})  # noqa: PTA104 (host-side, never traced)
             # trainer id 0 is the serving front (the attach() parent);
-            # replicas take 1..N so trace/span id streams decorrelate
+            # replicas take 1..N so trace/span id streams decorrelate; on a
+            # TPU host each replica is bound to the chip of its local rank
             env = child_env({SPEC_ENV: json.dumps(spec),
-                             "PADDLE_TRAINER_ID": str(rid + 1)})
+                             "PADDLE_TRAINER_ID": str(rid + 1),
+                             **(chip_env(self._chip(i)) if bind else {})})
             log_path = None
             stdout = None
             if a.log_dir:
@@ -322,7 +338,7 @@ def _parser():
     p.add_argument("--rank", type=int, default=int(os.environ.get("PADDLE_NODE_RANK", "0")), help="this node's rank")
     p.add_argument("--master", type=str, default=os.environ.get("PADDLE_MASTER", "127.0.0.1:49175"), help="coordinator host:port (rank-0 node)")
     p.add_argument("--log_dir", type=str, default=None, help="per-worker log directory")
-    p.add_argument("--devices", "--gpus", type=str, default=None, help="device selection (parity flag)")
+    p.add_argument("--devices", "--gpus", type=str, default=None, help="comma list of local TPU chips, one per worker slot (default: chip = local rank)")
     p.add_argument("--elastic_retries", type=int, default=0, help="relaunch the collective up to N times on worker failure")
     p.add_argument("--elastic_np", type=str, default=os.environ.get("PADDLE_ELASTIC_NP"), help="elastic node range 'min:max' (or 'n'): membership-managed launch with rescaling")
     p.add_argument("--elastic_timeout", type=float, default=3.0, help="heartbeat staleness (s) before a node is considered gone")
@@ -335,6 +351,7 @@ def _parser():
 def launch(argv=None):
     argv = list(sys.argv[1:] if argv is None else argv)
     ns, script_args = _parser().parse_known_args(argv)
+    ensure_compile_cache()  # workers and replicas inherit the directory
     if ns.serve:
         return _serve(ns, script_args)
     ctx = LaunchContext(ns, script_args)

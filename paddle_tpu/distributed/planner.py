@@ -429,12 +429,12 @@ def _model_fingerprint(model) -> str:
 
 
 def _cache_path(key: str):
-    from ..framework.flags import flag
+    from ..framework.flags import compile_cache_dir
 
-    d = flag("FLAGS_compile_cache_dir")
+    d = compile_cache_dir()
     if not d:
         return None
-    return os.path.join(str(d), "planner", f"{key}.json")
+    return os.path.join(d, "planner", f"{key}.json")
 
 
 def _cache_key(model, n_devices, abstract_batch, template_names, stages,

@@ -1,4 +1,3 @@
-from . import jax_compat  # noqa: F401  (must run before anything touches jax.*)
 from .autograd import backward, enable_grad, is_grad_enabled, no_grad, set_grad_enabled
 from .core import Tensor, get_device, is_compiled_with_tpu, primitive, set_device, unwrap
 from .dtype import convert_dtype, get_default_dtype, set_default_dtype, to_jax_dtype

@@ -135,14 +135,6 @@ def load_native() -> ctypes.CDLL:
             raise RuntimeError(f"native library unavailable: {_lib_err}") from e
 
 
-def native_available() -> bool:
-    try:
-        load_native()
-        return True
-    except RuntimeError:  # pragma: no cover
-        return False
-
-
 def _take_buffer(lib: ctypes.CDLL, ptr: ctypes.c_void_p, length: int) -> bytes:
     data = ctypes.string_at(ptr, length)
     lib.pt_buffer_free(ptr)

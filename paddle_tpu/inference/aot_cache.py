@@ -1,12 +1,13 @@
 """Persist AOT-compiled executables across process restarts.
 
 A restarted engine pays the full prefill/decode compile family again before
-it can serve its first token — the ROADMAP restart-latency leftover. When
-``FLAGS_compile_cache_dir`` is set, every serving program the engine
+it can serve its first token — the ROADMAP restart-latency leftover. When a
+compile-cache directory is in force (``JAX_COMPILATION_CACHE_DIR``, else
+``FLAGS_compile_cache_dir``), every serving program the engine
 compiles is also serialized (``jax.experimental.serialize_executable`` —
 the raw PJRT executable plus its call trees) under
 ``<dir>/serving/<key>.aotc``, keyed on the (kind, argument avals, engine
-fingerprint, jax version, backend) specialization. A fresh engine with the
+fingerprint, jax version, device) specialization. A fresh engine with the
 same specialization loads the executable instead of recompiling: restart
 ``time_to_first_token`` drops to deserialize+dispatch cost
 (bench_serve.py reports it as ``restart_ttft``).
@@ -25,45 +26,74 @@ persistence must never break dispatch. Writes are atomic
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
+import zlib
 from pathlib import Path
 from typing import Any, Optional
 
 __all__ = ["cache_dir", "make_key", "load", "store"]
 
-_FORMAT = "aotc-v1"
+_FORMAT = "aotc-v2"  # v2: zlib-compressed entries
 
 
 def cache_dir(scope: str = "serving") -> Optional[Path]:
     """The executable cache directory for ``scope`` (serving / train_step /
-    executor / ...), or None when ``FLAGS_compile_cache_dir`` is unset."""
-    from ..framework.flags import flag
+    executor / ...) under the compile-cache directory in force
+    (``framework.flags.compile_cache_dir``), or None when there is none."""
+    from ..framework.flags import compile_cache_dir
 
-    d = flag("FLAGS_compile_cache_dir")
+    d = compile_cache_dir()
     if not d:
         return None
-    return Path(str(d)) / scope
+    return Path(d) / scope
 
 
-def make_key(kind: str, sig: Any, fingerprint: str) -> str:
+@functools.lru_cache(maxsize=1)
+def _code_version() -> str:
+    """Hash of the package's own source files. The serving keys name a
+    program by its kind, shapes and config, not by its text, and the store
+    outlives the process — and, under a cache directory placed from
+    outside, the checkout: without this an engine would load, and run, the
+    executable an older version of the code compiled under the same key."""
+    h = hashlib.sha256()
+    root = Path(__file__).resolve().parent.parent
+    for path in sorted(root.rglob("*.py")):
+        h.update(str(path.relative_to(root)).encode())
+        h.update(path.read_bytes())
+    return h.hexdigest()[:16]
+
+
+def _device(device=None):
+    import jax
+
+    return device if device is not None else jax.local_devices()[0]
+
+
+def make_key(kind: str, sig: Any, fingerprint: str, device=None) -> str:
     """Stable content key for one compiled specialization: the program kind
     (prefill / decode / decode_xD / spec_decode / chunk / ...), the argument
     avals, the engine's config fingerprint (model dims, sampling config,
     dtypes, kv-cache dtype, and the speculative draft config + spec_k — the
-    host scalars baked into the trace), and the jax version + backend the
-    executable was built for."""
+    host scalars baked into the trace), the jax version, this package's
+    code version, and the device the executable was built for (default: the
+    first local device) — an executable is assigned to its device at
+    compile time and loads only there, so each device keeps its own
+    entries."""
     import jax
 
-    payload = repr((_FORMAT, kind, sig, fingerprint, jax.__version__,
-                    jax.default_backend()))
+    d = _device(device)
+    payload = repr((_FORMAT, kind, sig, fingerprint, jax.__version__, _code_version(),
+                    d.platform, d.device_kind, d.id))
     return hashlib.sha256(payload.encode()).hexdigest()[:32]
 
 
-def load(key: str, scope: str = "serving"):
-    """Deserialize + load the executable stored under ``key``; None on any
-    miss or failure (caller compiles normally)."""
+def load(key: str, scope: str = "serving", device=None):
+    """Deserialize + load the executable stored under ``key`` onto
+    ``device`` (the one ``make_key`` was given); None on any miss or
+    failure (caller compiles normally)."""
     d = cache_dir(scope)
     if d is None:
         return None
@@ -73,8 +103,12 @@ def load(key: str, scope: str = "serving"):
     try:
         from jax.experimental.serialize_executable import deserialize_and_load
 
-        payload, in_tree, out_tree = pickle.loads(path.read_bytes())
-        return deserialize_and_load(payload, in_tree, out_tree)
+        dev = _device(device)
+        payload, in_tree, out_tree = pickle.loads(zlib.decompress(path.read_bytes()))
+        # without execution_devices the executable is loaded for every
+        # device of the backend and refuses one-device arguments
+        return deserialize_and_load(payload, in_tree, out_tree,
+                                    backend=dev.client, execution_devices=[dev])
     except Exception:
         return None
 
@@ -93,7 +127,9 @@ def store(key: str, compiled, scope: str = "serving") -> bool:
         payload, in_tree, out_tree = serialize(compiled)
         d.mkdir(parents=True, exist_ok=True)
         tmp = d / f".{key}.{os.getpid()}.tmp"
-        tmp.write_bytes(pickle.dumps((payload, in_tree, out_tree)))
+        # level 1: a serialized TPU executable is hundreds of MB raw and
+        # mostly compressible; the cache directory is often size-capped
+        tmp.write_bytes(zlib.compress(pickle.dumps((payload, in_tree, out_tree)), 1))
         os.replace(tmp, d / f"{key}.aotc")
         return True
     except Exception:

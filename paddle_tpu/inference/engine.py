@@ -40,6 +40,8 @@ fused decoder is the compiled step program and the "predictor" is the
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -72,6 +74,17 @@ def _dequant(entry, dt):
     if isinstance(entry, dict):
         return (entry["q"].astype(jnp.float32) * entry["s"]).astype(dt)
     return entry
+
+
+def _placed(method):
+    """Run an engine method with the engine's device as JAX's default, so
+    the host-side scalars and index arrays it builds land beside the cache
+    they are dispatched with (no-op for an engine on the default device)."""
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        with self._device_scope():
+            return method(self, *args, **kwargs)
+    return wrapped
 
 
 class _PrefillJob:
@@ -145,6 +158,12 @@ class DecodeEngine:
       f32 cache within quantization tolerance (the engine family itself
       stays bitwise-reproducible run to run).
 
+    ``device`` places the engine — its copy of the weights, the KV cache,
+    the slot state and every compiled program — on one ``jax.Device``
+    (default: JAX's default device). ``ServingFleet`` gives each replica a
+    local device of its own, which is what makes four replicas on a
+    four-chip host use four chips.
+
     Sampling config (``do_sample``/``temperature``/``top_k``/``top_p``) is
     compiled into the programs; per-request randomness comes from each
     request's own ``seed`` folded with its absolute position, so a request's
@@ -157,8 +176,10 @@ class DecodeEngine:
                  int8: bool = False, donate: bool = True, fuse: int = 1,
                  prefill_chunk: Optional[int] = None, prefix_cache_mb: float = 0.0,
                  draft=None, spec_k: int = 4, draft_seed: int = 0,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None, device=None):
         from ..models.gpt import GPTBlockStack, GPTConfig, _kv_zeros
+
+        self._device = device
 
         if not isinstance(model.gpt.layers, GPTBlockStack):
             raise NotImplementedError("DecodeEngine requires the stacked trunk (GPTConfig(stacked=True))")
@@ -257,6 +278,11 @@ class DecodeEngine:
                 dparams = pack_stack(draft_model.gpt.layers._order, dparams)
             self._dparams = {"stack": dparams, "wte": dwte, "wpe": dwpe,  # noqa: PTA104 (host-side serving state)
                              "fnw": dfnw, "fnb": dfnb}
+        if device is not None:
+            # this engine's own copy of the weights, beside its cache
+            self._params = jax.device_put(self._params, device)
+            if self._dparams is not None:
+                self._dparams = jax.device_put(self._dparams, device)  # noqa: PTA104 (host-side serving state)
 
         L = cfg.num_layers
         H = cfg.num_heads
@@ -268,20 +294,21 @@ class DecodeEngine:
         # attendable by an emitted token (q_pos < max_seq_len always)
         cache_S = S + self.spec_k
         self._shape = (L, B, H, cache_S, dh)
-        self._ck = _kv_zeros((L, B, H, cache_S, dh), dt, self._kv_dtype)
-        self._cv = _kv_zeros((L, B, H, cache_S, dh), dt, self._kv_dtype)
-        if draft_model is not None:
-            dcfg = self.draft_cfg
-            dL, dH = dcfg.num_layers, dcfg.num_heads
-            ddh = dcfg.hidden_size // dcfg.num_heads
-            # the draft cache is small — keep it in the compute dtype
-            self._dck = jnp.zeros((dL, B, dH, cache_S, ddh), dwte.dtype)  # noqa: PTA104 (host-side serving state)
-            self._dcv = jnp.zeros((dL, B, dH, cache_S, ddh), dwte.dtype)  # noqa: PTA104 (host-side serving state)
-        else:
-            self._dck = self._dcv = None  # noqa: PTA104 (host-side serving state)
-        self._pos = jnp.zeros((B,), jnp.int32)
-        self._tok = jnp.zeros((B,), jnp.int32)
-        self._active = jnp.zeros((B,), bool)
+        with self._device_scope():
+            self._ck = _kv_zeros((L, B, H, cache_S, dh), dt, self._kv_dtype)
+            self._cv = _kv_zeros((L, B, H, cache_S, dh), dt, self._kv_dtype)
+            if draft_model is not None:
+                dcfg = self.draft_cfg
+                dL, dH = dcfg.num_layers, dcfg.num_heads
+                ddh = dcfg.hidden_size // dcfg.num_heads
+                # the draft cache is small — keep it in the compute dtype
+                self._dck = jnp.zeros((dL, B, dH, cache_S, ddh), dwte.dtype)  # noqa: PTA104 (host-side serving state)
+                self._dcv = jnp.zeros((dL, B, dH, cache_S, ddh), dwte.dtype)  # noqa: PTA104 (host-side serving state)
+            else:
+                self._dck = self._dcv = None  # noqa: PTA104 (host-side serving state)
+            self._pos = jnp.zeros((B,), jnp.int32)
+            self._tok = jnp.zeros((B,), jnp.int32)
+            self._active = jnp.zeros((B,), bool)
         # host mirrors / per-slot request metadata (tiny, resent per dispatch)
         self._active_np = np.zeros((B,), bool)
         self._occupied = np.zeros((B,), bool)
@@ -332,6 +359,16 @@ class DecodeEngine:
         gauge_set("infer.kv_bytes_per_slot", self.kv_bytes_per_slot())
 
     # ------------------------------------------------------------ programs
+    @property
+    def device(self):
+        """The device this engine's KV cache lives on."""
+        return next(iter(jax.tree_util.tree_leaves(self._ck)[0].devices()))
+
+    def _device_scope(self):
+        if self._device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self._device)
+
     def _build(self):
         from ..models.gpt import (
             _cache_forward,
@@ -671,8 +708,8 @@ class DecodeEngine:
             from . import aot_cache
 
             label = label or which
-            key = aot_cache.make_key(which, sig[1:], self._fingerprint)
-            entry = aot_cache.load(key)
+            key = aot_cache.make_key(which, sig[1:], self._fingerprint, self._device)
+            entry = aot_cache.load(key, device=self._device)
             if entry is not None:
                 self._compiled[sig] = entry
                 counter_inc("infer.aot_cache_hits")
@@ -749,6 +786,7 @@ class DecodeEngine:
         return [i for i in range(self.max_batch_slots) if not self._occupied[i]]
 
     # ----------------------------------------------------------- prefill
+    @_placed
     def begin_prefill(self, prompt, slot: int, max_new_tokens: int,
                       eos_token_id: Optional[int] = None, seed: int = 0) -> _PrefillJob:
         """Claim ``slot`` for one prompt and apply any prefix-cache hits
@@ -801,6 +839,7 @@ class DecodeEngine:
             gauge_set("serving.prefix_cache_bytes", self.prefix_cache.bytes_used())
         return job
 
+    @_placed
     def prefill_step(self, job: _PrefillJob) -> bool:
         """Run ONE prefill dispatch for ``job``: the whole bucket program in
         bucketed mode, or one C-token chunk in chunked mode. Returns True
@@ -921,6 +960,7 @@ class DecodeEngine:
         return job.first, job.more
 
     # ------------------------------------------------------------- decode
+    @_placed
     def decode_step(self, fuse: Optional[int] = None):
         """Advance every active slot in ONE dispatch. At fuse depth 1
         returns ``(tokens[B], emitted[B], active[B])``; at depth D > 1 the
@@ -1002,6 +1042,7 @@ class DecodeEngine:
             self._active_np[slot] = False
         self._occupied[slot] = False
 
+    @_placed
     def reset(self) -> None:
         """Drop every in-flight request and zero the slot state (the cache
         keeps its buffers — stale K/V is always overwritten before it can be

@@ -162,10 +162,17 @@ class EngineReplica:
 
     def __init__(self, rid: int, model, engine_kwargs: Dict[str, Any],
                  on_beat=None, keep_finished: int = 256):
+        import jax
+
         from .engine import DecodeEngine
 
         self.rid = int(rid)
-        self.engine = DecodeEngine(model, **engine_kwargs)
+        # one local device per replica, round-robin: on a four-chip host
+        # four replicas hold four chips, on a one-chip host they share it
+        devices = jax.local_devices()
+        kw = dict(engine_kwargs)
+        kw.setdefault("device", devices[self.rid % len(devices)])
+        self.engine = DecodeEngine(model, **kw)
         self.scheduler = ContinuousBatchingScheduler(self.engine,
                                                      keep_finished=keep_finished)
         self.alive = True

@@ -349,6 +349,7 @@ class ProcReplica:
         self.beat_n = -1
         self.last_beat = time.monotonic()
         self.pid: Optional[int] = proc.pid if proc is not None else None
+        self.chip: Optional[int] = None  # local TPU chip the process is bound to
         self.host: Optional[str] = None
         self.counters: Dict[str, int] = {}
 
@@ -605,12 +606,23 @@ class ProcServingFleet:
                 "beat_interval": self.beat_interval,
                 "socket": self.use_sockets,
                 "jax_config": current_jax_config()}
+        # one chip per replica process: the lowest chip no live replica
+        # holds (refused when the host has none left)
+        from ..device import chip_env, place_on_chips
+
+        local = [rep for rep in self._alive().values() if rep.proc is not None]
+        chip = None
+        if place_on_chips(len(local) + 1, "ProcServingFleet"):
+            chip = min(set(range(len(local) + 1)) - {rep.chip for rep in local})
         # PADDLE_TRAINER_ID decorrelates the child's trace/span id streams
         # from the parent (rank 0) and its siblings — launcher discipline
         env = child_env({SPEC_ENV: json.dumps(spec),
-                         "PADDLE_TRAINER_ID": str(rid + 1)})
+                         "PADDLE_TRAINER_ID": str(rid + 1),
+                         **({} if chip is None else chip_env(chip))})
         proc = subprocess.Popen(CHILD_CMD, env=env)
-        return self._make_replica(rid, proc)
+        rep = self._make_replica(rid, proc)
+        rep.chip = chip
+        return rep
 
     def _adopt_replica(self, rid: int) -> ProcReplica:
         self._next_rid = max(self._next_rid, rid + 1)

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ...framework import random as _random
 from ...framework.flags import flag
@@ -89,26 +90,76 @@ def _sdpa_reference(q, k, v, mask=None, causal=False, dropout_p=0.0, drop_key=No
 # -- kernel registrations ----------------------------------------------------
 
 
-def _interpret_state():
-    # interpret-mode toggles live outside the flag registry; fold them into
-    # the selection-cache key so set_interpret() re-runs the predicates
+def _fleet_mesh():
+    from ...distributed.fleet import fleet  # imports this package: not at module level
+
+    return fleet.multi_device_mesh
+
+
+def _selection_state():
+    # interpret-mode toggles and the fleet mesh live outside the flag
+    # registry; fold them into the selection-cache key so set_interpret()
+    # and fleet.init() re-run the predicates
     from ...ops import flash_attention as _fa
     from ...ops import flash_attention_flat as _flat
 
-    return (_fa._INTERPRET, _flat._INTERPRET)
+    mesh = _fleet_mesh()
+    return (_fa._INTERPRET, _flat._INTERPRET,
+            None if mesh is None else tuple(mesh.shape.items()))
+
+
+def _kernel_shard(b, h):
+    """How a Pallas attention kernel runs under the fleet mesh.
+
+    Mosaic kernels cannot be partitioned automatically ("wrap the call in a
+    shard_map" is the TPU compiler's answer), so in a program sharded over
+    the fleet mesh the kernel runs per shard: batch over dp×sdp, heads over
+    mp. Returns ``(mesh, b_local, h_local)`` — ``mesh`` None when no
+    multi-device mesh is active and the kernel is called directly — or None
+    when it cannot run per shard (a live pp or sep axis, batch or heads
+    that do not divide); the XLA composite, which GSPMD partitions itself,
+    then takes the call."""
+    mesh = _fleet_mesh()
+    if mesh is None:
+        return None, b, h
+    n = mesh.shape
+    nb = n["dp"] * n["sdp"]
+    if n["pp"] > 1 or n["sep"] > 1 or b % nb or h % n["mp"]:
+        return None
+    return mesh, b // nb, h // n["mp"]
+
+
+_BSHD = P(("dp", "sdp"), None, "mp", None)          # q/k/v/out [b, s, h, d]
+_PACKED = P(("dp", "sdp"), None, None, "mp", None)  # qkv [b, s, 3, h, d]
+
+
+def _per_shard(b, h, fn, in_specs, out_specs):
+    """``fn`` as it must be called for a [b, ·, h, ·] problem: wrapped in a
+    shard_map over the fleet mesh when one is active, else ``fn`` itself."""
+    shard = _kernel_shard(b, h)
+    if shard is None or shard[0] is None:
+        return fn
+    return jax.shard_map(fn, mesh=shard[0], in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _sdpa_flash_available(q, k, v, mask, causal, dropout_p, drop_key, train):
     from ...ops.flash_attention import flash_attention_available
 
-    return (mask is None and dropout_p == 0.0 and flag("FLAGS_use_flash_attention")
-            and flash_attention_available(tuple(q.shape), tuple(k.shape)))
+    if (mask is not None or dropout_p != 0.0 or not flag("FLAGS_use_flash_attention")
+            or len(q.shape) != 4 or tuple(k.shape) != tuple(q.shape)):
+        return False
+    b, s, h, d = q.shape
+    shard = _kernel_shard(b, h)
+    return shard is not None and flash_attention_available((shard[1], s, shard[2], d))
 
 
 def _sdpa_flash(q, k, v, mask, causal, dropout_p, drop_key, train):
     from ...ops.flash_attention import flash_attention
 
-    return flash_attention(q, k, v, causal=causal)
+    return _per_shard(q.shape[0], q.shape[2],
+                      lambda q, k, v: flash_attention(q, k, v, causal=causal),
+                      (_BSHD, _BSHD, _BSHD), _BSHD)(q, k, v)
 
 
 def _sdpa_flat_available(q, k, v, mask, causal, dropout_p, drop_key, train):
@@ -119,6 +170,8 @@ def _sdpa_flat_available(q, k, v, mask, causal, dropout_p, drop_key, train):
 
     if mask is None or dropout_p != 0.0 or not flag("FLAGS_use_flash_attention"):
         return False
+    if _fleet_mesh() is not None:
+        return False  # masked/grouped operands are not split per shard here
     b, s, h, d = q.shape
     kv_ok = tuple(k.shape) == tuple(q.shape) or (
         k.shape[0] == b and k.shape[1] == s and h % k.shape[2] == 0 and k.shape[3] == d)
@@ -136,7 +189,7 @@ def _sdpa_flat(q, k, v, mask, causal, dropout_p, drop_key, train):
 
 _registry.define_kernel(
     "sdpa", flags=("FLAGS_use_flash_attention", "FLAGS_flash_flat"),
-    cache_key=_interpret_state)
+    cache_key=_selection_state)
 _registry.register(
     "sdpa", "flash", _sdpa_flash, available=_sdpa_flash_available,
     doc="classic Pallas flash pair (self-attn, no mask/dropout, tile-friendly seq)")
@@ -148,31 +201,44 @@ _registry.register(
     doc="jnp reference composite (any mask/dropout/shape)")
 
 
+def _core_shard(qkv, dropout_p):
+    """``_kernel_shard`` for a packed [b, s, 3, h, d] call the Pallas
+    kernels may take (no dropout, flash on), else None."""
+    if dropout_p != 0.0 or not flag("FLAGS_use_flash_attention"):
+        return None
+    return _kernel_shard(qkv.shape[0], qkv.shape[3])
+
+
 def _core_flat_available(qkv, dropout_p, drop_key):
     from ...ops import flash_attention_flat as _flat
 
-    return (dropout_p == 0.0 and flag("FLAGS_use_flash_attention")
-            and _flat.enabled(tuple(qkv.shape)))
+    shard = _core_shard(qkv, dropout_p)
+    _, s, _, _, d = qkv.shape
+    return shard is not None and _flat.enabled((shard[1], s, 3, shard[2], d))
 
 
 def _core_flat(qkv, dropout_p, drop_key):
     from ...ops import flash_attention_flat as _flat
 
-    return _flat.flash_packed(qkv, causal=True)
+    return _per_shard(qkv.shape[0], qkv.shape[3],
+                      lambda x: _flat.flash_packed(x, causal=True),
+                      (_PACKED,), _BSHD)(qkv)
 
 
 def _core_flash_available(qkv, dropout_p, drop_key):
     from ...ops.flash_attention import flash_attention_available
 
-    b, s, _, h, d = qkv.shape
-    return (dropout_p == 0.0 and flag("FLAGS_use_flash_attention")
-            and flash_attention_available((b, s, h, d)))
+    shard = _core_shard(qkv, dropout_p)
+    _, s, _, _, d = qkv.shape
+    return shard is not None and flash_attention_available((shard[1], s, shard[2], d))
 
 
 def _core_flash(qkv, dropout_p, drop_key):
     from ...ops.flash_attention import _flash
 
-    return _flash(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], True)
+    return _per_shard(qkv.shape[0], qkv.shape[3],
+                      lambda x: _flash(x[:, :, 0], x[:, :, 1], x[:, :, 2], True),
+                      (_PACKED,), _BSHD)(qkv)
 
 
 def _core_xla(qkv, dropout_p, drop_key):
@@ -182,7 +248,7 @@ def _core_xla(qkv, dropout_p, drop_key):
 
 _registry.define_kernel(
     "attention_core", flags=("FLAGS_use_flash_attention", "FLAGS_flash_flat"),
-    cache_key=_interpret_state)
+    cache_key=_selection_state)
 _registry.register(
     "attention_core", "flash_packed", _core_flat, available=_core_flat_available,
     doc="flat-lane packed-qkv kernels (zero-relayout reads via index maps)")

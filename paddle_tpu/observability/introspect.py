@@ -4,8 +4,7 @@ At every Executor/TrainStep compile the runtime lowers through jax.jit's
 AOT path (``.lower(...).compile()``) so the XLA ``Compiled`` handle — the
 only object that answers ``cost_analysis()``/``memory_analysis()`` — is
 retained instead of being buried in jit's internal cache. The analysis is
-normalized by ``framework.jax_compat`` (older jax returns a list of
-per-device dicts; CPU builds omit fields) into a flat dict::
+flattened (CPU builds omit fields) into one dict::
 
     {"flops", "bytes_accessed", "argument_bytes", "output_bytes",
      "temp_bytes", "peak_bytes", "generated_code_bytes"}
@@ -18,8 +17,6 @@ from __future__ import annotations
 
 import time
 from typing import Any, Dict, List, Optional, Tuple
-
-from ..framework import jax_compat
 
 __all__ = ["cost_summary", "aot_compile", "format_cost_table"]
 
@@ -78,8 +75,8 @@ def cost_summary(compiled) -> Dict[str, Any]:
     """Normalized cost/memory analysis of one XLA ``Compiled`` executable.
     Every field degrades to None when the backend does not report it, so
     CPU-only CI sees the same schema as TPU."""
-    cost = jax_compat.compiled_cost_analysis(compiled)
-    mem = jax_compat.compiled_memory_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
+    mem = compiled.memory_analysis()
     arg = getattr(mem, "argument_size_in_bytes", None)
     out_b = getattr(mem, "output_size_in_bytes", None)
     tmp = getattr(mem, "temp_size_in_bytes", None)

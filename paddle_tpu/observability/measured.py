@@ -34,7 +34,7 @@ import os
 import re
 from typing import List, Optional
 
-from ..framework.flags import flag
+from ..framework.flags import compile_cache_dir
 
 __all__ = ["record", "load", "path_for", "shard_paths", "fingerprints"]
 
@@ -43,10 +43,10 @@ _SHARD_RE = re.compile(r"^(?P<fp>.+)\.(?P<pid>\d+)\.json$")
 
 
 def _measured_dir() -> Optional[str]:
-    d = flag("FLAGS_compile_cache_dir")
+    d = compile_cache_dir()
     if not d:
         return None
-    return os.path.join(str(d), "measured")
+    return os.path.join(d, "measured")
 
 
 def path_for(fingerprint: str) -> Optional[str]:

@@ -28,7 +28,8 @@ _nlib = None  # cached handle; only Profiler.start pays the one-time build
 
 
 def _native(build: bool = False):
-    """The native tracer lib, or None.
+    """The native tracer lib (None until something asked for it with
+    ``build=True``).
 
     ``build=False`` (the per-RecordEvent path) never compiles and never takes
     the build lock — it only returns an already-loaded handle, so hot-loop
@@ -39,10 +40,7 @@ def _native(build: bool = False):
         return _nlib
     from ..framework import native
 
-    try:
-        _nlib = native.load_native()
-    except RuntimeError:  # pragma: no cover - g++ is baked into the image
-        pass
+    _nlib = native.load_native()  # a failed build raises: an error, not a skip
     return _nlib
 
 

@@ -304,6 +304,12 @@ class TestScaleOut:
         """Cold scale-out replica boots from the AOT executable cache:
         first token at infer.compiles == 0 (the acceptance pin)."""
         p = _prompts(1)[0]
+        # an executable loads only on the device it was built for, and
+        # replica r lives on local device r: warm both devices' stores
+        warm = ServingFleet(model, replicas=2, **KW)
+        for r in (0, 1):
+            warm.submit(p, max_new_tokens=4, seed=1, replica=r)
+        warm.run()
         fleet = ServingFleet(model, replicas=1, **KW)
         f0 = fleet.submit(p, max_new_tokens=4, seed=1)
         fleet.run()  # ensures the family is compiled AND serialized
