@@ -748,6 +748,7 @@ class DecodeEngine:
                              flops=info.get("flops"),
                              bytes_accessed=info.get("bytes_accessed"),
                              peak_bytes=info.get("peak_bytes"))
+            _introspect.note_program(f"infer/{which}", entry)
         try:
             try:
                 with _sanitizer.transfer_scope(f"infer.{which}"):
@@ -970,76 +971,77 @@ class DecodeEngine:
         iteration j self-deactivates in-graph (``emitted[j+1:, slot]`` is
         False) with no host round-trip until the stack is drained."""
         from ..observability import span as _span
-        from ..observability.metrics import observe
         from ..profiler import counter_inc
 
         depth = self.fuse if fuse is None else int(fuse)
         if depth < 1:
             raise ValueError(f"fuse depth must be >= 1, got {depth}")
-        if self._dparams is not None:
-            if depth != 1:
-                raise ValueError("speculative decode runs at fuse depth 1 (one "
-                                 "dispatch already emits up to spec_k+1 tokens)")
-            from ..observability.metrics import gauge_set
+        spec = self._dparams is not None
+        if spec and depth != 1:
+            raise ValueError("speculative decode runs at fuse depth 1 (one "
+                             "dispatch already emits up to spec_k+1 tokens)")
+        # infer.decode_step runs from entry to the tokens on the host. Its
+        # children: infer.decode_launch (the three host->device copies of
+        # eos/limit/seed and the dispatch) and infer.decode_sync (the pulls of
+        # tokens / emitted / active, which wait for the device) — the end of
+        # infer.decode_sync is when this tick's tokens reached the host.
+        with _span("infer.decode_step"):
+            if spec:
+                from ..observability.metrics import gauge_set
 
-            with _span("infer.spec_decode"):
-                out = self._dispatch(
-                    "spec_decode", self._spec_jit,
-                    (self._params, self._dparams, self._ck, self._cv, self._dck, self._dcv,
-                     self._pos, self._tok, self._active,
-                     jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)),
-                    label=f"spec_decode/K{self.spec_k}")
-            (self._ck, self._cv, self._dck, self._dcv,  # noqa: PTA104 (host-side serving state)
-             self._pos, self._tok, self._active, toks, emitted) = out  # noqa: PTA104 (host-side serving state)
-            toks = np.asarray(toks)
-            emitted = np.asarray(emitted)
-            self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
-            n_active = int(emitted[0].sum())   # row 0 always emits per live slot
-            n_emitted = int(emitted.sum())
-            self._spec_drafted += self.spec_k * n_active  # noqa: PTA104 (host-side serving state)
-            self._spec_accepted += n_emitted - n_active  # noqa: PTA104 (host-side serving state)
-            counter_inc("infer.decode_dispatches")
-            counter_inc("infer.tokens", n_emitted)
-            counter_inc("infer.spec_draft_tokens", self.spec_k * n_active)
-            counter_inc("infer.spec_accepted_tokens", n_emitted - n_active)
-            if self._spec_drafted:
-                gauge_set("serving.spec_acceptance_rate",
-                          self._spec_accepted / self._spec_drafted)
-            observe("infer.tokens_per_decode_dispatch", float(n_emitted))
-            return toks, emitted, self._active_np.copy()
-        if depth == 1:
-            emitted = self._active_np.copy()
-            with _span("infer.decode_step"):
-                out = self._dispatch(
-                    "decode", self._decode_jit,
-                    (self._params, self._ck, self._cv, self._pos, self._tok, self._active,
-                     jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)))
-            self._ck, self._cv, self._pos, self._tok, self._active = out
-            toks = np.asarray(self._tok)
-            self._active_np = np.array(self._active)  # writable host mirror
+                with _span("infer.decode_launch"):
+                    out = self._dispatch(
+                        "spec_decode", self._spec_jit,
+                        (self._params, self._dparams, self._ck, self._cv, self._dck, self._dcv,
+                         self._pos, self._tok, self._active,
+                         jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)),
+                        label=f"spec_decode/K{self.spec_k}")
+                (self._ck, self._cv, self._dck, self._dcv,  # noqa: PTA104 (host-side serving state)
+                 self._pos, self._tok, self._active, toks, emitted) = out  # noqa: PTA104 (host-side serving state)
+                with _span("infer.decode_sync"):
+                    toks = np.asarray(toks)
+                    emitted = np.asarray(emitted)
+                    self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
+                n_active = int(emitted[0].sum())   # row 0 always emits per live slot
+                n_emitted = int(emitted.sum())
+                self._spec_drafted += self.spec_k * n_active  # noqa: PTA104 (host-side serving state)
+                self._spec_accepted += n_emitted - n_active  # noqa: PTA104 (host-side serving state)
+                counter_inc("infer.spec_draft_tokens", self.spec_k * n_active)
+                counter_inc("infer.spec_accepted_tokens", n_emitted - n_active)
+                if self._spec_drafted:
+                    gauge_set("serving.spec_acceptance_rate",
+                              self._spec_accepted / self._spec_drafted)
+            elif depth == 1:
+                emitted = self._active_np.copy()
+                with _span("infer.decode_launch"):
+                    out = self._dispatch(
+                        "decode", self._decode_jit,
+                        (self._params, self._ck, self._cv, self._pos, self._tok, self._active,
+                         jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)))
+                self._ck, self._cv, self._pos, self._tok, self._active = out  # noqa: PTA104 (host-side serving state)
+                with _span("infer.decode_sync"):
+                    toks = np.asarray(self._tok)
+                    self._active_np = np.array(self._active)  # writable host mirror  # noqa: PTA104 (host-side serving state)
+            else:
+                with _span("infer.decode_launch"):
+                    consts = (self._params, jnp.asarray(self._eos), jnp.asarray(self._limit),
+                              jnp.asarray(self._seed))
+                    carry = (self._ck, self._cv, self._pos, self._tok, self._active)
+                    out = self._dispatch(f"decode_x{depth}", self._fused(depth), (consts, carry))
+                (self._ck, self._cv, self._pos, self._tok, self._active), (toks, emitted) = out  # noqa: PTA104 (host-side serving state)
+                with _span("infer.decode_sync"):
+                    toks = np.asarray(toks)
+                    emitted = np.asarray(emitted)
+                    self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
             counter_inc("infer.decode_dispatches")
             counter_inc("infer.tokens", int(emitted.sum()))
-            observe("infer.tokens_per_decode_dispatch", float(emitted.sum()))
-            return toks, emitted, self._active_np.copy()
-        consts = (self._params, jnp.asarray(self._eos), jnp.asarray(self._limit),
-                  jnp.asarray(self._seed))
-        carry = (self._ck, self._cv, self._pos, self._tok, self._active)
-        with _span("infer.decode_step"):
-            out = self._dispatch(f"decode_x{depth}", self._fused(depth), (consts, carry))
-        (self._ck, self._cv, self._pos, self._tok, self._active), (toks, emitted) = out
-        toks = np.asarray(toks)
-        emitted = np.asarray(emitted)
-        self._active_np = np.array(self._active)
-        counter_inc("infer.decode_dispatches")
-        counter_inc("infer.tokens", int(emitted.sum()))
-        observe("infer.tokens_per_decode_dispatch", float(emitted.sum()))
         return toks, emitted, self._active_np.copy()
 
     def free_slot(self, slot: int) -> None:
         """Release a slot for the next admission (cancels it if still live)."""
         if self._active_np[slot]:
-            self._active = self._active.at[slot].set(False)
-            self._active_np[slot] = False
+            self._active = self._active.at[slot].set(False)  # noqa: PTA104 (host-side serving state)
+            self._active_np[slot] = False  # noqa: PTA104 (host-side serving state)
         self._occupied[slot] = False
 
     @_placed

@@ -56,7 +56,9 @@ from ..observability import exporter as _exporter
 from ..observability import flightrec as _flightrec
 from ..observability import runlog as _runlog
 from ..observability import slo as _slo
+from ..observability import span as _span
 from ..observability import trace as _trace
+from ..observability.metrics import counter as _counter
 from ..observability.metrics import counter_inc, gauge_set, observe
 from ..testing import chaos
 from .router import Router
@@ -501,25 +503,26 @@ class ServingFleet:
         harvest completions/cancellations into the fleet ledger, and answer
         replica faults (raise or heartbeat overrun) with mark-dead + drain +
         requeue. Returns the fleet requests finished this tick."""
+        with _span("infer.fleet.step"):
+            return self._step()
+
+    def _step(self) -> List[FleetRequest]:
+        """The body of :meth:`step`, inside the ``infer.fleet.step`` span.
+        Its children are one ``infer.sched.step`` per alive replica; what is
+        left is the fleet's own time: harvest, ledger GC, the heartbeat
+        check and the SLO hook."""
         done: List[FleetRequest] = []
         for rid, rep in list(self.replicas.items()):  # noqa: PTA102 (host-side serving loop, never traced)
             if not rep.alive:
                 continue
-            from ..observability.metrics import counters as _counters
-
-            def _builds():
-                c = _counters("infer.")
-                return (c.get("infer.compiles", 0)
-                        + c.get("infer.aot_cache_hits", 0))
-
-            builds0 = _builds()
+            builds0 = _counter("infer.compiles") + _counter("infer.aot_cache_hits")
             try:
                 finished = rep.tick()
             except Exception as exc:  # replica death: chaos kill or real fault
                 self._on_replica_death(rep, exc)
                 continue  # noqa: PTA103 (host-side serving loop, never traced)
             self._harvest(rep, finished, done)
-            compiled = _builds() > builds0
+            compiled = _counter("infer.compiles") + _counter("infer.aot_cache_hits") > builds0
             if (self.heartbeat_timeout and not compiled
                     and rep.last_tick_seconds > self.heartbeat_timeout):
                 # the tick came back but took longer than the liveness

@@ -12,9 +12,9 @@ Round 2 of the serving hot path rides the engine's three throughput knobs:
   fixed-size chunk dispatches driven one per tick, INTERLEAVED with decode
   — a 2k-token prompt no longer stalls every in-flight request for its
   whole prefill. The time prefill dispatches spend while other slots hold
-  active decodes is the *stall*: tracked per request, observed in the
-  ``serving.prefill_stall_seconds`` histogram, and reported as p50/p99 by
-  ``observability report``.
+  active decodes is the *stall*: tracked per request
+  (``Request.stall_seconds``, carried by the ``request`` run-log events) and
+  reported as p50/p99 by ``observability report``.
 - **fused decode** (``fuse=D``): one decode dispatch returns a ``[D, B]``
   token stack; the scheduler drains it in order, appending only tokens
   whose slot really emitted (finished slots self-deactivate in-graph).
@@ -299,7 +299,6 @@ class ContinuousBatchingScheduler:
                                   chunk=r.prefill_chunks, done=bool(done))
             if decode_waiting:
                 r.stall_seconds += dt  # noqa: PTA104 (host-side serving loop)
-                observe("serving.prefill_stall_seconds", dt)
             if not done:
                 continue
             r.first_token_ts = time.perf_counter()  # noqa: PTA104 (host-side serving loop)
@@ -353,43 +352,48 @@ class ContinuousBatchingScheduler:
         one prefill dispatch per mid-prefill admission, then advance every
         decoding slot in a single decode dispatch (a ``[D, B]`` token stack
         at fuse depth D, drained in order). Returns requests finished this
-        tick."""
-        before = set(self.finished)
-        before_cancelled = set(self.cancelled)
-        self._expire_deadlines()
-        self._admit()
-        self._prefill_tick()
-        if self.running:
-            traced = sorted({r.trace_id for r in self.running.values()
-                             if r.trace_id is not None})
-            t0 = time.perf_counter()
-            toks, emitted, active = self.engine.decode_step()
-            if traced:
-                from ..observability import trace as _trace
+        tick.
 
-                # one fused dispatch advances EVERY running slot: a single
-                # span event fanned across the traces it served
-                _trace.span_event("serving.decode", trace_id=None,
-                                  seconds=time.perf_counter() - t0,
-                                  traces=traced, slots=len(self.running))
-            toks = np.atleast_2d(toks)
-            emitted = np.atleast_2d(emitted)
-            for d in range(toks.shape[0]):
-                for slot, r in self.running.items():  # noqa: PTA102 (host-side serving loop)
-                    if emitted[d, slot]:
-                        r.tokens.append(int(toks[d, slot]))  # noqa: PTA104 (host-side serving loop)
-            for slot, r in list(self.running.items()):  # noqa: PTA102 (host-side serving loop)
-                if not active[slot]:
-                    self._finish(r)
-        done = [self.finished[rid] for rid in self.finished if rid not in before]
-        fresh = ({rid for rid in self.finished if rid not in before}
-                 | {rid for rid in self.cancelled if rid not in before_cancelled})
-        self._gc_ledgers(protect=fresh)
+        The tick is one ``infer.sched.step`` span with a child per phase:
+        ``infer.sched.admit`` (deadline sweep, slot claim, prefix inserts),
+        ``infer.sched.prefill``, the engine's ``infer.decode_step`` (launch
+        and token pull) and ``infer.sched.drain`` (token appends, finishes,
+        ledger GC, SLO hook; ``slots`` = slots that decoded). A request's
+        path through the decode phase is these tick spans between its first
+        token and its finish — no per-tick event names the requests."""
         from ..observability import slo as _slo
+        from ..observability import span as _span
 
-        # judgment layer: cadence-gated host-side evaluate — a single flag
-        # check per tick until FLAGS_slo (or an explicit install) arms it
-        _slo.on_tick()
+        with _span("infer.sched.step"):
+            before = set(self.finished)
+            before_cancelled = set(self.cancelled)
+            with _span("infer.sched.admit"):
+                self._expire_deadlines()
+                self._admit()
+            with _span("infer.sched.prefill"):
+                self._prefill_tick()
+            decoded = len(self.running)
+            if decoded:
+                toks, emitted, active = self.engine.decode_step()
+            with _span("infer.sched.drain", slots=decoded):
+                if decoded:
+                    toks = np.atleast_2d(toks)
+                    emitted = np.atleast_2d(emitted)
+                    for d in range(toks.shape[0]):
+                        for slot, r in self.running.items():  # noqa: PTA102 (host-side serving loop)
+                            if emitted[d, slot]:
+                                r.tokens.append(int(toks[d, slot]))  # noqa: PTA104 (host-side serving loop)
+                    for slot, r in list(self.running.items()):  # noqa: PTA102 (host-side serving loop)
+                        if not active[slot]:
+                            self._finish(r)
+                done = [self.finished[rid] for rid in self.finished if rid not in before]
+                fresh = ({r.rid for r in done}
+                         | {rid for rid in self.cancelled if rid not in before_cancelled})
+                self._gc_ledgers(protect=fresh)
+                # judgment layer: cadence-gated host-side evaluate — a single
+                # flag check per tick until FLAGS_slo (or an explicit install)
+                # arms it
+                _slo.on_tick()
         return done
 
     def _gc_ledgers(self, protect=()) -> None:

@@ -208,7 +208,8 @@ class TrainStep:
                 if o2:
                     # cast-through: grads of the cast are a cast back, so the
                     # optimizer sees f32 grads against f32 master params
-                    p = _to_amp(p)
+                    with jax.named_scope("amp_cast"):
+                        p = _to_amp(p)
                     inputs_c = _to_amp(inputs)
                 else:
                     inputs_c = inputs
@@ -304,15 +305,17 @@ class TrainStep:
                             for g in jax.tree_util.tree_leaves(grads))
                 gnorm = jnp.sqrt(sumsq)
                 bad = ~(jnp.isfinite(sumsq) & jnp.isfinite(loss))
-            new_params, new_opt, lr = optimizer._traced_update(
-                grads, state["opt"], state["params"], state["step"])
+            with jax.named_scope("optimizer"):
+                new_params, new_opt, lr = optimizer._traced_update(
+                    grads, state["opt"], state["params"], state["step"])
             if guard:
                 # bad-step skip: select the PRE-step value for every state
                 # leaf inside the compiled program — bitwise no-op update,
                 # correct under donate_argnums (nothing escaped the program)
                 sel = lambda new, old: jnp.where(bad, old, new)  # noqa: E731
-                new_params = jax.tree_util.tree_map(sel, new_params, state["params"])
-                new_opt = jax.tree_util.tree_map(sel, new_opt, state["opt"])
+                with jax.named_scope("optimizer"):
+                    new_params = jax.tree_util.tree_map(sel, new_params, state["params"])
+                    new_opt = jax.tree_util.tree_map(sel, new_opt, state["opt"])
                 new_buffers = jax.tree_util.tree_map(sel, new_buffers, state["buffers"])
                 new_step = jnp.where(bad, state["step"], state["step"] + 1)
                 new_state["skipped"] = state["skipped"] + bad.astype(jnp.int32)
@@ -375,6 +378,7 @@ class TrainStep:
                 compiled, info = _introspect.aot_compile(
                     jitfn, (self.state, batch), cache_scope="train_step")
             entry = compiled if compiled is not None else jitfn
+            _introspect.note_program(f"train_step/{which}", compiled)
             if compiled is not None:
                 from ..framework.flags import flag as _flag
 

@@ -175,9 +175,10 @@ class GPTAttention(nn.Layer):
         from ..nn.layer.transformer import MultiHeadAttention
 
         b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        qkv = M.reshape(qkv, [b, s, 3, self.num_heads, self.head_dim])
-        q, k, v = (M.squeeze(t, 2) for t in M.split(qkv, 3, axis=2))
+        with jax.named_scope("attn_qkv"):
+            qkv = self.qkv_proj(x)
+            qkv = M.reshape(qkv, [b, s, 3, self.num_heads, self.head_dim])
+            q, k, v = (M.squeeze(t, 2) for t in M.split(qkv, 3, axis=2))
         if isinstance(cache, MultiHeadAttention.FixedCache):
             from ..nn.layer.transformer import _fixed_cache_mask, _fixed_cache_write
 
@@ -215,9 +216,11 @@ class GPTAttention(nn.Layer):
             mask = jnp.tril(jnp.ones((s, k.shape[1]), bool), k=past)
             out = F.scaled_dot_product_attention(q, k, v, attn_mask=_wrap_value(mask), dropout_p=self.attn_dropout, training=self.training)
         else:
-            out = F.scaled_dot_product_attention(q, k, v, is_causal=True, dropout_p=self.attn_dropout, training=self.training)
-        out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
-        out = self.out_proj(out)
+            with jax.named_scope("attn_core"):
+                out = F.scaled_dot_product_attention(q, k, v, is_causal=True, dropout_p=self.attn_dropout, training=self.training)
+        with jax.named_scope("attn_out"):
+            out = M.reshape(out, [b, s, self.num_heads * self.head_dim])
+            out = self.out_proj(out)
         if cache is not None:
             return out, cache
         return out
@@ -248,15 +251,21 @@ class GPTBlock(nn.Layer):
         return self.attn.gen_cache(x, static=static, max_seq=max_seq, kv_dtype=kv_dtype)
 
     def forward(self, x, cache=None):
+        with jax.named_scope("norm"):
+            h = self.norm1(x)
         if cache is not None:
-            att, cache = self.attn(self.norm1(x), cache=cache)
+            att, cache = self.attn(h, cache=cache)
+        else:
+            att = self.attn(h)
+        with jax.named_scope("attn_out"):
             x = x + self.dropout(att)
-        else:
-            x = x + self.dropout(self.attn(self.norm1(x)))
-        if self.moe is not None:
-            x = x + self.dropout(self.moe(self.norm2(x)))
-        else:
-            x = x + self.dropout(self.ffn2(F.gelu(self.ffn1(self.norm2(x)), approximate=True)))
+        with jax.named_scope("norm"):
+            h = self.norm2(x)
+        with jax.named_scope("mlp"):
+            if self.moe is not None:
+                x = x + self.dropout(self.moe(h))
+            else:
+                x = x + self.dropout(self.ffn2(F.gelu(self.ffn1(h), approximate=True)))
         if cache is not None:
             return x, cache
         return x
@@ -308,14 +317,38 @@ def _block_apply(lp, h, key, *, num_heads, dropout=0.0, attn_dropout=0.0, epsilo
 
     b, s, d = h.shape
     hd = d // num_heads
-    x1 = ln(h, n1w, n1b)
-    qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
-    att = _attn_core_packed(qkv, attn_dropout, k_attn).reshape(b, s, d)
-    h = h + drop(att @ ow + ob, dropout, k_res1)
-    x2 = ln(h, n2w, n2b)
-    y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-    h = h + drop(y @ f2w + f2b, dropout, k_res2)
+    with jax.named_scope("norm"):
+        x1 = ln(h, n1w, n1b)
+    with jax.named_scope("attn_qkv"):
+        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
+    with jax.named_scope("attn_core"):
+        att = _attn_core_packed(qkv, attn_dropout, k_attn).reshape(b, s, d)
+    with jax.named_scope("attn_out"):
+        h = h + drop(att @ ow + ob, dropout, k_res1)
+    with jax.named_scope("norm"):
+        x2 = ln(h, n2w, n2b)
+    with jax.named_scope("mlp"):
+        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
+        h = h + drop(y @ f2w + f2b, dropout, k_res2)
     return h
+
+
+# The part of the block each of the 12 stacked parameters belongs to, in
+# ``GPTBlockStack._order``.
+_PARAM_SCOPES = ("norm", "norm", "attn_qkv", "attn_qkv", "attn_out", "attn_out",
+                 "norm", "norm", "mlp", "mlp", "mlp", "mlp")
+
+
+def _layer_params(params, idx, i):
+    """``lp`` of layer ``i``: its slices of the stacked parameters and its
+    index. Each slice is cut under the scope of the part that uses it, so
+    the slice — and, in backward, the write of its gradient into the
+    stacked gradient — is named like the part."""
+    sliced = []
+    for j in range(len(params)):
+        with jax.named_scope(_PARAM_SCOPES[j]):
+            sliced.append(params[j][i])  # noqa: PTA104 (static unroll, host loop bound)
+    return tuple(sliced), idx[i]
 
 
 def _stack_forward(x, *rest, num_layers, num_heads, dropout, attn_dropout, recompute, has_key, mesh, n_micro):
@@ -372,8 +405,7 @@ def _stack_forward(x, *rest, num_layers, num_heads, dropout, attn_dropout, recom
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.dots_saveable)
     h = constrain(x)
     for i in range(num_layers):
-        lp = (tuple(p[i] for p in params), idx[i])
-        h = constrain(body(lp, h))
+        h = constrain(body(_layer_params(params, idx, i), h))
     return h
 
 
@@ -526,17 +558,19 @@ def _kvc_slice(c, idx, size):
 
 def _kv_layer(c, i):
     """Layer ``i`` of a stacked [L, ...] cache (array or pack)."""
-    if isinstance(c, dict):
-        return {"q": c["q"][i], "s": c["s"][i]}
-    return c[i]
+    with jax.named_scope("cache_read"):
+        if isinstance(c, dict):
+            return {"q": c["q"][i], "s": c["s"][i]}
+        return c[i]
 
 
 def _kv_stack(xs):
     """Re-stack per-layer caches (inverse of :func:`_kv_layer`)."""
-    if isinstance(xs[0], dict):
-        return {"q": jnp.stack([x["q"] for x in xs]),
-                "s": jnp.stack([x["s"] for x in xs])}
-    return jnp.stack(xs)
+    with jax.named_scope("cache_write"):
+        if isinstance(xs[0], dict):
+            return {"q": jnp.stack([x["q"] for x in xs]),
+                    "s": jnp.stack([x["s"] for x in xs])}
+        return jnp.stack(xs)
 
 
 def _kv_zeros(shape, dt, kv_dtype=None):
@@ -571,28 +605,36 @@ def _cache_block(lp, h, ck, cv, start_pos, *, num_heads, epsilon=1e-5):
     b, s, d = h.shape
     S = (ck["q"] if isinstance(ck, dict) else ck).shape[2]
     hd = d // num_heads
-    x1 = ln(h, n1w, n1b)
-    qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
-    q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, s, dh]
-    k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-    v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-    ck = _kvc_update(ck, k, (0, 0, start_pos, 0))
-    cv = _kvc_update(cv, v, (0, 0, start_pos, 0))
-    rk = _kvc_read(ck, h.dtype)
-    rv = _kvc_read(cv, h.dtype)
-    scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
-                        preferred_element_type=jnp.float32)
-    q_pos = start_pos + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, S), 1)
-    scores = jnp.where((k_pos <= q_pos)[None, None], scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
-    att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
-    att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
-    h = h + att @ ow + ob
-    x2 = ln(h, n2w, n2b)
-    y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-    h = h + y @ f2w + f2b
+    with jax.named_scope("norm"):
+        x1 = ln(h, n1w, n1b)
+    with jax.named_scope("attn_qkv"):
+        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
+        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, s, dh]
+        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
+        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
+    with jax.named_scope("cache_write"):
+        ck = _kvc_update(ck, k, (0, 0, start_pos, 0))
+        cv = _kvc_update(cv, v, (0, 0, start_pos, 0))
+    with jax.named_scope("cache_read"):
+        rk = _kvc_read(ck, h.dtype)
+        rv = _kvc_read(cv, h.dtype)
+    with jax.named_scope("attn_core"):
+        scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
+                            preferred_element_type=jnp.float32)
+        q_pos = start_pos + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, S), 1)
+        scores = jnp.where((k_pos <= q_pos)[None, None], scores, -jnp.inf)
+        p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
+        att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
+        att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
+    with jax.named_scope("attn_out"):
+        h = h + att @ ow + ob
+    with jax.named_scope("norm"):
+        x2 = ln(h, n2w, n2b)
+    with jax.named_scope("mlp"):
+        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
+        h = h + y @ f2w + f2b
     return h, ck, cv
 
 
@@ -615,21 +657,24 @@ def _cache_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v, start_pos
 
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
 
-    pos = start_pos + jnp.arange(s, dtype=jnp.int32)
-    h = jnp.take(wte, ids, axis=0) + jnp.take(wpe, pos, axis=0)[None]
-    h = h.astype(wte.dtype)
+    with jax.named_scope("embed"):
+        pos = start_pos + jnp.arange(s, dtype=jnp.int32)
+        h = jnp.take(wte, ids, axis=0) + jnp.take(wpe, pos, axis=0)[None]
+        h = h.astype(wte.dtype)
     new_k, new_v = [], []
     for i in range(num_layers):
-        lp = (tuple(p[i] for p in params), idx[i])
+        lp = _layer_params(params, idx, i)
         h, ck, cv = _cache_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
                                  start_pos, num_heads=num_heads)
         # int8 packs skip the mp constraint (the serving engine never meshes)
         new_k.append(ck if isinstance(ck, dict) else mpc(ck, None, "mp"))  # noqa: PTA104 (static unroll, host loop bound)
         new_v.append(cv if isinstance(cv, dict) else mpc(cv, None, "mp"))  # noqa: PTA104 (static unroll, host loop bound)
-    mean = jnp.mean(h, axis=-1, keepdims=True)
-    var = jnp.var(h, axis=-1, keepdims=True)
-    h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
-    logits = mpc(jnp.einsum("bsd,vd->bsv", h, wte), None, None, "mp")
+    with jax.named_scope("norm"):
+        mean = jnp.mean(h, axis=-1, keepdims=True)
+        var = jnp.var(h, axis=-1, keepdims=True)
+        h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
+    with jax.named_scope("head_loss"):
+        logits = mpc(jnp.einsum("bsd,vd->bsv", h, wte), None, None, "mp")
     return logits, _kv_stack(new_k), _kv_stack(new_v)
 
 
@@ -660,14 +705,17 @@ def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, epsilon=1e-5, active=Non
     b, s, d = h.shape
     S = (ck["q"] if isinstance(ck, dict) else ck).shape[2]
     hd = d // num_heads
-    x1 = ln(h, n1w, n1b)
-    qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
-    q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, W, dh]
-    k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-    v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
+    with jax.named_scope("norm"):
+        x1 = ln(h, n1w, n1b)
+    with jax.named_scope("attn_qkv"):
+        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
+        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, W, dh]
+        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
+        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
     if active is None:
-        ck = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(ck, k, pos)
-        cv = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(cv, v, pos)
+        with jax.named_scope("cache_write"):
+            ck = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(ck, k, pos)
+            cv = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(cv, v, pos)
     else:
         def upd(c, u, p, a):
             if isinstance(c, dict):
@@ -681,24 +729,30 @@ def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, epsilon=1e-5, active=Non
             cur = jax.lax.dynamic_slice(c, (0, p, 0), u.shape)
             return jax.lax.dynamic_update_slice(c, jnp.where(a, u, cur), (0, p, 0))
 
-        ck = jax.vmap(upd)(ck, k, pos, active)
-        cv = jax.vmap(upd)(cv, v, pos, active)
-    rk = _kvc_read(ck, h.dtype)
-    rv = _kvc_read(cv, h.dtype)
-    scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
-                        preferred_element_type=jnp.float32)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (b, s, S), 2)
-    q_pos = pos[:, None, None] + jax.lax.broadcasted_iota(jnp.int32, (b, s, S), 1)
-    visible = k_pos <= q_pos  # [b, W, S]: row j sees its slot's prefix + itself
-    scores = jnp.where(visible[:, None], scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
-    att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
-    att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
-    h = h + att @ ow + ob
-    x2 = ln(h, n2w, n2b)
-    y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-    h = h + y @ f2w + f2b
+        with jax.named_scope("cache_write"):
+            ck = jax.vmap(upd)(ck, k, pos, active)
+            cv = jax.vmap(upd)(cv, v, pos, active)
+    with jax.named_scope("cache_read"):
+        rk = _kvc_read(ck, h.dtype)
+        rv = _kvc_read(cv, h.dtype)
+    with jax.named_scope("attn_core"):
+        scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
+                            preferred_element_type=jnp.float32)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (b, s, S), 2)
+        q_pos = pos[:, None, None] + jax.lax.broadcasted_iota(jnp.int32, (b, s, S), 1)
+        visible = k_pos <= q_pos  # [b, W, S]: row j sees its slot's prefix + itself
+        scores = jnp.where(visible[:, None], scores, -jnp.inf)
+        p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
+        att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
+        att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
+    with jax.named_scope("attn_out"):
+        h = h + att @ ow + ob
+    with jax.named_scope("norm"):
+        x2 = ln(h, n2w, n2b)
+    with jax.named_scope("mlp"):
+        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
+        h = h + y @ f2w + f2b
     return h, ck, cv
 
 
@@ -719,19 +773,22 @@ def _slot_window_forward(stacked, wte, wpe, fnw, fnb, toks, cache_k, cache_v, po
     # jnp.take fills NaN, which the window's own KV writes would spread to
     # later rows). No-op at W=1, where pos < max_seq_len always holds.
     rows = jnp.minimum(rows, jnp.int32(wpe.shape[0] - 1))
-    h = jnp.take(wte, toks, axis=0) + jnp.take(wpe, rows, axis=0)
-    h = h.astype(wte.dtype)
+    with jax.named_scope("embed"):
+        h = jnp.take(wte, toks, axis=0) + jnp.take(wpe, rows, axis=0)
+        h = h.astype(wte.dtype)
     new_k, new_v = [], []
     for i in range(num_layers):
-        lp = (tuple(p[i] for p in params), idx[i])
+        lp = _layer_params(params, idx, i)
         h, ck, cv = _slot_cache_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
                                       pos, num_heads=num_heads, active=active)
         new_k.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
         new_v.append(cv)  # noqa: PTA104 (static unroll, host loop bound)
-    mean = jnp.mean(h, axis=-1, keepdims=True)
-    var = jnp.var(h, axis=-1, keepdims=True)
-    h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
-    logits = jnp.einsum("bsd,vd->bsv", h, wte)
+    with jax.named_scope("norm"):
+        mean = jnp.mean(h, axis=-1, keepdims=True)
+        var = jnp.var(h, axis=-1, keepdims=True)
+        h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
+    with jax.named_scope("head_loss"):
+        logits = jnp.einsum("bsd,vd->bsv", h, wte)
     return logits, _kv_stack(new_k), _kv_stack(new_v)
 
 
@@ -776,28 +833,36 @@ def _chunk_prefill_block(lp, h, ck, cv, slot, start, *, num_heads, epsilon=1e-5)
     H = raw.shape[1]
     S = raw.shape[2]
     hd = d // num_heads
-    x1 = ln(h, n1w, n1b)
-    qkv = (x1 @ qkvw + qkvb).reshape(1, s, 3, num_heads, hd)
-    q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [1, H, C, dh]
-    k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-    v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-    ck = _kvc_update(ck, k, (slot, 0, start, 0))
-    cv = _kvc_update(cv, v, (slot, 0, start, 0))
-    rk = _kvc_read(_kvc_slice(ck, (slot, 0, 0, 0), (1, H, S, hd)), h.dtype)
-    rv = _kvc_read(_kvc_slice(cv, (slot, 0, 0, 0), (1, H, S, hd)), h.dtype)
-    scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
-                        preferred_element_type=jnp.float32)
-    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, S), 1)
-    scores = jnp.where((k_pos <= q_pos)[None, None], scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
-    att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
-    att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(1, s, d)
-    h = h + att @ ow + ob
-    x2 = ln(h, n2w, n2b)
-    y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-    h = h + y @ f2w + f2b
+    with jax.named_scope("norm"):
+        x1 = ln(h, n1w, n1b)
+    with jax.named_scope("attn_qkv"):
+        qkv = (x1 @ qkvw + qkvb).reshape(1, s, 3, num_heads, hd)
+        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [1, H, C, dh]
+        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
+        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
+    with jax.named_scope("cache_write"):
+        ck = _kvc_update(ck, k, (slot, 0, start, 0))
+        cv = _kvc_update(cv, v, (slot, 0, start, 0))
+    with jax.named_scope("cache_read"):
+        rk = _kvc_read(_kvc_slice(ck, (slot, 0, 0, 0), (1, H, S, hd)), h.dtype)
+        rv = _kvc_read(_kvc_slice(cv, (slot, 0, 0, 0), (1, H, S, hd)), h.dtype)
+    with jax.named_scope("attn_core"):
+        scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
+                            preferred_element_type=jnp.float32)
+        q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, S), 1)
+        scores = jnp.where((k_pos <= q_pos)[None, None], scores, -jnp.inf)
+        p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
+        att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
+        att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(1, s, d)
+    with jax.named_scope("attn_out"):
+        h = h + att @ ow + ob
+    with jax.named_scope("norm"):
+        x2 = ln(h, n2w, n2b)
+    with jax.named_scope("mlp"):
+        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
+        h = h + y @ f2w + f2b
     return h, ck, cv
 
 
@@ -814,12 +879,13 @@ def _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v,
     params, idx = stacked
     num_layers = params[0].shape[0]
     s = ids.shape[1]
-    pos = start + jnp.arange(s, dtype=jnp.int32)
-    h = jnp.take(wte, ids, axis=0) + jnp.take(wpe, pos, axis=0)[None]
-    h = h.astype(wte.dtype)
+    with jax.named_scope("embed"):
+        pos = start + jnp.arange(s, dtype=jnp.int32)
+        h = jnp.take(wte, ids, axis=0) + jnp.take(wpe, pos, axis=0)[None]
+        h = h.astype(wte.dtype)
     new_k, new_v = [], []
     for i in range(num_layers):
-        lp = (tuple(p[i] for p in params), idx[i])
+        lp = _layer_params(params, idx, i)
         h, ck, cv = _chunk_prefill_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
                                          slot, start, num_heads=num_heads)
         new_k.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
@@ -828,11 +894,13 @@ def _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v,
     cache_v = _kv_stack(new_v)
     if last_row is None:
         return None, cache_k, cache_v
-    hl = jax.lax.dynamic_slice(h, (0, last_row, 0), (1, 1, h.shape[2]))
-    mean = jnp.mean(hl, axis=-1, keepdims=True)
-    var = jnp.var(hl, axis=-1, keepdims=True)
-    hl = (hl - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
-    logits = jnp.einsum("bsd,vd->bsv", hl, wte)[:, 0]  # [1, V]
+    with jax.named_scope("norm"):
+        hl = jax.lax.dynamic_slice(h, (0, last_row, 0), (1, 1, h.shape[2]))
+        mean = jnp.mean(hl, axis=-1, keepdims=True)
+        var = jnp.var(hl, axis=-1, keepdims=True)
+        hl = (hl - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
+    with jax.named_scope("head_loss"):
+        logits = jnp.einsum("bsd,vd->bsv", hl, wte)[:, 0]  # [1, V]
     return logits, cache_k, cache_v
 
 
@@ -921,7 +989,8 @@ class GPTEmbeddings(nn.Layer):
             from ..tensor.creation import arange
 
             position_ids = arange(0, input_ids.shape[1], dtype="int32")
-        return self.dropout(self.word_embeddings(input_ids) + self.position_embeddings(position_ids))
+        with jax.named_scope("embed"):
+            return self.dropout(self.word_embeddings(input_ids) + self.position_embeddings(position_ids))
 
 
 class GPTModel(nn.Layer):
@@ -947,7 +1016,8 @@ class GPTModel(nn.Layer):
         else:
             for blk in self.layers:
                 h = self._block_maybe_remat(blk, h)
-        return self.final_norm(h)
+        with jax.named_scope("norm"):
+            return self.final_norm(h)
 
     def _block_maybe_remat(self, blk, h):
         # honor cfg.recompute on the per-layer trunk too (the stacked path
@@ -1048,7 +1118,8 @@ class GPTForPretraining(nn.Layer):
 
         # tied head: h @ wte^T; vocab axis stays mp-sharded for the
         # vocab-parallel loss (c_softmax_with_cross_entropy parity)
-        logits = matmul(h, self.gpt.embeddings.word_embeddings.weight, transpose_y=True)
+        with jax.named_scope("head_loss"):
+            logits = matmul(h, self.gpt.embeddings.word_embeddings.weight, transpose_y=True)
         if self.gpt.cfg.moe_num_experts:
             # GPT-MoE: the GShard balancing loss rides the outputs so the
             # criterion (and any compiled step) sees it — no side channel
@@ -1193,6 +1264,10 @@ class GPTPretrainingCriterion(nn.Layer):
         self.moe_aux_coef = moe_aux_coef
 
     def forward(self, logits, labels, loss_mask=None):
+        with jax.named_scope("head_loss"):
+            return self._loss(logits, labels, loss_mask)
+
+    def _loss(self, logits, labels, loss_mask):
         from ..tensor.math import mean, multiply, sum as t_sum
         from ..tensor.manipulation import reshape
 

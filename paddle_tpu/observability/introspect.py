@@ -12,13 +12,23 @@ flattened (CPU builds omit fields) into one dict::
 ``Executor.explain()`` / ``TrainStep.explain()`` return one such row per
 cached specialization; :func:`format_cost_table` renders them for humans
 (bench.py prints it).
+
+The retained handles also join a device trace back to the model:
+:func:`op_scopes` maps each compiled program's HLO instruction names
+(``fusion.939`` — what a profiler's ``XLA Ops`` event is called) to the
+``op_name`` path in that instruction's metadata, which carries the
+``jax.named_scope`` names of the code that produced it
+(``jit(_step)/jit(main)/transpose(jvp(norm))/...``). The trace itself has no
+``op_name``, so the join goes through the program's own optimized HLO text.
 """
 from __future__ import annotations
 
+import re
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["cost_summary", "aot_compile", "format_cost_table"]
+__all__ = ["cost_summary", "aot_compile", "format_cost_table", "note_program",
+           "op_scopes", "parse_op_names"]
 
 _PARTITION_RE = None
 
@@ -29,6 +39,44 @@ _PARTITION_RE = None
 # zero recompile. Bounded; single-device programs ALSO persist to disk.
 _EXEC_MEMO: dict = {}
 _EXEC_MEMO_CAP = 8
+
+
+# program name ("train_step/step", "infer/decode", ...) -> the executable's
+# Compiled handle until op_scopes() first reads it, then the parsed map. A
+# handle holds the executable and its signature — no argument, no state
+# buffer — so it outlives the TrainStep or engine that built it at no cost in
+# device memory beyond the program's code.
+_PROGRAMS: Dict[str, Any] = {}
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*?metadata=\{[^}\n]*?op_name="([^"]*)"', re.M)
+
+
+def note_program(name: str, compiled) -> None:
+    """Remember the executable behind program ``name`` for :func:`op_scopes`.
+    Called where a program is compiled or AOT-loaded; costs one dict write —
+    the HLO text is read on the first ``op_scopes()`` call, not here. A later
+    specialization of the same program replaces the earlier one."""
+    if hasattr(compiled, "as_text"):
+        _PROGRAMS[name] = compiled  # noqa: PTA104 (host-side, never traced)
+
+
+def parse_op_names(hlo_text: str) -> Dict[str, str]:
+    """``instruction name -> op_name`` for every instruction of an optimized
+    HLO module's text that carries ``metadata={op_name="..."}``."""
+    return dict(_HLO_OP_NAME.findall(hlo_text))
+
+
+def op_scopes() -> Dict[str, Dict[str, str]]:
+    """Per program this process has compiled or AOT-loaded, the map from HLO
+    instruction name to the ``op_name`` path of its metadata (see the module
+    docstring). Parsed from the retained handle's optimized HLO text on the
+    first call that finds it; the handle is dropped once parsed."""
+    out = {}
+    for name, entry in list(_PROGRAMS.items()):  # noqa: PTA102 (host-side, never traced)
+        if not isinstance(entry, dict):
+            entry = _PROGRAMS[name] = parse_op_names(entry.as_text())  # noqa: PTA104 (host-side, never traced)
+        out[name] = entry  # noqa: PTA104 (host-side, never traced)
+    return out
 
 
 def _no_persistent_compile_cache():

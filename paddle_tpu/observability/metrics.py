@@ -26,7 +26,7 @@ import threading
 from typing import Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
-    "Histogram", "counter_inc", "counters", "reset_counters", "gauge_set",
+    "Histogram", "counter_inc", "counter", "counters", "reset_counters", "gauge_set",
     "gauges", "observe", "histogram", "histograms", "declare_counter",
     "declare_histogram", "declare_help", "snapshot", "prometheus_text",
     "escape_help", "escape_label_value", "reset_all",
@@ -145,6 +145,11 @@ class Histogram:
 def counter_inc(name: str, n: float = 1) -> None:
     """Bump a named monotonic counter (lock-free single-dict hot path)."""
     _COUNTERS[name] = _COUNTERS.get(name, 0) + n
+
+
+def counter(name: str) -> float:
+    """One counter's value (0 for a series nothing has bumped yet)."""
+    return _COUNTERS.get(name, 0)
 
 
 def counters(prefix: str = "") -> Dict[str, float]:
@@ -354,8 +359,7 @@ KNOWN_GAUGES: Tuple[str, ...] = (
 )
 
 KNOWN_HISTOGRAMS: Tuple[str, ...] = (
-    "infer.tokens_per_decode_dispatch",
-    "serving.prefill_stall_seconds", "serving.ttft_seconds",
+    "serving.ttft_seconds",
     "serving.queue_seconds", "serving.latency_seconds",
     "fleet.latency_seconds",
     # network ingress (PR 20): wall time of one HTTP request end-to-end

@@ -1,16 +1,24 @@
-"""Step timeline spans: nestable timed sections that feed three sinks.
+"""The span: one timed section with a start, an end and a parent.
 
-A ``span("executor.dispatch")`` block:
+``with span("infer.sched.step"): ...`` records one :class:`Span` — name,
+``start_ns``/``end_ns`` on ``time.perf_counter_ns()``, its own id, the id of
+the span that was open on the same thread when it started (one thread-local
+stack), and optionally a trace id and a few small attributes. On exit the
+span
 
-1. opens a :class:`paddle_tpu.profiler.RecordEvent` — so the section shows
-   up in the device trace (``jax.profiler.TraceAnnotation``), the native
-   host tracer, and ``Profiler.export``'s chrome trace when a profiling
-   session is active;
-2. records its wall duration into the bounded histogram metric of the same
-   name (``metrics.observe``) — so steady-state percentiles are available
-   without any profiler session;
-3. optionally carries attributes for the caller to stuff into a run-log
-   event (the span object exposes ``seconds`` after exit).
+1. closes its :class:`paddle_tpu.profiler.RecordEvent` — a
+   ``jax.profiler.TraceAnnotation``, so the same interval lies on the
+   profiler's clock beside the device ops (plus the native host tracer and
+   ``Profiler.export``'s chrome trace while a session is active);
+2. is appended to a bounded in-memory ring — :func:`recent` reads it back,
+   :func:`self_time` gives each span's duration minus what its children
+   cover;
+3. records its duration into the histogram metric of the same name;
+4. when it belongs to a trace (its own ``trace_id``, or the enclosing
+   context's — :mod:`.trace`), emits the run-log ``span`` event.
+
+:func:`.trace.trace_span` and :func:`.trace.span_event` build the same record
+and leave through the same exit path.
 
 Gated by ``FLAGS_monitor``: when the flag is off, ``span(...)`` returns a
 shared no-op context whose enter/exit are two attribute lookups — the hot
@@ -18,59 +26,112 @@ paths keep their instrumentation unconditionally.
 """
 from __future__ import annotations
 
+import itertools
+import threading
 import time
-from typing import Optional
+from collections import deque
+from typing import Dict, Iterable, List, Optional
 
 from ..framework.flags import flag
 from . import metrics
 
-__all__ = ["span", "Span"]
+__all__ = ["span", "Span", "recent", "self_time", "RING_CAPACITY"]
+
+# A 51-s window of the serving cells is under 10k spans (about 900 ticks of
+# eight); the ring keeps several windows.
+RING_CAPACITY = 65536
+_RING: deque = deque(maxlen=RING_CAPACITY)
+_IDS = itertools.count(1)
+
+
+class _Stack(threading.local):
+    def __init__(self):
+        self.open = []   # innermost last: Span, or trace._Attach (a context without a duration)
+
+
+_TLS = _Stack()
 
 
 class Span:
-    """One timed section. Use via ``with span(name): ...``; after exit,
-    ``seconds`` holds the wall duration (also recorded into the histogram
-    metric ``name``) and ``error`` is True when the body raised.
+    """One timed section. Use via ``with span(name): ...``; after exit
+    ``start_ns``/``end_ns``/``seconds`` hold the interval and ``error`` is
+    True when the body raised.
 
-    Exit is **exception-safe**: a raising body still closes the
-    RecordEvent (so the chrome-trace nesting stays balanced for the next
-    span), still records the histogram observation, and — when a trace
-    context is attached (:mod:`.trace`) — emits the span's run-log event
+    ``span_id`` is a process-wide integer, or a deterministic 16-hex id
+    (:func:`.trace.new_span_id`) when the span belongs to a trace and so
+    reaches the run log. ``parent_id`` is the id of whatever was innermost on
+    this thread's stack at entry.
+
+    Exit is **exception-safe**: a raising body still pops the stack, closes
+    the RecordEvent (so the chrome-trace nesting stays balanced for the next
+    span), reaches the ring and the histogram, and emits its run-log event
     with ``error=true``. The original exception always propagates."""
 
-    __slots__ = ("name", "seconds", "error", "_t0", "_re")
+    __slots__ = ("name", "start_ns", "end_ns", "span_id", "parent_id", "trace_id",
+                 "attrs", "error", "_re")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, trace_id: Optional[str] = None, attrs: Optional[dict] = None):
         self.name = name
-        self.seconds: Optional[float] = None
+        self.trace_id = trace_id
+        self.attrs = attrs
+        self.start_ns = self.end_ns = 0
+        self.span_id = self.parent_id = None
         self.error = False
-        self._t0 = 0
         self._re = None
+
+    @property
+    def seconds(self) -> Optional[float]:
+        return (self.end_ns - self.start_ns) / 1e9 if self.end_ns else None
+
+    def _link(self):
+        """Take parent and trace from the innermost open entry; a span of a
+        trace gets the id its run-log event will carry."""
+        stack = _TLS.open
+        if stack:
+            self.parent_id = stack[-1].span_id  # noqa: PTA104 (host-side, never traced)
+            if self.trace_id is None:
+                self.trace_id = stack[-1].trace_id  # noqa: PTA104 (host-side, never traced)
+        if self.trace_id is not None:
+            from . import trace as _trace
+
+            if _trace.enabled():
+                self.span_id = _trace.new_span_id()  # noqa: PTA104 (host-side, never traced)
+                return
+        self.span_id = next(_IDS)
 
     def __enter__(self):
         from ..profiler import RecordEvent
 
+        self._link()
+        _TLS.open.append(self)
         self._re = RecordEvent(self.name)
         self._re.begin()
-        self._t0 = time.perf_counter_ns()
+        self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dt = (time.perf_counter_ns() - self._t0) / 1e9
+        self.end_ns = time.perf_counter_ns()
         self.error = exc_type is not None
         try:
-            if self._re is not None:
-                self._re.end()
+            self._re.end()
         finally:
             self._re = None
-            self.seconds = dt
-            metrics.observe(self.name, dt)
-            from . import trace as _trace
-
-            if _trace.current_trace() is not None:
-                _trace.span_event(self.name, trace_id=_trace.current_trace(),
-                                  seconds=dt, error=self.error)
+            _TLS.open.pop()
+            self._record()
         return False
+
+    def _record(self):
+        """The one exit path: ring, histogram, run-log event."""
+        _RING.append(self)
+        metrics.observe(self.name, (self.end_ns - self.start_ns) / 1e9)
+        if isinstance(self.span_id, str):
+            from . import runlog as _runlog
+
+            metrics.counter_inc("trace.spans")
+            parent = self.parent_id if isinstance(self.parent_id, str) else None
+            _runlog.emit("span", name=self.name, trace=self.trace_id, span=self.span_id,
+                         parent=parent, start=self.start_ns / 1e9, seconds=self.seconds,
+                         error=self.error, **(self.attrs or {}))
 
 
 class _NullSpan:
@@ -78,7 +139,7 @@ class _NullSpan:
 
     __slots__ = ()
     name = ""
-    seconds = None
+    trace_id = span_id = parent_id = seconds = attrs = None
     error = False
 
     def __enter__(self):
@@ -91,9 +152,37 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
-def span(name: str):
+def span(name: str, **attrs):
     """A timed section context: real :class:`Span` when FLAGS_monitor is
-    on, the shared no-op otherwise."""
+    on, the shared no-op otherwise. ``attrs`` are a few small values kept on
+    the record (``slots=8``)."""
     if not flag("FLAGS_monitor"):
         return _NULL
-    return Span(name)
+    return Span(name, attrs=attrs or None)
+
+
+def recent(since_ns: int = 0, until_ns: Optional[int] = None) -> List[Span]:
+    """Finished spans still in the ring that ended after ``since_ns`` (and
+    at or before ``until_ns``), oldest first, on ``time.perf_counter_ns()``."""
+    return [s for s in list(_RING)
+            if s.end_ns > since_ns and (until_ns is None or s.end_ns <= until_ns)]
+
+
+def self_time(records: Iterable[Span]) -> Dict[object, int]:
+    """``span_id -> ns`` for every record: its duration minus the union of
+    its children's intervals (the records whose ``parent_id`` it is), each
+    clipped to the parent, so overlapping children are subtracted once."""
+    records = list(records)
+    children: Dict[object, list] = {}
+    for r in records:
+        children.setdefault(r.parent_id, []).append(r)  # noqa: PTA104 (host-side, never traced)
+    out = {}
+    for r in records:
+        covered, at = 0, r.start_ns
+        for c in sorted(children.get(r.span_id, ()), key=lambda c: c.start_ns):
+            lo, hi = max(c.start_ns, at), min(c.end_ns, r.end_ns)
+            if hi > lo:
+                covered += hi - lo
+                at = hi
+        out[r.span_id] = (r.end_ns - r.start_ns) - covered  # noqa: PTA104 (host-side, never traced)
+    return out

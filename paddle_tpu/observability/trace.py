@@ -7,11 +7,14 @@ process that touches it: a fleet request carries its id from
 per-chunk prefill, fused decode dispatches, requeue-after-kill, and
 delivery; ``run_resilient`` stamps one id on a whole supervised run so
 every step event and every incident (HOLD, rollback, rescale, resume) of
-that run correlates. A **span** is one timed section inside a trace,
-emitted as a ``span`` run-log event::
+that run correlates. A **span** is one timed section
+(:class:`.spans.Span` — this module adds no span class and no stack of its
+own); one that belongs to a trace is emitted as a ``span`` run-log event::
 
     {"event": "span", "name": ..., "trace": ..., "span": ...,
-     "parent": ..., "seconds": ..., "error": false, ...attrs}
+     "parent": ..., "start": ..., "seconds": ..., "error": false, ...attrs}
+
+(``start`` on the process's ``time.perf_counter()``, like the ring's)
 
 so ``observability report --merge`` / ``observability trace`` reconstruct
 one request's whole path from N processes' run logs.
@@ -42,6 +45,7 @@ from typing import Dict, Optional, Tuple
 from ..framework.flags import flag
 from . import metrics
 from . import runlog as _runlog
+from . import spans as _spans
 
 __all__ = [
     "enabled", "new_trace_id", "new_span_id", "current_trace",
@@ -51,13 +55,6 @@ __all__ = [
 
 EPOCH_KEY_PREFIX = "__obs__"
 
-
-class _TraceState(threading.local):
-    def __init__(self):
-        self.stack = []  # [(trace_id, span_id)] innermost last
-
-
-_TLS = _TraceState()
 
 # One numpy generator per (seed, tag, rank): host_generator returns an
 # identically-seeded stream per call, so successive ids must come from a
@@ -110,18 +107,22 @@ def new_span_id() -> str:
 
 
 def current_trace() -> Optional[str]:
-    """The innermost attached trace id, or None."""
-    return _TLS.stack[-1][0] if _TLS.stack else None
+    """The innermost open context's trace id, or None."""
+    stack = _spans._TLS.open
+    return stack[-1].trace_id if stack else None
 
 
 def current_span() -> Optional[str]:
-    return _TLS.stack[-1][1] if _TLS.stack else None
+    """The innermost open span's (or attached context's) id, or None."""
+    stack = _spans._TLS.open
+    return stack[-1].span_id if stack else None
 
 
 class _Attach:
     """Context manager installing (trace_id, span_id) as the current trace
-    context for the block — exception-safe: the stack pops in ``finally``
-    semantics whether or not the body raised."""
+    context for the block: an entry on the spans' own thread-local stack
+    with no duration of its own — exception-safe, the stack pops whether or
+    not the body raised."""
 
     __slots__ = ("trace_id", "span_id", "_pushed")
 
@@ -132,13 +133,13 @@ class _Attach:
 
     def __enter__(self):
         if self.trace_id is not None:
-            _TLS.stack.append((self.trace_id, self.span_id))  # noqa: PTA104 (host-side, never traced)
+            _spans._TLS.open.append(self)  # noqa: PTA104 (host-side, never traced)
             self._pushed = True  # noqa: PTA104 (host-side, never traced)
         return self
 
     def __exit__(self, *exc):
         if self._pushed:
-            _TLS.stack.pop()  # noqa: PTA104 (host-side, never traced)
+            _spans._TLS.open.pop()  # noqa: PTA104 (host-side, never traced)
             self._pushed = False  # noqa: PTA104 (host-side, never traced)
         return False
 
@@ -150,93 +151,35 @@ def attach(trace_id: Optional[str], span_id: Optional[str] = None) -> _Attach:
     return _Attach(trace_id, span_id)
 
 
-class TraceSpan:
-    """One timed, trace-linked section. On exit — exception-safe — it pops
-    the nesting stack, emits a ``span`` run-log event carrying
-    trace/span/parent ids, duration, and ``error`` (true when the body
-    raised), and records the duration histogram."""
-
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "seconds", "error", "_t0", "_pushed")
-
-    def __init__(self, name: str, trace_id: Optional[str] = None, **attrs):
-        self.name = name
-        self.trace_id = trace_id
-        self.span_id: Optional[str] = None
-        self.parent_id: Optional[str] = None
-        self.attrs = attrs
-        self.seconds: Optional[float] = None
-        self.error = False
-        self._t0 = 0
-        self._pushed = False
-
-    def __enter__(self):
-        if self.trace_id is None:
-            self.trace_id = current_trace()  # noqa: PTA104 (host-side, never traced)
-        self.parent_id = current_span()
-        self.span_id = new_span_id()
-        _TLS.stack.append((self.trace_id, self.span_id))
-        self._pushed = True
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        dt = (time.perf_counter_ns() - self._t0) / 1e9
-        self.seconds = dt
-        self.error = exc_type is not None
-        try:
-            span_event(self.name, trace_id=self.trace_id, seconds=dt,
-                       span_id=self.span_id, parent_id=self.parent_id,
-                       error=self.error, **self.attrs)
-            metrics.observe(self.name, dt)
-        finally:
-            if self._pushed:
-                _TLS.stack.pop()  # noqa: PTA104 (host-side, never traced)
-                self._pushed = False  # noqa: PTA104 (host-side, never traced)
-        return False
-
-
-class _NullTraceSpan:
-    """Shared no-op for the tracing-off path."""
-
-    __slots__ = ()
-    name = ""
-    trace_id = span_id = parent_id = seconds = None
-    error = False
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullTraceSpan()
+_NULL = _spans._NULL
 
 
 def trace_span(name: str, trace_id: Optional[str] = None, **attrs):
-    """A trace-linked timed section: a real :class:`TraceSpan` when tracing
-    is enabled, the shared no-op otherwise."""
+    """A trace-linked timed section: a :class:`.spans.Span` carrying
+    ``trace_id`` (or the ambient trace) when tracing is enabled — it emits
+    its ``span`` run-log event on exit like any span of a trace — and the
+    shared no-op otherwise."""
     if not enabled():
         return _NULL
-    return TraceSpan(name, trace_id=trace_id, **attrs)
+    return _spans.Span(name, trace_id=trace_id, attrs=attrs or None)
 
 
 def span_event(name: str, trace_id: Optional[str], seconds: Optional[float] = None,
-               span_id: Optional[str] = None, parent_id: Optional[str] = None,
                error: bool = False, **attrs) -> Optional[str]:
-    """Emit one ``span`` run-log event directly (the cheap spelling for hot
-    loops that already measured their own duration — per-chunk prefill,
-    fused decode). Returns the span id, or None when tracing is off or the
-    event carries no trace linkage at all."""
-    if not enabled() or (trace_id is None and not attrs.get("traces")):
+    """Record one span that the caller timed itself (the cheap spelling for
+    hot loops — per-chunk prefill): the same record as a ``with`` span,
+    ending now and ``seconds`` long, through the same exit path. Returns
+    the span id, or None when tracing is off or there is no trace to link
+    it to."""
+    if not enabled() or (trace_id is None and current_trace() is None):
         return None
-    sid = span_id or new_span_id()
-    metrics.counter_inc("trace.spans")
-    _runlog.emit("span", name=name, trace=trace_id, span=sid,
-                 parent=parent_id if parent_id is not None else current_span(),
-                 seconds=seconds, error=bool(error), **attrs)
-    return sid
+    sp = _spans.Span(name, trace_id=trace_id, attrs=attrs or None)
+    sp._link()
+    sp.end_ns = time.perf_counter_ns()
+    sp.start_ns = sp.end_ns - int((seconds or 0.0) * 1e9)
+    sp.error = bool(error)
+    sp._record()
+    return sp.span_id
 
 
 def sync_clocks(store, rank: int, world_size: int,

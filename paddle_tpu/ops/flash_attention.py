@@ -165,6 +165,7 @@ def _flash_fwd(q, k, v, causal):
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=_INTERPRET,
     )(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2), lse
@@ -272,6 +273,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal):
         in_specs=row_specs,
         out_specs=pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+        name="flash_bwd_dq",
         interpret=_INTERPRET,
     )(qt, kt, vt, dot, lse, di)
 
@@ -295,6 +297,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal):
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=_INTERPRET,
     )(qt, kt, vt, dot, lse, di)
 

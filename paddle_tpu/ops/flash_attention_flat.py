@@ -284,6 +284,7 @@ def _fwd_call(operands, b, s, h, d, dtype, causal, packed):
             jax.ShapeDtypeStruct((b, s, h * d), dtype),
             jax.ShapeDtypeStruct((b, G, s, hg), jnp.float32),
         ],
+        name="flash_flat_fwd",
         interpret=_INTERPRET,
     )(*operands, *( [bias] if bias is not None else [] ))
     return out, lse
@@ -356,6 +357,7 @@ def _bwd_call(operands, b, s, h, d, dtype, o, lse, do, causal, packed):
             jax.ShapeDtypeStruct((b, s, h * d), dtype),
             jax.ShapeDtypeStruct((b, s, h * d), dtype),
         ],
+        name="flash_flat_bwd",
         interpret=_INTERPRET,
     )(*operands, *extra_ops)
     return dq.astype(dtype), dk, dv

@@ -386,6 +386,7 @@ def _grouped_ffn_fwd(xg, w1, b1, w2, b2, act, bm, bh):
                 jax.ShapeDtypeStruct((R, D), xg.dtype),
                 jax.ShapeDtypeStruct((R, H), jnp.float32),
             ],
+            name="moe_fwd_small",
             interpret=_INTERPRET,
         )(xg, w1, b1, w2, b2f)
         return y, (xg, w1, b1, w2, b2, s)
@@ -397,6 +398,7 @@ def _grouped_ffn_fwd(xg, w1, b1, w2, b2, act, bm, bh):
         ],
         out_specs=_row_specs(bm, D, "g_outer"),
         out_shape=jax.ShapeDtypeStruct((R, D), jnp.float32),
+        name="moe_fwd",
         interpret=_INTERPRET,
     )(xg, w1, b1, w2, b2f)
     return y.astype(xg.dtype), (xg, w1, b1, w2, b2, None)
@@ -440,6 +442,7 @@ def _grouped_ffn_bwd(act, bm, bh, res, dy):
                 jax.ShapeDtypeStruct((E, H, D), jnp.float32),
                 jax.ShapeDtypeStruct((E, 1, D), jnp.float32),
             ],
+            name="moe_bwd_fused",
             interpret=_INTERPRET,
         )(xg, dyc, s_res, w1, w2)
         return (dx.astype(xg.dtype), dw1.astype(w1.dtype), db1.astype(b1.dtype),
@@ -458,6 +461,7 @@ def _grouped_ffn_bwd(act, bm, bh, res, dy):
             jax.ShapeDtypeStruct((R, D), jnp.float32),
             jax.ShapeDtypeStruct((E, 1, D), jnp.float32),
         ],
+        name="moe_bwd_dx",
         interpret=_INTERPRET,
     )(xg, dyc, w1, b1, w2)
 
@@ -477,6 +481,7 @@ def _grouped_ffn_bwd(act, bm, bh, res, dy):
             jax.ShapeDtypeStruct((E, 1, H), jnp.float32),
             jax.ShapeDtypeStruct((E, H, D), jnp.float32),
         ],
+        name="moe_bwd_dw",
         interpret=_INTERPRET,
     )(xg, dyc, w1, b1, w2)
 

@@ -209,13 +209,27 @@ class TestFleetTracePath:
         # the full story, in order, under ONE trace id
         for a, b in [("fleet.submitted", "fleet.placed"),
                      ("fleet.placed", "serving.prefill_chunk"),
-                     ("serving.prefill_chunk", "serving.decode"),
-                     ("serving.decode", "fleet.replica_dead"),
+                     ("serving.prefill_chunk", "fleet.replica_dead"),
                      ("fleet.replica_dead", "fleet.requeue"),
                      ("fleet.requeue", "fleet.finished")]:
             assert path.index(a) < path.index(b), (a, b, path)
         assert path.count("fleet.placed") == 2  # killed replica + rescuer
         assert path[-1] == "fleet.finished"
+        # no per-tick event names the requests a decode dispatch served: the
+        # decode phase of a request is the tick spans between its first token
+        # and its finish, which the span ring holds (slots = slots decoding)
+        assert "serving.decode" not in {_label(e) for e in events}
+        freq = next(f for f in done.values() if f.trace_id == tid)
+        lo, hi = int(freq.first_token_ts * 1e9), int(freq.finished_ts * 1e9)
+        ticks = [s for s in obs.spans.recent(since_ns=lo, until_ns=hi)
+                 if s.name == "infer.sched.drain" and s.attrs["slots"] >= 1]
+        steps = [s for s in obs.spans.recent(since_ns=lo, until_ns=hi)
+                 if s.name == "infer.decode_step"]
+        assert ticks and len(steps) == len(ticks)
+        assert len(freq.tokens) - 1 <= KW["fuse"] * len(steps)
+        # and the run log's span events now say when they started
+        chunk = next(e for e in events if e.get("name") == "serving.prefill_chunk")
+        assert chunk["start"] > 0 and chunk["seconds"] >= 0
 
         # every submission got its own trace id; all six delivered
         finished = [e for e in events
