@@ -12,7 +12,7 @@ with a non-zero exit code:
 3. *serve*   — the trained model in bf16 through ``DecodeEngine`` ->
    ``ContinuousBatchingScheduler`` -> a one-replica ``ServingFleet`` ->
    ``ServingIngress`` on a loopback port, greedy tokens held to
-   ``model.generate()``;
+   ``model.generate()``, the decode step through the aliased cache kernel;
 4. *kernels* — forward+backward parity of every Pallas kernel a supported
    model can reach against its plain XLA reference.
 
@@ -331,6 +331,10 @@ def phase_serve(model, seed, cache_events):
     infer = metrics.counters("infer.")
     programs = infer.get("infer.compiles", 0) + infer.get("infer.aot_cache_hits", 0)
     check(programs == 3, f"expected 3 programs (chunk, final chunk, decode), got {infer}")
+    kernels = {k_: v for k_, v in metrics.counters("kernels.decode_attention.").items() if v}
+    check(kernels.get("kernels.decode_attention.picked", 0) > 0
+          and not kernels.get("kernels.decode_attention.fallback", 0),
+          f"the decode step did not pick the aliased cache kernel: {kernels}")
     emit("serve", config=f"h{MODEL['hidden_size']}L{MODEL['num_layers']}/bf16/"
                          f"slots{SERVE['slots']}s{MODEL['max_seq_len']}c{SERVE['prefill_chunk']}",
          requests=len(prompts), prompt_lens=list(SERVE["prompt_lens"]), tokens=got,
@@ -338,7 +342,7 @@ def phase_serve(model, seed, cache_events):
          matches_generate=[g == w for g, w in zip(got, want)], bf16_ties=ties,
          ttft_cold_seconds=ttft_cold, ttft_warm_seconds=ttft_warm,
          compiles=infer.get("infer.compiles", 0),
-         aot_cache_hits=infer.get("infer.aot_cache_hits", 0),
+         aot_cache_hits=infer.get("infer.aot_cache_hits", 0), kernels=kernels,
          compile_cache=cache_events.take())
 
 

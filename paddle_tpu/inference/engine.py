@@ -24,9 +24,16 @@ request stream:
   compiled ``dynamic_update_slice`` program per chunk — no prefill compute
   or compile for the shared portion.
 
-Both cache buffers (and the slot state) are donated — the XLA executable
-updates them in place, so cache memory stays flat for the life of the
-engine. Compiles run through the observability AOT ``lower().compile()``
+Both cache buffers (and the slot state) are donated, so what the engine
+*holds* stays flat for its life. Whether a program also updates them in
+place is the program's: the decode step on the TPU does (the
+``decode_attention`` registry entry's aliased kernel writes a slot's new rows
+into the stacked cache where it is stored, and the compiled step holds
+temporaries of a fraction of the cache); the lax programs — prefill, chunked
+prefill, and decode on other devices, for an int8 cache or under a mesh — cut
+each layer out of the stack, re-lay it out and stack it again, with
+temporaries larger than the cache (PERF.md §5, §6 "Memory").
+Compiles run through the observability AOT ``lower().compile()``
 path, so ``explain()`` answers cost/memory questions, the
 ``infer.compiles`` counter pins the program-family size in tests, and — with
 ``FLAGS_compile_cache_dir`` set — every executable is serialized to disk

@@ -34,6 +34,7 @@ from ..distributed.mp_layers import (
 from ..framework import random as _random
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..ops import registry as _registry
 from ..tensor import manipulation as M
 
 
@@ -586,10 +587,13 @@ def _cache_block(lp, h, ck, cv, start_pos, *, num_heads, epsilon=1e-5):
     """One decoder block with a fixed-size KV cache.
 
     h [b, s, d] (s = prompt len at prefill, 1 at decode); ck/cv
-    [b, H, S, dh] (head-major so per-step attention reads the cache
-    contiguously per head — the [b, S, H, dh] layout forced XLA to relayout
-    the whole cache every decode step) hold keys/values for positions
-    < start_pos and are updated in place at [start_pos, start_pos+s).
+    [b, H, S, dh] (head-major, so that a head's rows are contiguous; this
+    did not spare the lax programs a relayout: compiled for the v5e, the
+    [b, S, H, dh] layout was re-laid-out whole every step, and so is this
+    one, layer by layer, for the q=1 dot — PERF.md §5; the decode step's way
+    out is the aliased kernel of :func:`_slot_window_forward`) hold
+    keys/values for positions < start_pos and are updated at
+    [start_pos, start_pos+s).
     Attention masks cache positions beyond start_pos+row. Scores run as
     bf16×bf16→f32 MXU dots (preferred_element_type) — no f32 cache
     materialization. Returns (h, ck, cv). Parity: the per-layer decode of
@@ -678,40 +682,17 @@ def _cache_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v, start_pos
     return logits, _kv_stack(new_k), _kv_stack(new_v)
 
 
-def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, epsilon=1e-5, active=None):
-    """One decoder block over PER-SLOT cache positions (continuous-batching
-    decode). ``h`` [b, W, d] holds a W-token window per batch slot (W=1 for
-    plain decode, W=K+1 for the speculative verification forward); ``ck``/
-    ``cv`` [b, H, S, dh] (or int8 packs); ``pos`` [b] int32 is each slot's
-    write index for window row 0. The window's K/V are written at
-    ``pos[b]`` via a vmapped ``dynamic_update_slice`` (write BEFORE attend,
-    so a stale cache entry — including a speculative window's rejected
-    tail — is always overwritten before it can become visible) and row j
-    attends keys up to ``pos[b] + j`` — slots at different sequence depths
-    share one compiled program. ``active`` [b] bool gates the write per
-    slot: an inactive slot's cache stays bitwise untouched, so decode
-    dispatches interleaved with another slot's chunked prefill cannot
-    clobber its freshly written K/V at a stale ``pos``. Same per-row math
-    as :func:`_cache_block` at s=1 (the bitwise basis of both the chunked-
-    prefill and the greedy speculative-decoding pins).
-    """
-    (n1w, n1b, qkvw, qkvb, ow, ob, n2w, n2b, f1w, f1b, f2w, f2b), _ = lp
-
-    def ln(v, w, bb):
-        mean = jnp.mean(v, axis=-1, keepdims=True)
-        var = jnp.var(v, axis=-1, keepdims=True)
-        return (v - mean) / jnp.sqrt(var + epsilon) * w + bb
-
-    b, s, d = h.shape
+def _slot_write_attend(q, k, v, ck, cv, pos, active, layer=None):
+    """The lax write-and-attend of one layer's cache: the ``decode_attention``
+    registry entry's fallback. ``q``/``k``/``v`` [b, H, W, dh]; ``ck``/``cv``
+    [b, H, S, dh] (or int8 packs) are ONE layer, cut from the stack by the
+    caller (so ``layer``, which the kernel needs to find it in the stack, is
+    not looked at). The window's K/V are written at ``pos[b]`` via a vmapped
+    ``dynamic_update_slice``, gated per slot by ``active`` (None: every
+    slot), then row j attends keys up to ``pos[b] + j``. Returns
+    (att [b, H, W, dh] in q's dtype, ck, cv)."""
+    b, _, s, hd = q.shape
     S = (ck["q"] if isinstance(ck, dict) else ck).shape[2]
-    hd = d // num_heads
-    with jax.named_scope("norm"):
-        x1 = ln(h, n1w, n1b)
-    with jax.named_scope("attn_qkv"):
-        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
-        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, W, dh]
-        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
     if active is None:
         with jax.named_scope("cache_write"):
             ck = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(ck, k, pos)
@@ -733,8 +714,8 @@ def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, epsilon=1e-5, active=Non
             ck = jax.vmap(upd)(ck, k, pos, active)
             cv = jax.vmap(upd)(cv, v, pos, active)
     with jax.named_scope("cache_read"):
-        rk = _kvc_read(ck, h.dtype)
-        rv = _kvc_read(cv, h.dtype)
+        rk = _kvc_read(ck, q.dtype)
+        rv = _kvc_read(cv, q.dtype)
     with jax.named_scope("attn_core"):
         scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
         scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
@@ -745,7 +726,67 @@ def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, epsilon=1e-5, active=Non
         scores = jnp.where(visible[:, None], scores, -jnp.inf)
         p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
         att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
-        att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
+    return att.astype(q.dtype), ck, cv
+
+
+def _decode_attention_impl(cache, window):
+    """The ``decode_attention`` registry entry's choice for a stacked cache
+    (array or int8 pack) and a window of ``window`` tokens: the aliased
+    Pallas kernel of ``ops/decode_attention.py`` on the TPU for a plain-array
+    cache with no mesh, the lax formulation everywhere else. One selection
+    per compiled specialization (``kernels.decode_attention.picked`` /
+    ``.fallback``)."""
+    packed = isinstance(cache, dict)
+    return _registry.select("decode_attention", cache["q"] if packed else cache,
+                            packed=packed, window=int(window))
+
+
+_registry.register(
+    "decode_attention", "xla", _slot_write_attend, fallback=True,
+    doc="lax write-and-attend on one layer cut from the stack (any cache, any device)")
+
+
+def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, attend, layer=None, epsilon=1e-5, active=None):
+    """One decoder block over PER-SLOT cache positions (continuous-batching
+    decode). ``h`` [b, W, d] holds a W-token window per batch slot (W=1 for
+    plain decode, W=K+1 for the speculative verification forward); ``pos``
+    [b] int32 is each slot's write index for window row 0. The window's K/V
+    are written at ``pos[b]`` (write BEFORE attend, so a stale cache entry
+    — including a speculative window's rejected tail — is always
+    overwritten before it can become visible) and row j attends keys up to
+    ``pos[b] + j`` — slots at different sequence depths share one compiled
+    program. ``active`` [b] bool gates the write per slot: an inactive
+    slot's cache stays bitwise untouched, so decode dispatches interleaved
+    with another slot's chunked prefill cannot clobber its freshly written
+    K/V at a stale ``pos``. Same per-row math as :func:`_cache_block` at s=1
+    (the bitwise basis of both the chunked-prefill and the greedy
+    speculative-decoding pins).
+
+    ``attend`` is the ``decode_attention`` registry entry's choice
+    (:func:`_decode_attention_impl`). For the lax one
+    (:func:`_slot_write_attend`) ``ck``/``cv`` are one layer [b, H, S, dh]
+    (or int8 packs); for the aliased kernel they are the whole stack
+    [L, b, H, S, dh] and ``layer`` is the block's index in it: the kernel
+    writes and reads the cache where it is stored.
+    """
+    (n1w, n1b, qkvw, qkvb, ow, ob, n2w, n2b, f1w, f1b, f2w, f2b), _ = lp
+
+    def ln(v, w, bb):
+        mean = jnp.mean(v, axis=-1, keepdims=True)
+        var = jnp.var(v, axis=-1, keepdims=True)
+        return (v - mean) / jnp.sqrt(var + epsilon) * w + bb
+
+    b, s, d = h.shape
+    hd = d // num_heads
+    with jax.named_scope("norm"):
+        x1 = ln(h, n1w, n1b)
+    with jax.named_scope("attn_qkv"):
+        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
+        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, W, dh]
+        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
+        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
+    att, ck, cv = attend(q, k, v, ck, cv, pos, active, layer)
+    att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
     with jax.named_scope("attn_out"):
         h = h + att @ ow + ob
     with jax.named_scope("norm"):
@@ -776,20 +817,30 @@ def _slot_window_forward(stacked, wte, wpe, fnw, fnb, toks, cache_k, cache_v, po
     with jax.named_scope("embed"):
         h = jnp.take(wte, toks, axis=0) + jnp.take(wpe, rows, axis=0)
         h = h.astype(wte.dtype)
-    new_k, new_v = [], []
-    for i in range(num_layers):
-        lp = _layer_params(params, idx, i)
-        h, ck, cv = _slot_cache_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
-                                      pos, num_heads=num_heads, active=active)
-        new_k.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
-        new_v.append(cv)  # noqa: PTA104 (static unroll, host loop bound)
+    impl = _decode_attention_impl(cache_k, W)
+    if impl.fallback:
+        new_k, new_v = [], []
+        for i in range(num_layers):
+            lp = _layer_params(params, idx, i)
+            h, ck, cv = _slot_cache_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
+                                          pos, num_heads=num_heads, active=active, attend=impl.fn)
+            new_k.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
+            new_v.append(cv)  # noqa: PTA104 (static unroll, host loop bound)
+        cache_k, cache_v = _kv_stack(new_k), _kv_stack(new_v)
+    else:
+        # the stacked caches thread through the layers whole: the kernel
+        # writes and reads layer i where it is stored
+        for i in range(num_layers):
+            lp = _layer_params(params, idx, i)
+            h, cache_k, cache_v = _slot_cache_block(lp, h, cache_k, cache_v, pos, num_heads=num_heads,
+                                                    active=active, layer=i, attend=impl.fn)
     with jax.named_scope("norm"):
         mean = jnp.mean(h, axis=-1, keepdims=True)
         var = jnp.var(h, axis=-1, keepdims=True)
         h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
     with jax.named_scope("head_loss"):
         logits = jnp.einsum("bsd,vd->bsv", h, wte)
-    return logits, _kv_stack(new_k), _kv_stack(new_v)
+    return logits, cache_k, cache_v
 
 
 def _slot_decode_forward(stacked, wte, wpe, fnw, fnb, tok, cache_k, cache_v, pos, *, num_heads, active=None):

@@ -252,6 +252,7 @@ KERNEL_COUNTERS: Tuple[str, ...] = (
     "kernels.sdpa.picked", "kernels.sdpa.fallback",
     "kernels.attention_core.picked", "kernels.attention_core.fallback",
     "kernels.moe.picked", "kernels.moe.fallback",
+    "kernels.decode_attention.picked", "kernels.decode_attention.fallback",
 )
 
 # SPMD sharding analyzer (paddle_tpu.analysis.spmd, FLAGS_shard_check):

@@ -19,6 +19,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /
 # touches one, and parallel test workers each describe the topology
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,18 +34,24 @@ from paddle_tpu.ops import flash_attention_flat as ff
 from paddle_tpu.ops import moe_pallas
 
 
-def _topology():
+@pytest.fixture(scope="module")
+def topo():
+    """The described host. Described when the first test of this file runs,
+    never while a module is imported: the process that describes it loads the
+    TPU's library and keeps it, and every test worker imports every file."""
     try:
         from jax.experimental import topologies
 
         return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # no TPU compiler in this installation
-        return exc
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
 
 
-_TOPO = _topology()
-pytestmark = pytest.mark.skipif(isinstance(_TOPO, Exception),
-                                reason=f"cannot describe a v5e:2x2 topology: {_TOPO}")
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """``one_chip(shape, dtype)``: an abstract array on the host's first chip."""
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
 @pytest.fixture(autouse=True)
@@ -72,20 +80,13 @@ def as_tpu(monkeypatch):
     from paddle_tpu.ops import registry
 
     monkeypatch.setattr(paddle.device, "is_tpu", lambda: True)
-    for kernel in ("sdpa", "attention_core", "moe"):
-        registry.clear_cache(kernel)
+    registry.clear_cache()
     yield
-    for kernel in ("sdpa", "attention_core", "moe"):
-        registry.clear_cache(kernel)
+    registry.clear_cache()
 
 
-def _one_chip(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(_TOPO.devices[0]))
-
-
-def _abstract(tree, sharding_of=lambda a: SingleDeviceSharding(_TOPO.devices[0])):
-    return jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=sharding_of(a)), tree)
+def _abstract(tree, one_chip):
+    return jax.tree_util.tree_map(lambda a: one_chip(np.shape(a), a.dtype), tree)
 
 
 def _compile(fn, *args):
@@ -108,15 +109,15 @@ _FLASH = {
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("family", sorted(_FLASH))
-def test_flash_attention_compiles_for_v5e(family, direction):
-    q = _one_chip(_QKV, jnp.bfloat16)
+def test_flash_attention_compiles_for_v5e(one_chip, family, direction):
+    q = one_chip(_QKV, jnp.bfloat16)
     fn = _FLASH[family] if direction == "fwd" else _grad_of(_FLASH[family], (0, 1, 2))
     assert _compile(fn, q, q, q) >= 1
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_moe_dispatch_combine_compiles_for_v5e_at_flagship_width(dtype, direction):
+def test_moe_dispatch_combine_compiles_for_v5e_at_flagship_width(one_chip, dtype, direction):
     """D=1024 / H=4096 / E=8 / top-2: the weight-gradient kernel's [D, tile]
     f32 blocks overran the 16 MiB scoped-VMEM limit at the forward's
     512-wide tile (16.32M bf16, 18.00M f32) until the tile was chosen from D
@@ -128,9 +129,9 @@ def test_moe_dispatch_combine_compiles_for_v5e_at_flagship_width(dtype, directio
     T = 8192 if dtype == "bfloat16" else 1024
     capacity = int(1.25 * T * K / E)
     dt = jnp.dtype(dtype)
-    args = (_one_chip((T, D), dt), _one_chip((T, K), dt), _one_chip((T, K), jnp.int32),
-            _one_chip((E, D, H), dt), _one_chip((E, 1, H), dt),
-            _one_chip((E, H, D), dt), _one_chip((E, 1, D), dt))
+    args = (one_chip((T, D), dt), one_chip((T, K), dt), one_chip((T, K), jnp.int32),
+            one_chip((E, D, H), dt), one_chip((E, 1, H), dt),
+            one_chip((E, H, D), dt), one_chip((E, 1, D), dt))
 
     def moe(tok, gv, gi, w1, b1, w2, b2):
         return moe_pallas.moe_dispatch_combine(tok, gv, gi, None, w1, b1, w2, b2,
@@ -140,10 +141,10 @@ def test_moe_dispatch_combine_compiles_for_v5e_at_flagship_width(dtype, directio
     assert _compile(fn, *args) >= (1 if direction == "fwd" else 2)
 
 
-def test_layer_norm_fused_compiles_for_v5e():
+def test_layer_norm_fused_compiles_for_v5e(one_chip):
     from paddle_tpu.ops.layer_norm import layer_norm_fused
 
-    x, w = _one_chip((8, 1024, 1024), jnp.bfloat16), _one_chip((1024,), jnp.float32)
+    x, w = one_chip((8, 1024, 1024), jnp.bfloat16), one_chip((1024,), jnp.float32)
     _compile(_grad_of(lambda x, w, b: layer_norm_fused(x, w, b, 1e-5), (0, 1, 2)), x, w, w)
 
 
@@ -151,26 +152,79 @@ def test_layer_norm_fused_compiles_for_v5e():
 _WIDE = dict(vocab_size=50304, hidden_size=1024, num_heads=16, max_seq_len=1024)
 
 
-def _wide_model(num_layers, dtype=None):
+def _wide_model(num_layers):
     paddle.seed(0)
-    model = GPTForPretraining(GPTConfig(num_layers=num_layers, **_WIDE))
-    if dtype is not None:
-        model.astype(dtype)
-        model.eval()
-    return model
+    return GPTForPretraining(GPTConfig(num_layers=num_layers, **_WIDE))
 
 
-def test_decode_step_compiles_for_v5e():
-    """``DecodeEngine``'s decode program at h1024 / L16 / 8 slots / S=1024,
-    bf16: the engine is built on the CPU and its own jitted step is lowered
-    for the described chip with the shapes it is dispatched with."""
-    from paddle_tpu.inference import DecodeEngine
+# The serving cells' decode programs (BENCHMARK.json): slots, context, width.
+_DECODE = {"cerebras-gpt-1.3b.serve-longgen": dict(L=24, B=8, H=16, S=2048, D=2048),
+           "gpt2-medium.serve-chat": dict(L=24, B=64, H=16, S=1024, D=1024)}
+_CACHE_MAY_PASS_THROUGH = {"custom-call", "parameter", "get-tuple-element", "bitcast", "tuple"}
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
 
-    engine = DecodeEngine(_wide_model(16, "bfloat16"), max_batch_slots=8, max_seq_len=1024,
-                          prefill_chunk=128)
-    args = (engine._params, engine._ck, engine._cv, engine._pos, engine._tok, engine._active,
-            engine._eos, engine._limit, engine._seed)
-    _compile(engine._decode_jit, *_abstract(args))
+
+def _cache_shaped_ops(hlo_text, L, B, H, S, dh):
+    """``{opcode: count}`` of the instructions whose result, or a member of
+    whose tuple result, has the shape of one layer of the cache or of the
+    whole cache, in either stored order of the last two dimensions."""
+    layer = {(B, H, S, dh), (B, H, dh, S)}
+    shapes = layer | {(L,) + s for s in layer}
+    found = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1)):
+            dims = tuple(int(d) for d in dims.split(","))
+            while len(dims) > 4 and dims[0] == 1:
+                dims = dims[1:]
+            if dims in shapes:
+                found[m.group(2)] = found.get(m.group(2), 0) + 1
+                break
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE))
+def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
+    """The decode forward (``_slot_decode_forward``, the body of the engine's
+    ``decode_fn``) at the two serving cells' shapes, bf16, cache donated,
+    abstract arguments only. It compiles for the described chip with one
+    ``decode_attn`` call a layer; its temporaries stay under a quarter of the
+    cache (the lax program: 1.2x and 1.3x); and nothing in the optimized
+    program but that call makes, copies, scatters into or re-lays-out a
+    buffer the shape of a cache layer or of the cache — at head size 64, where
+    the device stores S in the lanes, as at 128."""
+    from paddle_tpu.models.gpt import _slot_decode_forward
+    from paddle_tpu.observability import metrics
+
+    L, B, H, S, D = (_DECODE[cell][k] for k in "LBHSD")
+    dh, V, bf = D // H, 50304, jnp.bfloat16
+    stack = tuple(one_chip((L,) + shape, bf) for shape in (
+        (D,), (D,), (D, 3 * D), (3 * D,), (D, D), (D,), (D,), (D,), (D, 4 * D), (4 * D,), (4 * D, D), (D,)))
+    cache = one_chip((L, B, H, S, dh), bf)
+
+    def step(stack, idx, wte, wpe, fnw, fnb, tok, ck, cv, pos, active):
+        logits, ck, cv = _slot_decode_forward((stack, idx), wte, wpe, fnw, fnb, tok, ck, cv, pos,
+                                              num_heads=H, active=active)
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1), ck, cv
+
+    metrics.reset_counters("kernels.decode_attention.")
+    compiled = jax.jit(step, donate_argnums=(7, 8)).lower(
+        stack, one_chip((L,), jnp.int32), one_chip((V, D), bf), one_chip((S, D), bf),
+        one_chip((D,), bf), one_chip((D,), bf), one_chip((B,), jnp.int32), cache, cache,
+        one_chip((B,), jnp.int32), one_chip((B,), jnp.bool_)).compile()
+    counts = metrics.counters("kernels.decode_attention.")
+    assert counts["kernels.decode_attention.picked"] == 1 and not counts.get("kernels.decode_attention.fallback")
+    text = compiled.as_text()
+    assert sum("custom-call(" in line and "decode_attn" in line for line in text.splitlines()) == L
+    cache_bytes = 2 * L * B * H * S * dh * 2
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= 0.25 * cache_bytes, memory.temp_size_in_bytes
+    assert memory.alias_size_in_bytes >= cache_bytes       # both caches updated in place
+    touched = _cache_shaped_ops(text, L, B, H, S, dh)
+    assert touched.get("custom-call") == L
+    assert set(touched) <= _CACHE_MAY_PASS_THROUGH, touched
 
 
 def _train_step(model, make_step):
@@ -178,21 +232,21 @@ def _train_step(model, make_step):
     return make_step(model, opt, GPTPretrainingCriterion())
 
 
-def test_train_step_picks_and_compiles_the_flash_kernels_for_v5e(as_tpu):
+def test_train_step_picks_and_compiles_the_flash_kernels_for_v5e(as_tpu, one_chip):
     """``TrainStep``'s whole AMP-O2 step at the flagship width (depth cut to
     one layer: the block is compiled once whatever the depth) with the
     Pallas attention kernels in it, forward and backward."""
     from paddle_tpu.jit import TrainStep
 
     step = _train_step(_wide_model(1), lambda m, o, c: TrainStep(m, o, c, amp_level="O2"))
-    ids = _one_chip((8, 1024), jnp.int32)
-    assert _compile(step._jit, _abstract(step.state), ((ids,), (ids,))) >= 3
+    ids = one_chip((8, 1024), jnp.int32)
+    assert _compile(step._jit, _abstract(step.state, one_chip), ((ids,), (ids,))) >= 3
 
 
 @pytest.mark.parametrize("layout", [dict(dp=2, mp=2, sdp=1, stage=0),
                                     dict(dp=1, mp=2, sdp=2, stage=2)],
                          ids=["dp2xmp2", "sharding2xmp2"])
-def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, layout):
+def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, topo, layout):
     """``fleet.distributed_step`` at the flagship width on the described
     four-chip mesh. A Mosaic kernel cannot be partitioned automatically —
     the compiler's own words are "wrap the call in a shard_map" — so the
@@ -217,7 +271,7 @@ def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, layout):
         step = _train_step(model, fleet.distributed_step)
         # the trace reads the fleet mesh for its sharding constraints and for
         # the kernels' shard_map: hand it the described chips
-        mesh = Mesh(np.array(_TOPO.devices).reshape(fleet.mesh.devices.shape), AXES)
+        mesh = Mesh(np.array(topo.devices).reshape(fleet.mesh.devices.shape), AXES)
         fleet._hcg.mesh = mesh
         mp_specs = {n: p.dist_spec for n, p in model.named_parameters()
                     if getattr(p, "dist_spec", None) is not None}

@@ -34,6 +34,7 @@ def test_chip_smoke_fails_without_a_tpu(tmp_path):
 
 @pytest.fixture
 def tiny_smoke(monkeypatch, tmp_path):
+    from paddle_tpu.ops import decode_attention as da
     from paddle_tpu.ops import flash_attention as fa
     from paddle_tpu.ops import flash_attention_flat as ff
     from paddle_tpu.ops import moe_pallas
@@ -56,7 +57,8 @@ def tiny_smoke(monkeypatch, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     prev_dir = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
-    prior = (fa.set_interpret(True), ff.set_interpret(True), moe_pallas.set_interpret(True))
+    prior = (fa.set_interpret(True), ff.set_interpret(True), moe_pallas.set_interpret(True),
+             da.set_interpret(True))
     from jax.experimental.compilation_cache import compilation_cache
 
     from paddle_tpu.distributed import fleet
@@ -72,6 +74,7 @@ def tiny_smoke(monkeypatch, tmp_path):
     fleet._hcg = prev_hcg
     registry.clear_cache()
     fa.set_interpret(prior[0]), ff.set_interpret(prior[1]), moe_pallas.set_interpret(prior[2])
+    da.set_interpret(prior[3])
     jax.config.update("jax_compilation_cache_dir", prev_dir)
     compilation_cache.reset_cache()
     jax.config.update("jax_default_matmul_precision", "highest")  # conftest's pin
@@ -91,6 +94,7 @@ def test_one_chip_phases_complete_at_a_tiny_size(tiny_smoke, capsys):
     assert train["losses"][-1] < train["losses"][0]
     assert train["kernels"] == {"kernels.attention_core.picked": 1}
     assert serve["tokens_decoded"] == 16 and all(serve["matches_generate"])
+    assert serve["kernels"] == {"kernels.decode_attention.picked": 1}
     assert kernels["selected"] == {"sdpa": "flash", "attention_core": "flash",
                                    "moe": "pallas_sorted"}
 
