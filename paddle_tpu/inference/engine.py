@@ -24,7 +24,18 @@ request stream:
   compiled ``dynamic_update_slice`` program per chunk — no prefill compute
   or compile for the shared portion.
 
-Both cache buffers (and the slot state) are donated, so what the engine
+What depends on the architecture the engine takes from the model's *decoder*
+(``model.decoder()``, the interface of :mod:`paddle_tpu.models.decoder`): the
+parameter pack, the per-slot buffers — rows of keys and values, which
+admission leaves as they are, and for a model with linear-attention layers a
+recurrent state, which the slot's first prefill program zeroes — and the
+prefill, chunk, decode and window forwards over them. GPT
+(``models/gpt.py:GPTDecoder``) and Solar Open 2
+(``models/solar_open2.py:SolarOpen2Decoder``) are its two clients; for a model
+with recurrent state the engine refuses a prefix cache, a draft and an int8
+cache.
+
+The slot buffers (and the slot state) are donated, so what the engine
 *holds* stays flat for its life. Whether a program also updates them in
 place is the program's: the decode step on the TPU does (the
 ``decode_attention`` registry entry's aliased kernel writes a slot's new rows
@@ -73,16 +84,6 @@ def default_buckets(max_seq: int, start: int = 16) -> Tuple[int, ...]:
     return tuple(sorted(set(out)))
 
 
-def _dequant(entry, dt):
-    """A params-pack entry is either a plain array or an int8 payload
-    ``{"q", "s"}``; dequantize the latter to ``dt`` (XLA folds the multiply
-    into the consuming matmul — the QuantizedLinear idiom on raw stacked
-    weights)."""
-    if isinstance(entry, dict):
-        return (entry["q"].astype(jnp.float32) * entry["s"]).astype(dt)
-    return entry
-
-
 def _placed(method):
     """Run an engine method with the engine's device as JAX's default, so
     the host-side scalars and index arrays it builds land beside the cache
@@ -128,8 +129,10 @@ class _PrefillJob:
 class DecodeEngine:
     """Slot-based autoregressive decode over a static KV cache.
 
-    ``model`` is a :class:`~paddle_tpu.models.gpt.GPTForPretraining` with the
-    stacked trunk. ``max_batch_slots`` fixes the decode batch width B: each
+    ``model`` is any model with a ``decoder()`` (:mod:`paddle_tpu.models.decoder`):
+    :class:`~paddle_tpu.models.gpt.GPTForPretraining` with the stacked trunk,
+    :class:`~paddle_tpu.models.solar_open2.SolarOpen2ForCausalLM`.
+    ``max_batch_slots`` fixes the decode batch width B: each
     slot holds one in-flight request, and requests are admitted into free
     slots mid-stream (continuous batching) — admission never recompiles.
 
@@ -184,17 +187,14 @@ class DecodeEngine:
                  prefill_chunk: Optional[int] = None, prefix_cache_mb: float = 0.0,
                  draft=None, spec_k: int = 4, draft_seed: int = 0,
                  kv_dtype: Optional[str] = None, device=None):
-        from ..models.gpt import GPTBlockStack, GPTConfig, _kv_zeros
-
         self._device = device
 
-        if not isinstance(model.gpt.layers, GPTBlockStack):
-            raise NotImplementedError("DecodeEngine requires the stacked trunk (GPTConfig(stacked=True))")
-        cfg = model.gpt.cfg
-        S = int(max_seq_len) if max_seq_len is not None else int(cfg.max_seq_len)
-        if S > cfg.max_seq_len:
-            raise ValueError(f"max_seq_len {S} exceeds the model's positional table {cfg.max_seq_len}")
-        self.cfg = cfg
+        dec = self._decoder_of(model)
+        self._dec = dec
+        self.cfg = getattr(dec, "cfg", None)
+        S = int(max_seq_len) if max_seq_len is not None else int(dec.max_positions)
+        if S > dec.max_positions:
+            raise ValueError(f"max_seq_len {S} exceeds the model's positional table {dec.max_positions}")
         self.max_seq_len = S
         self.max_batch_slots = B = int(max_batch_slots)
         self.buckets = tuple(sorted(int(b) for b in prefill_buckets)) if prefill_buckets else default_buckets(S)
@@ -212,15 +212,34 @@ class DecodeEngine:
         self._kv_dtype = None if kv_dtype is None else str(kv_dtype)
         if self._kv_dtype not in (None, "int8"):
             raise ValueError(f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
+        if dec.recurrent:
+            # a slot of this model holds state no cached row can rebuild, and
+            # snapshots of it are not built: refuse, loudly, what rests on rows
+            what = type(model).__name__
+            if prefix_cache_mb and float(prefix_cache_mb) > 0:
+                raise NotImplementedError(f"prefix_cache_mb: {what} keeps recurrent state beside its KV rows; the "
+                                          "prefix cache holds chunk-aligned KV segments and no state snapshots")
+            if draft is not None:
+                raise NotImplementedError(f"draft=: {what} keeps recurrent state; a rejected speculative tail "
+                                          "cannot be rolled back out of it")
+            if self._kv_dtype == "int8":
+                raise NotImplementedError(f"kv_dtype='int8': {what}'s decoder has no quantized cache")
+            if self._chunk is not None and S % self._chunk:
+                raise ValueError(f"prefill_chunk {self._chunk} must divide max_seq_len {S} for {what}: a final "
+                                 "chunk cannot be shifted back over tokens its state has already taken in")
 
         # --- draft model for speculative decoding ------------------------
-        draft_model = None
+        ddec = None
         if draft is not None:
+            from ..models.gpt import GPTConfig
+
             if int(spec_k) < 1:
                 raise ValueError(f"spec_k must be >= 1, got {spec_k}")
             if self.fuse != 1:
                 raise ValueError("draft= requires fuse=1 (a speculative dispatch "
                                  "already emits up to spec_k+1 tokens)")
+            if not dec.has_window:
+                raise NotImplementedError(f"draft=: {type(model).__name__}'s decoder has no window forward")
             if isinstance(draft, dict):
                 draft = GPTConfig(**draft)
             if isinstance(draft, GPTConfig):
@@ -239,80 +258,39 @@ class DecodeEngine:
                     _fwrng.set_rng_state(state)
             else:
                 draft_model = draft
-            if not isinstance(draft_model.gpt.layers, GPTBlockStack):
-                raise NotImplementedError("draft model requires the stacked trunk")
-            dcfg = draft_model.gpt.cfg
-            if dcfg.vocab_size != cfg.vocab_size:
-                raise ValueError(f"draft vocab {dcfg.vocab_size} != target vocab {cfg.vocab_size}")
-            if dcfg.max_seq_len < S:
-                raise ValueError(f"draft positional table {dcfg.max_seq_len} < max_seq_len {S}")
-            self.draft_cfg = dcfg  # noqa: PTA104 (host-side serving state)
-        else:
-            self.draft_cfg = None  # noqa: PTA104 (host-side serving state)
+            try:
+                ddec = self._decoder_of(draft_model)
+            except NotImplementedError as e:
+                raise NotImplementedError("draft model requires the stacked trunk") from e
+            if ddec.vocab_size != dec.vocab_size:
+                raise ValueError(f"draft vocab {ddec.vocab_size} != target vocab {dec.vocab_size}")
+            if ddec.max_positions < S:
+                raise ValueError(f"draft positional table {ddec.max_positions} < max_seq_len {S}")
+        self._ddec = ddec
+        self.draft_cfg = getattr(ddec, "cfg", None)
         self.spec_k = int(spec_k) if draft is not None else 0
         self.draft_seed = int(draft_seed)
 
-        def pack_stack(order, params):
-            # per-layer × per-output-channel abs_max scales on the
-            # [L, in, out]-stacked trunk weight (channel_wise_abs_max
-            # over the stack) — int8 constants land in the compiled
-            # programs, dequant folds into the matmul
-            from .. import quantization as Q
-
-            quant = {"qkv_w", "out_w", "ffn1_w", "ffn2_w"}
-            packed = []
-            for name, w in zip(order, params):
-                if name in quant:
-                    q, s = Q.quant_abs_max(np.asarray(w), channel_axis=(0, 2))
-                    packed.append({"q": jnp.asarray(q), "s": jnp.asarray(s)})
-                else:
-                    packed.append(w)
-            return tuple(packed)
-
-        stacked, wte, wpe, fnw, fnb = model._decode_params()
-        params, self._idx = stacked
-        self._stack_dts = tuple(w.dtype for w in params)  # dequant targets
-        if int8:
-            params = pack_stack(model.gpt.layers._order, params)
-        self._params = {"stack": params, "wte": wte, "wpe": wpe, "fnw": fnw, "fnb": fnb}
-
-        self._dparams = None
-        if draft_model is not None:
-            dstacked, dwte, dwpe, dfnw, dfnb = draft_model._decode_params()
-            dparams, self._didx = dstacked  # noqa: PTA104 (host-side serving state)
-            self._draft_dts = tuple(w.dtype for w in dparams)  # noqa: PTA104 (host-side serving state)
-            if int8:
-                dparams = pack_stack(draft_model.gpt.layers._order, dparams)
-            self._dparams = {"stack": dparams, "wte": dwte, "wpe": dwpe,  # noqa: PTA104 (host-side serving state)
-                             "fnw": dfnw, "fnb": dfnb}
+        self._params = dec.params(int8=self.int8)
+        self._idx = getattr(dec, "idx", None)
+        self._dparams = None if ddec is None else ddec.params(int8=self.int8)
         if device is not None:
             # this engine's own copy of the weights, beside its cache
             self._params = jax.device_put(self._params, device)
             if self._dparams is not None:
                 self._dparams = jax.device_put(self._dparams, device)  # noqa: PTA104 (host-side serving state)
 
-        L = cfg.num_layers
-        H = cfg.num_heads
-        dh = cfg.hidden_size // cfg.num_heads
-        dt = wte.dtype
         # the cache carries spec_k slack rows past max_seq_len so the
         # (spec_k+1)-wide speculative window write near the sequence limit
         # never clamps back over committed rows; slack rows are never
         # attendable by an emitted token (q_pos < max_seq_len always)
         cache_S = S + self.spec_k
-        self._shape = (L, B, H, cache_S, dh)
+        self._specs = dec.buffer_specs(B, cache_S, self._kv_dtype)
+        self._shape = tuple(self._specs[0].shape)
         with self._device_scope():
-            self._ck = _kv_zeros((L, B, H, cache_S, dh), dt, self._kv_dtype)
-            self._cv = _kv_zeros((L, B, H, cache_S, dh), dt, self._kv_dtype)
-            if draft_model is not None:
-                dcfg = self.draft_cfg
-                dL, dH = dcfg.num_layers, dcfg.num_heads
-                ddh = dcfg.hidden_size // dcfg.num_heads
-                # the draft cache is small — keep it in the compute dtype
-                self._dck = jnp.zeros((dL, B, dH, cache_S, ddh), dwte.dtype)  # noqa: PTA104 (host-side serving state)
-                self._dcv = jnp.zeros((dL, B, dH, cache_S, ddh), dwte.dtype)  # noqa: PTA104 (host-side serving state)
-            else:
-                self._dck = self._dcv = None  # noqa: PTA104 (host-side serving state)
+            self._cache = dec.alloc(B, cache_S, self._kv_dtype)
+            # the draft cache is small — keep it in the compute dtype
+            self._dcache = None if ddec is None else ddec.alloc(B, cache_S, None)  # noqa: PTA104 (host-side serving state)
             self._pos = jnp.zeros((B,), jnp.int32)
             self._tok = jnp.zeros((B,), jnp.int32)
             self._active = jnp.zeros((B,), bool)
@@ -324,6 +302,7 @@ class DecodeEngine:
         self._seed = np.zeros((B,), np.int32)
         self._spec_drafted = 0
         self._spec_accepted = 0
+        self.last_stats = None    # the decoder's counters of the last decode dispatch (``n_stats`` int32)
 
         self.prefix_cache = None
         if prefix_cache_mb and float(prefix_cache_mb) > 0:
@@ -332,30 +311,19 @@ class DecodeEngine:
                                  "entries are chunk-aligned KV segments)")
             from .prefix_cache import PrefixCache
 
-            if self._kv_dtype == "int8":
-                # int8 payload + one f32 scale per (layer, head, row)
-                entry_bytes = 2 * L * H * self._chunk * (dh + 4)
-            else:
-                entry_bytes = 2 * L * H * self._chunk * dh * jnp.dtype(dt).itemsize
             self.prefix_cache = PrefixCache(self._chunk,
                                             int(float(prefix_cache_mb) * (1 << 20)),
-                                            entry_bytes)
+                                            dec.segment_bytes(self._chunk, self._kv_dtype))
 
         # host scalars baked into the traced programs — part of the disk
         # cache key so a restarted engine only reuses executables compiled
         # for the exact same specialization (kv dtype and the draft config
         # change every traced program, so both fold in)
-        dfp = None
-        if self.draft_cfg is not None:
-            dcfg = self.draft_cfg
-            dfp = (dcfg.vocab_size, dcfg.hidden_size, dcfg.num_layers,
-                   dcfg.num_heads, dcfg.ffn_hidden_size, dcfg.max_seq_len,
-                   self.spec_k)
+        dfp = None if ddec is None else tuple(ddec.fingerprint()) + (self.spec_k,)
         self._fingerprint = repr((
-            (cfg.vocab_size, cfg.hidden_size, cfg.num_layers, cfg.num_heads,
-             cfg.ffn_hidden_size, cfg.max_seq_len),
+            tuple(dec.fingerprint()),
             self._sample, self.int8, self._donate, S, B, self._chunk,
-            tuple(str(d) for d in self._stack_dts), str(dt),
+            tuple(str(d) for d in getattr(dec, "stack_dtypes", ())), str(dec.dtype),
             self._kv_dtype, dfp))
 
         self._build()
@@ -364,59 +332,48 @@ class DecodeEngine:
         self._specializations: List[dict] = []
         from ..observability.metrics import gauge_set
         gauge_set("infer.kv_bytes_per_slot", self.kv_bytes_per_slot())
+        gauge_set("infer.state_bytes_per_slot", self.state_bytes_per_slot())
 
     # ------------------------------------------------------------ programs
     @property
     def device(self):
         """The device this engine's KV cache lives on."""
-        return next(iter(jax.tree_util.tree_leaves(self._ck)[0].devices()))
+        return next(iter(jax.tree_util.tree_leaves(self._cache)[0].devices()))
 
     def _device_scope(self):
         if self._device is None:
             return contextlib.nullcontext()
         return jax.default_device(self._device)
 
+    @staticmethod
+    def _decoder_of(model):
+        """The model's decoder (:mod:`paddle_tpu.models.decoder`)."""
+        if not hasattr(model, "decoder"):
+            raise NotImplementedError(f"{type(model).__name__} has no decoder(): DecodeEngine serves a model "
+                                      "through the interface of paddle_tpu.models.decoder")
+        return model.decoder()
+
+    # the buffers by their old names: the key and the value cache of a model
+    # whose decoder holds just those (what the benchmark's GPT family and the
+    # tests read)
+    @property
+    def _ck(self):
+        return self._cache[0]
+
+    @property
+    def _cv(self):
+        return self._cache[1]
+
     def _build(self):
-        from ..models.gpt import (
-            _cache_forward,
-            _chunk_prefill_forward,
-            _filtered_logits,
-            _kv_zeros,
-            _kvc_copy,
-            _kvc_slice,
-            _select_token,
-            _select_token_rows,
-            _slot_decode_forward,
-            _slot_window_forward,
-        )
+        from ..models.decoder import filtered_logits as _filtered_logits
+        from ..models.decoder import select_token as _select_token
+        from ..models.decoder import select_token_rows as _select_token_rows
 
-        cfg = self.cfg
-        num_heads = cfg.num_heads
-        L = cfg.num_layers
-        H = num_heads
-        dh = cfg.hidden_size // num_heads
+        dec, ddec = self._dec, self._ddec
         do_sample, temperature, top_k, top_p = self._sample
-        idx = self._idx
-        kvdt = self._kv_dtype
         spec_k = self.spec_k
-        has_draft = self._dparams is not None
-        if has_draft:
-            dcfg = self.draft_cfg
-            draft_heads = dcfg.num_heads
-            dL, dH = dcfg.num_layers, dcfg.num_heads
-            ddh = dcfg.hidden_size // dcfg.num_heads
-            didx = self._didx
-            ddts = self._draft_dts
-
-        dts = self._stack_dts
-
-        def unpack(p):
-            return ((tuple(_dequant(e, dt) for e, dt in zip(p["stack"], dts)), idx),
-                    p["wte"], p["wpe"], p["fnw"], p["fnb"])
-
-        def unpack_draft(dp):
-            return ((tuple(_dequant(e, dt) for e, dt in zip(dp["stack"], ddts)), didx),
-                    dp["wte"], dp["wpe"], dp["fnw"], dp["fnb"])
+        has_draft = ddec is not None
+        n_stats = int(dec.n_stats)
 
         def admit_state(pos, tok, active, first, length, slot, eos, limit):
             """Shared tail of every first-token program: the in-graph
@@ -429,111 +386,73 @@ class DecodeEngine:
             active = dus(active, more[None], (slot,))
             return pos, tok, active, more
 
-        def prefill_core(p, ck, cv, pos, tok, active, ids, length, slot, eos, limit, seed):
-            stacked, wte, wpe, fnw, fnb = unpack(p)
-            P = ids.shape[1]
-            # the bucketed scratch carries the SAME representation as the big
-            # cache (int8 pack under kv_dtype), so bucketed prefill attends
-            # exactly the rows a chunked prefill would — the bitwise basis
-            # of the bucketed-vs-chunked parity pin survives quantization
-            sk = _kv_zeros((L, 1, H, P, dh), wte.dtype, kvdt)
-            sv = _kv_zeros((L, 1, H, P, dh), wte.dtype, kvdt)
-            logits, sk, sv = _cache_forward(stacked, wte, wpe, fnw, fnb, ids, sk, sv,
-                                            jnp.int32(0), num_heads=num_heads)
-            ck = _kvc_copy(ck, sk, (0, slot, 0, 0, 0))
-            cv = _kvc_copy(cv, sv, (0, slot, 0, 0, 0))
-            last = jax.lax.dynamic_slice(logits, (0, length - 1, 0), (1, 1, logits.shape[2]))[:, 0]
+        def prefill_core(p, cache, pos, tok, active, ids, length, slot, eos, limit, seed):
+            last, cache = dec.prefill(p, cache, ids, length, slot)
             key = jax.random.fold_in(jax.random.key(seed), length - 1)
             first = _select_token(last.astype(jnp.float32), key, do_sample, temperature, top_k, top_p)[0]
             pos, tok, active, more = admit_state(pos, tok, active, first, length, slot, eos, limit)
-            return ck, cv, pos, tok, active, first, more
+            return cache, pos, tok, active, first, more
 
-        def draft_prefill(dp, dck, dcv, ids, slot):
+        if has_draft:
             # draft prefill rides the SAME dispatch as the target prefill
             # (one donated program, two trunks; XLA dead-code-eliminates the
             # draft logits) so admission cost stays one dispatch per bucket
-            dstacked, dwte, dwpe, dfnw, dfnb = unpack_draft(dp)
-            P = ids.shape[1]
-            dsk = jnp.zeros((dL, 1, dH, P, ddh), dwte.dtype)
-            dsv = jnp.zeros((dL, 1, dH, P, ddh), dwte.dtype)
-            _, dsk, dsv = _cache_forward(dstacked, dwte, dwpe, dfnw, dfnb, ids, dsk, dsv,
-                                         jnp.int32(0), num_heads=draft_heads)
-            dck = jax.lax.dynamic_update_slice(dck, dsk, (0, slot, 0, 0, 0))
-            dcv = jax.lax.dynamic_update_slice(dcv, dsv, (0, slot, 0, 0, 0))
-            return dck, dcv
-
-        def draft_chunk(dp, dck, dcv, ids, slot, start):
-            dstacked, dwte, dwpe, dfnw, dfnb = unpack_draft(dp)
-            _, dck, dcv = _chunk_prefill_forward(dstacked, dwte, dwpe, dfnw, dfnb, ids,
-                                                 dck, dcv, slot, start,
-                                                 num_heads=draft_heads)
-            return dck, dcv
-
-        if has_draft:
-            def prefill_fn(p, dp, ck, cv, dck, dcv, pos, tok, active, ids, length,
+            def prefill_fn(p, dp, cache, dcache, pos, tok, active, ids, length,
                            slot, eos, limit, seed):
-                ck, cv, pos, tok, active, first, more = prefill_core(
-                    p, ck, cv, pos, tok, active, ids, length, slot, eos, limit, seed)
-                dck, dcv = draft_prefill(dp, dck, dcv, ids, slot)
-                return ck, cv, dck, dcv, pos, tok, active, first, more
+                cache, pos, tok, active, first, more = prefill_core(
+                    p, cache, pos, tok, active, ids, length, slot, eos, limit, seed)
+                _, dcache = ddec.prefill(dp, dcache, ids, length, slot)
+                return cache, dcache, pos, tok, active, first, more
         else:
             prefill_fn = prefill_core
 
-        def chunk_core(p, ck, cv, ids, slot, start):
-            stacked, wte, wpe, fnw, fnb = unpack(p)
-            _, ck, cv = _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, ck, cv,
-                                               slot, start, num_heads=num_heads)
-            return ck, cv
+        def chunk_core(p, cache, ids, slot, start):
+            _, cache = dec.chunk(p, cache, ids, slot, start)
+            return cache
+
+        def draft_chunk(dp, dcache, ids, slot, start):
+            _, dcache = ddec.chunk(dp, dcache, ids, slot, start)
+            return dcache
 
         if has_draft:
-            def chunk_fn(p, dp, ck, cv, dck, dcv, ids, slot, start):
-                ck, cv = chunk_core(p, ck, cv, ids, slot, start)
-                dck, dcv = draft_chunk(dp, dck, dcv, ids, slot, start)
-                return ck, cv, dck, dcv
+            def chunk_fn(p, dp, cache, dcache, ids, slot, start):
+                cache = chunk_core(p, cache, ids, slot, start)
+                dcache = draft_chunk(dp, dcache, ids, slot, start)
+                return cache, dcache
         else:
             chunk_fn = chunk_core
 
-        def chunk_final_core(p, ck, cv, pos, tok, active, ids, slot, start, last_row,
+        def chunk_final_core(p, cache, pos, tok, active, ids, slot, start, last_row,
                              length, eos, limit, seed):
-            stacked, wte, wpe, fnw, fnb = unpack(p)
-            logits, ck, cv = _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, ck, cv,
-                                                    slot, start, num_heads=num_heads,
-                                                    last_row=last_row)
+            logits, cache = dec.chunk(p, cache, ids, slot, start, last_row=last_row)
             key = jax.random.fold_in(jax.random.key(seed), length - 1)
             first = _select_token(logits.astype(jnp.float32), key, do_sample, temperature, top_k, top_p)[0]
             pos, tok, active, more = admit_state(pos, tok, active, first, length, slot, eos, limit)
-            return ck, cv, pos, tok, active, first, more
+            return cache, pos, tok, active, first, more
 
         if has_draft:
-            def chunk_final_fn(p, dp, ck, cv, dck, dcv, pos, tok, active, ids, slot,
+            def chunk_final_fn(p, dp, cache, dcache, pos, tok, active, ids, slot,
                                start, last_row, length, eos, limit, seed):
-                ck, cv, pos, tok, active, first, more = chunk_final_core(
-                    p, ck, cv, pos, tok, active, ids, slot, start, last_row,
+                cache, pos, tok, active, first, more = chunk_final_core(
+                    p, cache, pos, tok, active, ids, slot, start, last_row,
                     length, eos, limit, seed)
-                dck, dcv = draft_chunk(dp, dck, dcv, ids, slot, start)
-                return ck, cv, dck, dcv, pos, tok, active, first, more
+                dcache = draft_chunk(dp, dcache, ids, slot, start)
+                return cache, dcache, pos, tok, active, first, more
         else:
             chunk_final_fn = chunk_final_core
 
-        def insert_fn(ck, cv, seg_k, seg_v, slot, start):
+        def insert_fn(cache, segment, slot, start):
             # prefix-cache hit: copy a cached chunk's KV into the slot's
             # lanes — the whole "prefill" of the shared portion is this one
-            # dynamic_update_slice program. Under kv_dtype the segment is the
-            # stored int8 pack and both planes copy verbatim: a cache hit
-            # never round-trips through f32 in HBM.
-            ck = _kvc_copy(ck, seg_k, (0, slot, 0, start, 0))
-            cv = _kvc_copy(cv, seg_v, (0, slot, 0, start, 0))
-            return ck, cv
+            # dynamic_update_slice program
+            return dec.segment_insert(cache, segment, slot, start)
 
         chunk = self._chunk
 
-        def extract_fn(ck, cv, slot, start):
-            size = (L, 1, H, chunk if chunk else 1, dh)
-            seg_k = _kvc_slice(ck, (0, slot, 0, start, 0), size)
-            seg_v = _kvc_slice(cv, (0, slot, 0, start, 0), size)
-            return seg_k, seg_v
+        def extract_fn(cache, slot, start):
+            return dec.segment_extract(cache, slot, start, chunk if chunk else 1)
 
-        def spec_fn(p, dp, ck, cv, dck, dcv, pos, tok, active, eos_v, limit_v, seed_v):
+        def spec_fn(p, dp, cache, dcache, pos, tok, active, eos_v, limit_v, seed_v):
             """ONE speculative dispatch: spec_k+1 chained draft forwards on
             the draft cache propose a window, ONE (spec_k+1)-wide target
             forward verifies it, and the accept-longest-prefix + bonus-token
@@ -541,8 +460,6 @@ class DecodeEngine:
             rolled-back position — harmless under write-before-attend (the
             next window overwrites those rows before any emitted row can
             attend them)."""
-            stacked, wte, wpe, fnw, fnb = unpack(p)
-            dstacked, dwte, dwpe, dfnw, dfnb = unpack_draft(dp)
             K = spec_k
             # --- draft scan: iteration i consumes the token at pos+i and
             # writes its draft KV there; iterations 0..K-1 yield proposals
@@ -552,9 +469,7 @@ class DecodeEngine:
             dtok = tok
             for i in range(K + 1):  # noqa: PTA104 (static unroll, host loop bound)
                 dpos = pos + jnp.int32(i)
-                dlogits, dck, dcv = _slot_decode_forward(
-                    dstacked, dwte, dwpe, dfnw, dfnb, dtok, dck, dcv, dpos,
-                    num_heads=draft_heads, active=active)
+                dlogits, dcache, _ = ddec.decode(dp, dcache, dtok, dpos, active)
                 if i < K:
                     if do_sample:
                         fl = _filtered_logits(dlogits.astype(jnp.float32),
@@ -571,9 +486,7 @@ class DecodeEngine:
             # --- target verification: one (K+1)-wide window forward over
             # [tok, d_1..d_K] at per-slot positions pos..pos+K
             ids = jnp.stack([tok] + props, axis=1)
-            vlogits, ck, cv = _slot_window_forward(
-                stacked, wte, wpe, fnw, fnb, ids, ck, cv, pos,
-                num_heads=num_heads, active=active)
+            vlogits, cache = dec.window(p, cache, ids, pos, active)
             # --- per-row outcome: row j scores the token at position
             # pos+j+1. Greedy: argmax + equality accept (bitwise = sequential
             # decode, since per-row width-W math equals the s=1 math).
@@ -631,7 +544,7 @@ class DecodeEngine:
                 emit_rows.append(emit)  # noqa: PTA104 (host-side serving state)
                 if j < K:
                     win = win & accs[j]
-            return (ck, cv, dck, dcv, pos_s, tok_s, act_s,
+            return (cache, dcache, pos_s, tok_s, act_s,
                     jnp.stack(toks_rows), jnp.stack(emit_rows))
 
         def decode_body(consts, carry, _x):
@@ -639,10 +552,8 @@ class DecodeEngine:
             # (at D=1) the whole single-step program, so every fuse depth is
             # bitwise the same math
             p, eos_v, limit_v, seed_v = consts
-            ck, cv, pos, tok, active = carry
-            stacked, wte, wpe, fnw, fnb = unpack(p)
-            logits, ck, cv = _slot_decode_forward(stacked, wte, wpe, fnw, fnb, tok, ck, cv,
-                                                  pos, num_heads=num_heads, active=active)
+            cache, pos, tok, active = carry
+            logits, cache, stats = dec.decode(p, cache, tok, pos, active)
             keys = jax.vmap(lambda s, q: jax.random.fold_in(jax.random.key(s), q))(seed_v, pos)
             nxt = _select_token_rows(logits.astype(jnp.float32), keys, do_sample,
                                      temperature, top_k, top_p)
@@ -651,32 +562,40 @@ class DecodeEngine:
             new_pos = pos + active.astype(jnp.int32)
             new_active = active & ~hit_eos & (new_pos + 1 < limit_v)
             # ys: the step's token per slot + which slots really emitted
-            return (ck, cv, new_pos, nxt, new_active), (nxt, active)
+            # (+ the decoder's counters, where it counts)
+            ys = (nxt, active) if not n_stats else (nxt, active, stats)
+            return (cache, new_pos, nxt, new_active), ys
 
         self._decode_body = decode_body
 
-        def decode_fn(p, ck, cv, pos, tok, active, eos_v, limit_v, seed_v):
-            carry, _ys = decode_body((p, eos_v, limit_v, seed_v),
-                                     (ck, cv, pos, tok, active), None)
-            return carry
+        if n_stats:
+            def decode_fn(p, cache, pos, tok, active, eos_v, limit_v, seed_v):
+                carry, ys = decode_body((p, eos_v, limit_v, seed_v), (cache, pos, tok, active), None)
+                # the step's tokens and the decoder's counters leave in one array: one pull
+                return carry + (jnp.concatenate([carry[2], ys[2].astype(jnp.int32)]),)
+        else:
+            def decode_fn(p, cache, pos, tok, active, eos_v, limit_v, seed_v):
+                carry, _ys = decode_body((p, eos_v, limit_v, seed_v),
+                                         (cache, pos, tok, active), None)
+                return carry
 
         if has_draft:
-            # state args shift by one (draft params at arg 1) and both cache
-            # pairs donate; the draft weights thread through like the target's
-            donate = (2, 3, 4, 5, 6, 7, 8) if self._donate else ()
-            donate_cache = (2, 3, 4, 5) if self._donate else ()
+            # state args shift by one (draft params at arg 1) and both caches
+            # donate; the draft weights thread through like the target's
+            donate = (2, 3, 4, 5, 6) if self._donate else ()
+            donate_cache = (2, 3) if self._donate else ()
             self._spec_jit = jax.jit(spec_fn, donate_argnums=donate)  # noqa: PTA104 (host-side serving state)
             self._draft_chunk_jit = jax.jit(  # noqa: PTA104 (host-side serving state)
-                draft_chunk, donate_argnums=(1, 2) if self._donate else ())
+                draft_chunk, donate_argnums=(1,) if self._donate else ())
         else:
-            donate = (1, 2, 3, 4, 5) if self._donate else ()
-            donate_cache = (1, 2) if self._donate else ()
+            donate = (1, 2, 3, 4) if self._donate else ()
+            donate_cache = (1,) if self._donate else ()
             self._spec_jit = self._draft_chunk_jit = None  # noqa: PTA104 (host-side serving state)
         self._prefill_jit = jax.jit(prefill_fn, donate_argnums=donate)
-        self._decode_jit = jax.jit(decode_fn, donate_argnums=(1, 2, 3, 4, 5) if self._donate else ())
+        self._decode_jit = jax.jit(decode_fn, donate_argnums=(1, 2, 3, 4) if self._donate else ())
         self._chunk_jit = jax.jit(chunk_fn, donate_argnums=donate_cache)
         self._chunk_final_jit = jax.jit(chunk_final_fn, donate_argnums=donate)
-        self._insert_jit = jax.jit(insert_fn, donate_argnums=(0, 1) if self._donate else ())
+        self._insert_jit = jax.jit(insert_fn, donate_argnums=(0,) if self._donate else ())
         self._extract_jit = jax.jit(extract_fn)  # pure read: nothing donated
 
     def _fused(self, depth: int):
@@ -824,11 +743,10 @@ class DecodeEngine:
             # reuse at most n-1 tokens: the prompt's last token must run
             # through the model (its logits pick the first generated token)
             matched = self.prefix_cache.match(prompt, max_tokens=n - 1)
-            for i, (seg_k, seg_v) in enumerate(matched):
-                self._ck, self._cv = self._dispatch(
+            for i, segment in enumerate(matched):
+                self._cache = self._dispatch(  # noqa: PTA104 (host-side serving state)
                     "prefix_insert", self._insert_jit,
-                    (self._ck, self._cv, seg_k, seg_v, jnp.int32(slot),
-                     jnp.int32(i * self._chunk)))
+                    (self._cache, tuple(segment), jnp.int32(slot), jnp.int32(i * self._chunk)))
                 counter_inc("infer.prefix_insert_dispatches")
             if matched and self._dparams is not None:
                 # the prefix cache holds TARGET KV only; backfill the draft
@@ -836,9 +754,9 @@ class DecodeEngine:
                 # forwards (ascending — each chunk attends the earlier ones)
                 for i in range(len(matched)):
                     ids = prompt[i * self._chunk:(i + 1) * self._chunk][None]
-                    self._dck, self._dcv = self._dispatch(  # noqa: PTA104 (host-side serving state)
+                    self._dcache = self._dispatch(  # noqa: PTA104 (host-side serving state)
                         "draft_chunk", self._draft_chunk_jit,
-                        (self._dparams, self._dck, self._dcv, jnp.asarray(ids),
+                        (self._dparams, self._dcache, jnp.asarray(ids),
                          jnp.int32(slot), jnp.int32(i * self._chunk)),
                         label=f"draft_chunk/C{self._chunk}")
             job.next_pos = job.reused_tokens = len(matched) * self._chunk
@@ -865,8 +783,7 @@ class DecodeEngine:
             P = self.bucket_for(n)
             ids = np.zeros((1, P), np.int32)
             ids[0, :n] = job.prompt
-            state = ((self._params, self._dparams, self._ck, self._cv, self._dck, self._dcv)
-                     if spec else (self._params, self._ck, self._cv))
+            state = self._program_state()
             with _span("infer.prefill"):
                 out = self._dispatch(
                     "prefill", self._prefill_jit,
@@ -874,12 +791,7 @@ class DecodeEngine:
                              jnp.asarray(ids), jnp.int32(n), jnp.int32(slot), jnp.int32(job.eos),
                              jnp.int32(job.limit), jnp.int32(job.seed)),
                     label=f"prefill/P{P}")
-            if spec:
-                self._ck, self._cv, self._dck, self._dcv = out[:4]  # noqa: PTA104 (host-side serving state)
-                out = out[4:]
-            else:
-                self._ck, self._cv = out[:2]  # noqa: PTA104 (host-side serving state)
-                out = out[2:]
+            out = self._take_caches(out, spec)
             self._pos, self._tok, self._active, first, more = out  # noqa: PTA104 (host-side serving state)
             job.next_pos = n
         else:
@@ -887,17 +799,13 @@ class DecodeEngine:
             if job.next_pos + C < n:
                 # intermediate chunk: KV writes only, no logits work
                 ids = job.prompt[job.next_pos:job.next_pos + C][None]
-                state = ((self._params, self._dparams, self._ck, self._cv, self._dck, self._dcv)
-                         if spec else (self._params, self._ck, self._cv))
+                state = self._program_state()
                 with _span("infer.prefill_chunk"):
                     out = self._dispatch(
                         "prefill_chunk", self._chunk_jit,
                         state + (jnp.asarray(ids), jnp.int32(slot), jnp.int32(job.next_pos)),
                         label=f"prefill_chunk/C{C}")
-                if spec:
-                    self._ck, self._cv, self._dck, self._dcv = out  # noqa: PTA104 (host-side serving state)
-                else:
-                    self._ck, self._cv = out  # noqa: PTA104 (host-side serving state)
+                self._take_caches(out if spec else (out,), spec)
                 job.next_pos += C
                 counter_inc("infer.prefill_chunk_dispatches")
                 return False
@@ -908,8 +816,7 @@ class DecodeEngine:
             w = job.next_pos if job.next_pos + C <= self.max_seq_len else n - C
             ids = np.zeros((1, C), np.int32)
             ids[0, :n - w] = job.prompt[w:n]
-            state = ((self._params, self._dparams, self._ck, self._cv, self._dck, self._dcv)
-                     if spec else (self._params, self._ck, self._cv))
+            state = self._program_state()
             with _span("infer.prefill_chunk"):
                 out = self._dispatch(
                     "prefill_final", self._chunk_final_jit,
@@ -918,12 +825,7 @@ class DecodeEngine:
                              jnp.int32(n - 1 - w), jnp.int32(n), jnp.int32(job.eos),
                              jnp.int32(job.limit), jnp.int32(job.seed)),
                     label=f"prefill_final/C{C}")
-            if spec:
-                self._ck, self._cv, self._dck, self._dcv = out[:4]  # noqa: PTA104 (host-side serving state)
-                out = out[4:]
-            else:
-                self._ck, self._cv = out[:2]  # noqa: PTA104 (host-side serving state)
-                out = out[2:]
+            out = self._take_caches(out, spec)
             self._pos, self._tok, self._active, first, more = out  # noqa: PTA104 (host-side serving state)
             job.next_pos = n
             counter_inc("infer.prefill_chunk_dispatches")
@@ -936,6 +838,22 @@ class DecodeEngine:
         if self.prefix_cache is not None:
             self._store_prefix_chunks(job)
         return True
+
+    def _program_state(self):
+        """What every prefill program takes first: the weights and the slot
+        buffers (and the draft's, where there is one)."""
+        if self._dparams is not None:
+            return (self._params, self._dparams, self._cache, self._dcache)
+        return (self._params, self._cache)
+
+    def _take_caches(self, out, spec: bool):
+        """Keep the cache (and the draft's) a program returned first; the
+        rest of its outputs."""
+        if spec:
+            self._cache, self._dcache = out[:2]  # noqa: PTA104 (host-side serving state)
+            return out[2:]
+        self._cache = out[0]  # noqa: PTA104 (host-side serving state)
+        return out[1:]
 
     def _store_prefix_chunks(self, job: _PrefillJob) -> None:
         """After a completed prefill, extract and cache every chunk-aligned
@@ -950,7 +868,7 @@ class DecodeEngine:
                 continue
             seg_k, seg_v = self._dispatch(
                 "prefix_extract", self._extract_jit,
-                (self._ck, self._cv, jnp.int32(job.slot), jnp.int32(i * self._chunk)))
+                (self._cache, jnp.int32(job.slot), jnp.int32(i * self._chunk)))
             counter_inc("infer.prefix_extract_dispatches")
             cache.put(key, seg_k, seg_v)
         gauge_set("serving.prefix_cache_bytes", cache.bytes_used())
@@ -984,6 +902,7 @@ class DecodeEngine:
         if depth < 1:
             raise ValueError(f"fuse depth must be >= 1, got {depth}")
         spec = self._dparams is not None
+        n_stats = int(self._dec.n_stats)
         if spec and depth != 1:
             raise ValueError("speculative decode runs at fuse depth 1 (one "
                              "dispatch already emits up to spec_k+1 tokens)")
@@ -992,18 +911,18 @@ class DecodeEngine:
         # eos/limit/seed and the dispatch) and infer.decode_sync (the pulls of
         # tokens / emitted / active, which wait for the device) — the end of
         # infer.decode_sync is when this tick's tokens reached the host.
-        with _span("infer.decode_step"):
+        with _span("infer.decode_step") as step_span:
             if spec:
                 from ..observability.metrics import gauge_set
 
                 with _span("infer.decode_launch"):
                     out = self._dispatch(
                         "spec_decode", self._spec_jit,
-                        (self._params, self._dparams, self._ck, self._cv, self._dck, self._dcv,
+                        (self._params, self._dparams, self._cache, self._dcache,
                          self._pos, self._tok, self._active,
                          jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)),
                         label=f"spec_decode/K{self.spec_k}")
-                (self._ck, self._cv, self._dck, self._dcv,  # noqa: PTA104 (host-side serving state)
+                (self._cache, self._dcache,  # noqa: PTA104 (host-side serving state)
                  self._pos, self._tok, self._active, toks, emitted) = out  # noqa: PTA104 (host-side serving state)
                 with _span("infer.decode_sync"):
                     toks = np.asarray(toks)
@@ -1023,25 +942,38 @@ class DecodeEngine:
                 with _span("infer.decode_launch"):
                     out = self._dispatch(
                         "decode", self._decode_jit,
-                        (self._params, self._ck, self._cv, self._pos, self._tok, self._active,
+                        (self._params, self._cache, self._pos, self._tok, self._active,
                          jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)))
-                self._ck, self._cv, self._pos, self._tok, self._active = out  # noqa: PTA104 (host-side serving state)
+                self._cache, self._pos, self._tok, self._active = out[:4]  # noqa: PTA104 (host-side serving state)
                 with _span("infer.decode_sync"):
-                    toks = np.asarray(self._tok)
+                    if n_stats:
+                        # the tokens and the decoder's counters: one array, one pull
+                        pulled = np.asarray(out[4])
+                        toks, stats = pulled[:-n_stats], pulled[-n_stats:]
+                    else:
+                        toks = np.asarray(self._tok)
                     self._active_np = np.array(self._active)  # writable host mirror  # noqa: PTA104 (host-side serving state)
             else:
                 with _span("infer.decode_launch"):
                     consts = (self._params, jnp.asarray(self._eos), jnp.asarray(self._limit),
                               jnp.asarray(self._seed))
-                    carry = (self._ck, self._cv, self._pos, self._tok, self._active)
+                    carry = (self._cache, self._pos, self._tok, self._active)
                     out = self._dispatch(f"decode_x{depth}", self._fused(depth), (consts, carry))
-                (self._ck, self._cv, self._pos, self._tok, self._active), (toks, emitted) = out  # noqa: PTA104 (host-side serving state)
+                (self._cache, self._pos, self._tok, self._active), ys = out  # noqa: PTA104 (host-side serving state)
                 with _span("infer.decode_sync"):
-                    toks = np.asarray(toks)
-                    emitted = np.asarray(emitted)
+                    toks = np.asarray(ys[0])
+                    emitted = np.asarray(ys[1])
+                    if n_stats:
+                        stats = np.asarray(ys[2]).sum(axis=0)
                     self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
             counter_inc("infer.decode_dispatches")
             counter_inc("infer.tokens", int(emitted.sum()))
+            if n_stats:
+                self.last_stats = stats  # noqa: PTA104 (host-side serving state)
+                for name, value in zip(self._dec.stat_counters, stats):
+                    counter_inc(name, int(value))
+                # per tick, on the step's own span record: what a reader of the traced ticks takes
+                step_span.note(**{n.rsplit(".", 1)[-1]: int(v) for n, v in zip(self._dec.stat_counters, stats)})
         return toks, emitted, self._active_np.copy()
 
     def free_slot(self, slot: int) -> None:
@@ -1132,15 +1064,23 @@ class DecodeEngine:
         """Device bytes held by the preallocated target K/V cache, summed
         over the ACTUAL stored leaves — under ``kv_dtype="int8"`` that is
         the int8 payload plus the f32 scale planes, not the compute dtype."""
-        leaves = jax.tree_util.tree_leaves((self._ck, self._cv))
-        return int(sum(l.size * jnp.dtype(l.dtype).itemsize for l in leaves))
+        return self._buffer_bytes(reset=False)
+
+    def _buffer_bytes(self, reset: bool) -> int:
+        """Stored bytes of the slot buffers that are (``reset``) or are not
+        zeroed at admission: recurrent state, or cache rows."""
+        total = 0
+        for spec, buf in zip(self._specs, self._cache):
+            if spec.reset_at_admission == reset:
+                total += sum(l.size * jnp.dtype(l.dtype).itemsize for l in jax.tree_util.tree_leaves(buf))
+        return int(total)
 
     def draft_cache_bytes(self) -> int:
         """Device bytes held by the draft model's K/V cache (0 without a
         draft)."""
-        if self._dck is None:
+        if self._dcache is None:
             return 0
-        leaves = jax.tree_util.tree_leaves((self._dck, self._dcv))
+        leaves = jax.tree_util.tree_leaves(self._dcache)
         return int(sum(l.size * jnp.dtype(l.dtype).itemsize for l in leaves))
 
     def kv_bytes_per_slot(self) -> int:
@@ -1149,6 +1089,12 @@ class DecodeEngine:
         gauge — sizing concurrent-slot capacity from this number stays
         honest under int8 KV)."""
         return self.cache_bytes() // self.max_batch_slots
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state a slot holds beside its cache rows (the
+        buffers admission zeroes): the ``infer.state_bytes_per_slot`` gauge.
+        0 for a model whose slots hold keys and values only."""
+        return self._buffer_bytes(reset=True) // self.max_batch_slots
 
     def spec_stats(self) -> dict:
         """Cumulative speculative-decoding counters: proposals drafted,

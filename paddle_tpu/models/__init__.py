@@ -22,3 +22,7 @@ from .dlrm import (  # noqa: F401
     DLRMConfig,
     DLRMCriterion,
 )
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config,
+    SolarOpen2ForCausalLM,
+)

@@ -955,42 +955,12 @@ def _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v,
     return logits, cache_k, cache_v
 
 
-def _filtered_logits(logits, temperature, top_k, top_p):
-    """Temperature/top-k/top-p filtered f32 logits over [b, V] — the exact
-    transform :func:`_select_token` samples from, factored out so
-    speculative decoding's residual-resampling acceptance test works on the
-    SAME filtered distribution the sequential sampler would draw from."""
-    logits = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)
-    if top_k and top_k > 0:
-        k_eff = min(int(top_k), logits.shape[-1])  # top_k > vocab = keep all
-        kth = jnp.sort(logits, axis=-1)[..., -k_eff][..., None]
-        logits = jnp.where(logits < kth, -jnp.inf, logits)
-    if top_p < 1.0:
-        sl = jnp.sort(logits, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sl, axis=-1)
-        keep = jnp.cumsum(probs, axis=-1) - probs < top_p  # always keep top-1
-        threshold = jnp.min(jnp.where(keep, sl, jnp.inf), axis=-1, keepdims=True)
-        logits = jnp.where(logits < threshold, -jnp.inf, logits)
-    return logits
-
-
-def _select_token(logits, key, do_sample, temperature, top_k, top_p):
-    """Greedy or temperature/top-k/top-p sampling over [b, V] logits."""
-    if not do_sample:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = _filtered_logits(logits, temperature, top_k, top_p)
-    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
-
-
-def _select_token_rows(logits, keys, do_sample, temperature, top_k, top_p):
-    """Per-row variant of :func:`_select_token` for slot-masked sampling:
-    ``keys`` carries one PRNG key PER batch slot so a request's sample stream
-    depends only on its own (seed, position) — never on which slot it landed
-    in or what its batch neighbours are doing (no cross-request leakage)."""
-    if not do_sample:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    pick = lambda lg, k: _select_token(lg[None], k, True, temperature, top_k, top_p)[0]  # noqa: E731
-    return jax.vmap(pick)(logits, keys)
+# the token samplers are the serving engine's as much as ``generate``'s: they
+# live with the decoder interface
+from .decoder import BufferSpec, Decoder  # noqa: E402
+from .decoder import filtered_logits as _filtered_logits  # noqa: E402,F401
+from .decoder import select_token as _select_token  # noqa: E402
+from .decoder import select_token_rows as _select_token_rows  # noqa: E402,F401
 
 
 @functools.partial(jax.jit, static_argnames=("num_heads", "num_layers", "head_dim", "max_new", "do_sample", "temperature", "top_k", "top_p", "eos", "mesh"))
@@ -1026,6 +996,141 @@ def _generate_jit(params, ids, key, *, num_heads, num_layers, head_dim, max_new,
 
     (_, _, _, _, _), rest = jax.lax.scan(step, (first, cache_k, cache_v, done0, key), jnp.arange(max_new - 1, dtype=jnp.int32))
     return jnp.concatenate([ids, first[:, None], rest.T.astype(jnp.int32)], axis=1)
+
+
+def _dequant(entry, dt):
+    """A params-pack entry is either a plain array or an int8 payload
+    ``{"q", "s"}``; dequantize the latter to ``dt`` (XLA folds the multiply
+    into the consuming matmul — the QuantizedLinear idiom on raw stacked
+    weights)."""
+    if isinstance(entry, dict):
+        return (entry["q"].astype(jnp.float32) * entry["s"]).astype(dt)
+    return entry
+
+
+class GPTDecoder(Decoder):
+    """GPT through the serving engine's decoder interface
+    (:mod:`paddle_tpu.models.decoder`): the stacked trunk's parameter pack, a
+    key and a value cache ``[L, B, H, S, dh]`` over query heads (plain arrays
+    or int8 packs; rows need no reset at admission), and the cache forwards
+    above, called exactly as the engine called them before it had an
+    interface — its compiled programs are the same."""
+
+    has_window = True
+
+    def __init__(self, model):
+        if not isinstance(model.gpt.layers, GPTBlockStack):
+            raise NotImplementedError("DecodeEngine requires the stacked trunk (GPTConfig(stacked=True))")
+        cfg = model.gpt.cfg
+        self.cfg = cfg
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_seq_len
+        (stack, self.idx), self._wte, self._wpe, self._fnw, self._fnb = model._decode_params()
+        self._stack = stack
+        self._order = model.gpt.layers._order
+        self.stack_dtypes = tuple(w.dtype for w in stack)  # dequant targets
+        self.dtype = self._wte.dtype
+
+    # ------------------------------------------------------------ parameters
+    def params(self, int8: bool = False):
+        stack = self._stack
+        if int8:
+            # per-layer x per-output-channel abs_max scales on the
+            # [L, in, out]-stacked trunk weight (channel_wise_abs_max over the
+            # stack) — int8 constants land in the compiled programs, dequant
+            # folds into the matmul
+            import numpy as np
+
+            from .. import quantization as Q
+
+            def pack(i):
+                if self._order[i] not in {"qkv_w", "out_w", "ffn1_w", "ffn2_w"}:
+                    return stack[i]
+                q, s = Q.quant_abs_max(np.asarray(stack[i]), channel_axis=(0, 2))
+                return {"q": jnp.asarray(q), "s": jnp.asarray(s)}
+
+            return {"stack": tuple(pack(i) for i in range(len(stack))), "wte": self._wte, "wpe": self._wpe,
+                    "fnw": self._fnw, "fnb": self._fnb}
+        return {"stack": stack, "wte": self._wte, "wpe": self._wpe, "fnw": self._fnw, "fnb": self._fnb}
+
+    def fingerprint(self) -> tuple:
+        cfg = self.cfg
+        return (cfg.vocab_size, cfg.hidden_size, cfg.num_layers, cfg.num_heads,
+                cfg.ffn_hidden_size, cfg.max_seq_len)
+
+    def _unpack(self, p):
+        return ((tuple(_dequant(e, dt) for e, dt in zip(p["stack"], self.stack_dtypes)), self.idx),
+                p["wte"], p["wpe"], p["fnw"], p["fnb"])
+
+    # --------------------------------------------------------------- buffers
+    def cache_shape(self, slots: int, rows: int) -> tuple:
+        cfg = self.cfg
+        return (cfg.num_layers, int(slots), cfg.num_heads, int(rows), cfg.hidden_size // cfg.num_heads)
+
+    def buffer_specs(self, slots: int, rows: int, kv_dtype=None):
+        shape = self.cache_shape(slots, rows)
+        dt = "int8" if kv_dtype == "int8" else str(self.dtype)
+        return (BufferSpec("k", shape, dt, 1, False), BufferSpec("v", shape, dt, 1, False))
+
+    def alloc(self, slots: int, rows: int, kv_dtype=None):
+        shape = self.cache_shape(slots, rows)
+        return (_kv_zeros(shape, self.dtype, kv_dtype), _kv_zeros(shape, self.dtype, kv_dtype))
+
+    # -------------------------------------------------------------- forwards
+    def prefill(self, p, cache, ids, length, slot):
+        stacked, wte, wpe, fnw, fnb = self._unpack(p)
+        ck, cv = cache
+        L, _, H, _, dh = self.cache_shape(1, 1)
+        kvdt = "int8" if isinstance(ck, dict) else None
+        P = ids.shape[1]
+        # the bucketed scratch carries the SAME representation as the big
+        # cache (int8 pack under kv_dtype), so bucketed prefill attends
+        # exactly the rows a chunked prefill would — the bitwise basis
+        # of the bucketed-vs-chunked parity pin survives quantization
+        sk = _kv_zeros((L, 1, H, P, dh), wte.dtype, kvdt)
+        sv = _kv_zeros((L, 1, H, P, dh), wte.dtype, kvdt)
+        logits, sk, sv = _cache_forward(stacked, wte, wpe, fnw, fnb, ids, sk, sv,
+                                        jnp.int32(0), num_heads=self.cfg.num_heads)
+        ck = _kvc_copy(ck, sk, (0, slot, 0, 0, 0))
+        cv = _kvc_copy(cv, sv, (0, slot, 0, 0, 0))
+        last = jax.lax.dynamic_slice(logits, (0, length - 1, 0), (1, 1, logits.shape[2]))[:, 0]
+        return last, (ck, cv)
+
+    def chunk(self, p, cache, ids, slot, start, last_row=None):
+        stacked, wte, wpe, fnw, fnb = self._unpack(p)
+        ck, cv = cache
+        logits, ck, cv = _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, ck, cv, slot, start,
+                                                num_heads=self.cfg.num_heads, last_row=last_row)
+        return logits, (ck, cv)
+
+    def decode(self, p, cache, tok, pos, active):
+        stacked, wte, wpe, fnw, fnb = self._unpack(p)
+        logits, ck, cv = _slot_decode_forward(stacked, wte, wpe, fnw, fnb, tok, cache[0], cache[1],
+                                              pos, num_heads=self.cfg.num_heads, active=active)
+        return logits, (ck, cv), None
+
+    def window(self, p, cache, toks, pos, active):
+        stacked, wte, wpe, fnw, fnb = self._unpack(p)
+        logits, ck, cv = _slot_window_forward(stacked, wte, wpe, fnw, fnb, toks, cache[0], cache[1], pos,
+                                              num_heads=self.cfg.num_heads, active=active)
+        return logits, (ck, cv)
+
+    # -------------------------------------------- prefix-cache segments (KV)
+    def segment_extract(self, cache, slot, start, size: int):
+        L, _, H, _, dh = self.cache_shape(1, 1)
+        shape = (L, 1, H, int(size), dh)
+        return tuple(_kvc_slice(c, (0, slot, 0, start, 0), shape) for c in cache)
+
+    def segment_insert(self, cache, segment, slot, start):
+        # under kv_dtype the segment is the stored int8 pack and both planes
+        # copy verbatim: a cache hit never round-trips through f32 in HBM
+        return tuple(_kvc_copy(c, seg, (0, slot, 0, start, 0)) for c, seg in zip(cache, segment))
+
+    def segment_bytes(self, size: int, kv_dtype=None) -> int:
+        L, _, H, _, dh = self.cache_shape(1, 1)
+        if kv_dtype == "int8":
+            return 2 * L * H * int(size) * (dh + 4)      # int8 payload + one f32 scale per (layer, head, row)
+        return 2 * L * H * int(size) * dh * jnp.dtype(self.dtype).itemsize
 
 
 class GPTEmbeddings(nn.Layer):
@@ -1233,6 +1338,10 @@ class GPTForPretraining(nn.Layer):
             temperature=float(temperature), top_k=int(top_k), top_p=float(top_p),
             eos=None if eos_token_id is None else int(eos_token_id), mesh=mesh)
         return _wrap_value(out)
+
+    def decoder(self) -> "GPTDecoder":
+        """What the serving engine runs this model through."""
+        return GPTDecoder(self)
 
     def _decode_params(self):
         """The decode-loop parameter pack (single definition shared by

@@ -191,6 +191,9 @@ _HELP: Dict[str, str] = {}
 SERVING_COUNTERS: Tuple[str, ...] = (
     "infer.compiles", "infer.runs",
     "infer.prefill_dispatches", "infer.decode_dispatches", "infer.tokens",
+    # the routed experts' load of a decode step, pulled with its tokens: (token, expert) pairs routed to an
+    # expert held here, and held experts with at least one pair, both summed over the layers
+    "infer.moe.assignments_local", "infer.moe.experts_hit",
     "infer.prefill_chunk_dispatches",
     "infer.prefix_insert_dispatches", "infer.prefix_extract_dispatches",
     "infer.aot_cache_hits", "infer.aot_cache_stores",
@@ -347,6 +350,8 @@ KNOWN_GAUGES: Tuple[str, ...] = (
     # stored (post-quantization) HBM cost of one KV slot — concurrent-slot
     # capacity planning divides free HBM by this number
     "serving.spec_acceptance_rate", "infer.kv_bytes_per_slot",
+    # what a slot holds beside its cache rows: the recurrent state admission zeroes (0 for a key/value-only model)
+    "infer.state_bytes_per_slot",
     "fleet.replicas_alive", "fleet.replicas_dead", "fleet.queue_depth",
     "stability.lr", "amp.loss_scale",
     # judgment layer (PR 19): age of the stalest alive replica heartbeat

@@ -83,6 +83,10 @@ class Span:
     def seconds(self) -> Optional[float]:
         return (self.end_ns - self.start_ns) / 1e9 if self.end_ns else None
 
+    def note(self, **attrs):
+        """Keep a few more small values on the record (what the section counted)."""
+        self.attrs = {**(self.attrs or {}), **attrs}  # noqa: PTA104 (host-side, never traced)
+
     def _link(self):
         """Take parent and trace from the innermost open entry; a span of a
         trace gets the id its run-log event will carry."""
@@ -147,6 +151,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def note(self, **attrs):
+        pass
 
 
 _NULL = _NullSpan()

@@ -118,7 +118,7 @@ def _heads_dot(a, b, contract):
 
 
 def _kernel(layer_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref, ck_in, cv_in,
-            o_ref, ck_out, cv_out, kbuf, vbuf, rsem, wsem, *, window, rows, tile, seq, s_minor):
+            o_ref, ck_out, cv_out, kbuf, vbuf, rsem, wsem, *, window, rows, tile, seq, s_minor, group=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -198,7 +198,8 @@ def _kernel(layer_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref, ck_in, cv_in,
             k, v = kbuf[slot], vbuf[slot]
             s = _heads_dot(q_ref[...], k, ((2,), (1,)) if s_minor else ((2,), (2,)))   # [heads, wp, rows]
             k_pos = blk * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q_pos = pos + (q_row if group == 1 else q_row // group)    # a group's rows share a window row
             s = jnp.where(k_pos <= q_pos, s, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -224,11 +225,14 @@ def _kernel(layer_ref, pos_ref, act_ref, q_ref, kn_ref, vn_ref, ck_in, cv_in,
         o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
-def decode_attention(q, k, v, cache_k, cache_v, pos, active, layer):
+def decode_attention(q, k, v, cache_k, cache_v, pos, active, layer, group: int = 1):
     """Write-and-attend for one layer of the stacked cache.
 
     ``q``/``k``/``v`` ``[B, H, W, dh]``: the window's queries and its new
-    keys and values; ``cache_k``/``cache_v`` ``[L, B, H, S, dh]``; ``pos``
+    keys and values (``group`` > 1, grouped-query attention: ``H`` counts the
+    key/value heads and ``q`` is ``[B, H, W * group, dh]``, window row ``j``'s
+    ``group`` query heads at rows ``j * group ...``; they share the padded
+    16-row operand a single query row leaves mostly empty); ``cache_k``/``cache_v`` ``[L, B, H, S, dh]``; ``pos``
     ``[B]`` int32, each slot's write index for window row 0; ``active``
     ``[B]`` bool (None: every slot) gates a slot's write — an inactive
     slot's rows stay bitwise untouched and its output is zero; ``layer`` the
@@ -241,14 +245,14 @@ def decode_attention(q, k, v, cache_k, cache_v, pos, active, layer):
     from jax.experimental.pallas import tpu as pltpu
 
     L, B, H, S, dh = cache_k.shape
-    W = q.shape[2]
+    W, q_rows = k.shape[2], q.shape[2]
     heads, rows, tile = _plan(H, S, dh, cache_k.dtype)
     s_minor = _s_minor(dh)
     item = jnp.dtype(cache_k.dtype).itemsize
-    wp = -(-W // _sublanes(q.dtype)) * _sublanes(q.dtype)   # the window, padded to a packed sublane tile
+    wp = -(-q_rows // _sublanes(q.dtype)) * _sublanes(q.dtype)   # the window, padded to a packed sublane tile
 
     q = q * jnp.asarray(1.0 / (dh ** 0.5), q.dtype)       # as the lax formulation scales it
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, wp - W), (0, 0)))
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, wp - q_rows), (0, 0)))
     if s_minor:
         # the cache in the order it is stored; the new rows as columns
         cache_k, cache_v = jnp.swapaxes(cache_k, -1, -2), jnp.swapaxes(cache_v, -1, -2)
@@ -264,7 +268,7 @@ def decode_attention(q, k, v, cache_k, cache_v, pos, active, layer):
     n_tiles = _tiles_touched(W, tile)
 
     call = pl.pallas_call(
-        functools.partial(_kernel, window=W, rows=rows, tile=tile, seq=S, s_minor=s_minor),
+        functools.partial(_kernel, window=W, rows=rows, tile=tile, seq=S, s_minor=s_minor, group=int(group)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, H // heads),
@@ -292,7 +296,7 @@ def decode_attention(q, k, v, cache_k, cache_v, pos, active, layer):
                                      q, k, v, cache_k, cache_v)
     if s_minor:
         cache_k, cache_v = jnp.swapaxes(cache_k, -1, -2), jnp.swapaxes(cache_v, -1, -2)
-    return att[:, :, :W], cache_k, cache_v
+    return att[:, :, :q_rows], cache_k, cache_v
 
 
 registry.define_kernel(

@@ -1,0 +1,81 @@
+"""Dropless top-k routing over gated experts, for a layer that is *told which
+experts it holds*.
+
+The router scores every expert of the model (``sigmoid`` scores, the ``k``
+largest, weights normalised over the chosen); this layer computes the part of
+the result that its own experts ``[first, first + count)`` give, for the
+(token, expert) pairs routed to them, and nothing for the others: on one chip
+of an expert-parallel group that is the chip's partial result, and no exchange
+runs. With ``held = (0, n_experts)`` it is the whole layer. No pair is ever
+dropped: there is no capacity.
+
+The held pairs are sorted by expert, so each expert's rows are one run, and
+the two projections are grouped matmuls over the runs with static shapes (the
+worst case: every pair of the batch lands here, ``T * k`` rows).
+``lax.ragged_dot`` is the grouped matmul: XLA compiles it for the TPU to one
+Mosaic kernel that visits only the (row tile, expert) pairs that hold rows,
+so a decode step reads an expert's weights only if a token chose it (PERF.md
+§6, PR 30, has why this and not ``ops/moe_pallas.py``, whose kernel pads every
+expert's run to a capacity). The pairs go back to token order by a gather
+and a weighted sum over each token's ``k``.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["route_topk", "dropless_experts", "gated_ffn"]
+
+
+def route_topk(x, router_w, *, top_k: int, norm_topk: bool = True, scale: float = 1.0):
+    """``(weights [T, k] float32, experts [T, k] int32)`` over all of the
+    router's outputs: sigmoid scores in float32, the ``k`` largest, their
+    weights normalised over the chosen and scaled."""
+    with jax.named_scope("moe_router"):
+        scores = jax.nn.sigmoid(jnp.matmul(x, router_w, preferred_element_type=jnp.float32))
+        w, idx = jax.lax.top_k(scores, int(top_k))
+        if norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return w * scale, idx.astype(jnp.int32)
+
+
+def gated_ffn(x, w_gate_up, w_down):
+    """``W_down(SiLU(W_gate x) * W_up x)`` with gate and up packed side by
+    side in ``w_gate_up [D, 2F]``."""
+    h = jnp.matmul(x, w_gate_up, preferred_element_type=jnp.float32)
+    f = w_down.shape[-2]
+    return jnp.matmul((jax.nn.silu(h[..., :f]) * h[..., f:]).astype(x.dtype), w_down,
+                      preferred_element_type=jnp.float32)
+
+
+def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held):
+    """The held experts' part of the routed result.
+
+    ``x [T, D]``; ``weights``/``experts`` ``[T, k]`` from :func:`route_topk`;
+    ``w_gate_up [count, D, 2F]``, ``w_down [count, F, D]`` the held experts'
+    weights; ``held = (first, count)``. Returns ``(y [T, D] float32, stats
+    int32[2])`` with ``stats = (pairs routed to a held expert, held experts
+    with at least one pair)``."""
+    first, count = int(held[0]), int(held[1])
+    T, k = experts.shape
+    with jax.named_scope("moe_routed"):
+        local = experts - first
+        mine = (local >= 0) & (local < count)
+        key = jnp.where(mine, local, count).reshape(-1)                 # absent experts sort last
+        order = jnp.argsort(key, stable=True)                           # [T*k]: held pairs first, by expert
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        rows = jnp.take(x, order // k, axis=0)                          # [T*k, D]
+        # bf16 operands at the default precision, as the other kernels pin theirs: XLA's grouped matmul is a Mosaic
+        # kernel, and Mosaic refuses a higher one on bf16 (a process-wide "highest" must not reach it)
+        precision = jax.lax.Precision.DEFAULT if rows.dtype == jnp.bfloat16 else None
+        h = jax.lax.ragged_dot(rows, w_gate_up, sizes, precision=precision, preferred_element_type=jnp.float32)
+        f = w_down.shape[-2]
+        a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        y = jax.lax.ragged_dot(a, w_down, sizes, precision=precision, preferred_element_type=jnp.float32)
+        # back to token order: pair p sits at row inverse[p]; rows past the
+        # held pairs belong to no run, so whatever they hold is masked out
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        y = jnp.take(y, inverse, axis=0).reshape(T, k, -1)
+        y = jnp.sum(jnp.where(mine[..., None], y * weights[..., None], 0.0), axis=1)
+        stats = jnp.stack([jnp.sum(mine.astype(jnp.int32)), jnp.sum((sizes > 0).astype(jnp.int32))])
+    return y, stats

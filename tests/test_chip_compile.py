@@ -227,6 +227,110 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
     assert set(touched) <= _CACHE_MAY_PASS_THROUGH, touched
 
 
+# What the engine's three GPT programs lower to at the two serving cells' shapes, as PR 29's engine (which imported
+# ``models/gpt.py``'s private forwards by name) lowered them: sha256 of the StableHLO text, and its lines. Not in the
+# hash (taken under this suite's conftest: its matmul-precision pin is in the text): the names of the results (``jax.result_info``: the cache is one argument of two leaves now) and the Mosaic
+# kernel's serialized body, which carries the kernel file's line numbers. A PR that means to change a GPT serving
+# program changes these with it.
+_PARENT_PROGRAMS = {
+    "cerebras-gpt-1.3b.serve-longgen": {"decode_fn": ("bc2c4b6c6f63602d", 3274), "chunk_core": ("a62eab5d420e6b87", 4747),
+                                        "chunk_final_core": ("bda3c0fbf1929d7c", 4988)},
+    "gpt2-medium.serve-chat": {"decode_fn": ("f76958b28793439a", 3418), "chunk_core": ("72c1ac6ef53f24dc", 4747),
+                               "chunk_final_core": ("83884956b5b900e0", 4988)},
+}
+
+
+def _fingerprint(lowered_text):
+    import hashlib
+
+    text = re.sub(r'backend_config = "(?:\\.|[^"\\])*"', 'backend_config = ""', lowered_text)
+    text = re.sub(r'jax\.result_info = "[^"]*"', "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16], text.count("\n")
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE))
+def test_gpt_serving_programs_are_the_parents_through_the_decoder_interface(as_tpu, one_chip, cell):
+    """``DecodeEngine`` takes GPT's forwards from ``model.decoder()`` and no longer by name from ``models/gpt.py``.
+    Its decode, chunk and final-chunk programs, lowered for the described chip at the two cells' shapes (chunk 128,
+    bf16, abstract arguments), are the programs the parent's engine lowered: same fingerprint, same line count.
+    The engine is built from a one-layer model of the cell's width and its decoder told the cell's depth: the
+    programs take every shape from their arguments and only the layer indices from the decoder."""
+    from paddle_tpu.inference import DecodeEngine
+
+    L, B, H, S, D = (_DECODE[cell][k] for k in "LBHSD")
+    C, V, dh, bf = 128, 50304, D // H, jnp.bfloat16
+    paddle.seed(0)
+    model = GPTForPretraining(GPTConfig(vocab_size=512, hidden_size=D, num_layers=1, num_heads=H, max_seq_len=S))
+    model.astype("bfloat16")
+    engine = DecodeEngine(model, max_batch_slots=2, max_seq_len=S, prefill_chunk=C)
+    engine._dec.idx = jnp.arange(L, dtype=jnp.int32)
+    engine._build()
+    stack = tuple(one_chip((L,) + shape, bf) for shape in (
+        (D,), (D,), (D, 3 * D), (3 * D,), (D, D), (D,), (D,), (D,), (D, 4 * D), (4 * D,), (4 * D, D), (D,)))
+    p = {"stack": stack, "wte": one_chip((V, D), bf), "wpe": one_chip((S, D), bf),
+         "fnw": one_chip((D,), bf), "fnb": one_chip((D,), bf)}
+    cache = (one_chip((L, B, H, S, dh), bf),) * 2
+    scalar, ids = one_chip((), jnp.int32), one_chip((1, C), jnp.int32)
+    slots = lambda dt: one_chip((B,), dt)  # noqa: E731
+    state = (slots(jnp.int32), slots(jnp.int32), slots(jnp.bool_))
+    programs = {
+        "decode_fn": (engine._decode_jit, (p, cache) + state + (slots(jnp.int32),) * 3),
+        "chunk_core": (engine._chunk_jit, (p, cache, ids, scalar, scalar)),
+        "chunk_final_core": (engine._chunk_final_jit, (p, cache) + state + (ids,) + (scalar,) * 7),
+    }
+    got = {name: _fingerprint(fn.lower(*args).as_text()) for name, (fn, args) in programs.items()}
+    assert got == _PARENT_PROGRAMS[cell]
+
+
+def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu, one_chip):
+    """The decode program of ``solar-open2-250b.serve-reasoning`` (128 slots x 16,384, one chip's share at the published
+    widths, bf16, abstract arguments) compiles for the described chip: the GQA layer through ``decode_attn`` by group,
+    two grouped matmuls an expert layer, every slot buffer updated in place, and next to no temporaries — which holds
+    only while a layer's experts are an array of their own (a slice of a stack is copied out for the grouped matmul:
+    4.2 GB) and the recurrent state is one buffer a layer."""
+    import json
+
+    from paddle_tpu.inference import DecodeEngine
+    from paddle_tpu.models.solar_open2 import F32_WEIGHTS, PER_LAYER_WEIGHTS, SolarOpen2Config, SolarOpen2ForCausalLM
+    from paddle_tpu.observability import metrics
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
+                           "solar-open2-250b.json")) as f:
+        cfg = SolarOpen2Config.from_config_file(json.load(f))
+    B, S, bf = 128, 16384, jnp.bfloat16
+    weights = {k: one_chip(shape, jnp.float32 if k in F32_WEIGHTS else bf) for k, shape in cfg.weight_shapes().items()}
+    for k in PER_LAYER_WEIGHTS:
+        weights[k] = tuple(one_chip(weights[k].shape[1:], bf) for _ in range(weights[k].shape[0]))
+    decoder = SolarOpen2ForCausalLM(cfg, weights=weights).decoder()
+    engine = DecodeEngine.__new__(DecodeEngine)       # the programs only: nothing is allocated, nothing runs
+    engine._dec, engine._ddec, engine._sample, engine.spec_k, engine._donate, engine._chunk = decoder, None, (False, 1.0, 0, 1.0), 0, True, 1024
+    engine._build()
+    cache = tuple(one_chip(spec.shape, spec.dtype) for spec in decoder.buffer_specs(B, S))
+    slots = lambda dt: one_chip((B,), dt)  # noqa: E731
+    metrics.reset_counters("kernels.decode_attention.")
+    compiled = engine._decode_jit.lower(weights, cache, slots(jnp.int32), slots(jnp.int32), slots(jnp.bool_),
+                                        slots(jnp.int32), slots(jnp.int32), slots(jnp.int32)).compile()
+    assert metrics.counters("kernels.decode_attention.")["kernels.decode_attention.picked"] == 1
+    text = compiled.as_text()
+    assert sum("custom-call(" in line and "decode_attn" in line for line in text.splitlines()) == len(cfg.gqa_layers)
+    assert sum("custom-call(" in line and '"ragged-dot-none"' in line for line in text.splitlines()) == 2 * cfg.num_hidden_layers
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
+    assert memory.alias_size_in_bytes >= held and memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
+    assert 6.9e9 < memory.argument_size_in_bytes < 7.1e9
+
+
+def test_engine_imports_no_private_function_of_a_model():
+    import ast
+    import inspect
+
+    from paddle_tpu.inference import engine
+
+    for node in ast.walk(ast.parse(inspect.getsource(engine))):
+        if isinstance(node, ast.ImportFrom) and "models" in (node.module or ""):
+            assert not [a.name for a in node.names if a.name.startswith("_")], (node.module, [a.name for a in node.names])
+
+
 def _train_step(model, make_step):
     opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters())
     return make_step(model, opt, GPTPretrainingCriterion())
