@@ -212,7 +212,7 @@ def _moe(cfg: SolarOpen2Config, p: dict, layer: int, x, routed=None):
     w, idx = route_topk(x, p["router"][layer], top_k=cfg.num_experts_per_tok,
                         norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor)
     y, stats = dropless_experts(x, w, idx, p["experts_gate_up"][layer], p["experts_down"][layer],
-                                held=cfg.held_experts)
+                                held=cfg.held_experts, n_experts=cfg.router_experts)
     with jax.named_scope("moe_shared"):
         y = y + gated_ffn(x, p["shared_gate_up"][layer], p["shared_down"][layer])
     if routed is not None:

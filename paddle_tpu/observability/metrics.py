@@ -256,6 +256,7 @@ KERNEL_COUNTERS: Tuple[str, ...] = (
     "kernels.attention_core.picked", "kernels.attention_core.fallback",
     "kernels.moe.picked", "kernels.moe.fallback",
     "kernels.decode_attention.picked", "kernels.decode_attention.fallback",
+    "kernels.grouped_matmul.picked", "kernels.grouped_matmul.fallback",
 )
 
 # SPMD sharding analyzer (paddle_tpu.analysis.spmd, FLAGS_shard_check):

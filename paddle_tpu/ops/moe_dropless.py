@@ -11,18 +11,24 @@ dropped: there is no capacity.
 
 The held pairs are sorted by expert, so each expert's rows are one run, and
 the two projections are grouped matmuls over the runs with static shapes (the
-worst case: every pair of the batch lands here, ``T * k`` rows).
-``lax.ragged_dot`` is the grouped matmul: XLA compiles it for the TPU to one
-Mosaic kernel that visits only the (row tile, expert) pairs that hold rows,
-so a decode step reads an expert's weights only if a token chose it (PERF.md
-§6, PR 30, has why this and not ``ops/moe_pallas.py``, whose kernel pads every
-expert's run to a capacity). The pairs go back to token order by a gather
-and a weighted sum over each token's ``k``.
+worst case: every pair of the batch lands here, ``T * k`` rows). The grouped
+matmul is the ``grouped_matmul`` registry entry's choice: on the TPU, for
+bf16 operands with no mesh, the Pallas kernel of ``ops/grouped_matmul.py``,
+which visits only the (row tile, expert) pairs that hold rows — so a decode
+step reads an expert's weights only if a token chose it — with a row tile
+sized to the run a batch of this size is expected to give an expert (``T * k``
+pairs over the router's width); everywhere else ``lax.ragged_dot``, which XLA
+compiles for the TPU to a kernel of the same visiting order with 512-row
+tiles (PERF.md §6, PR 30 and PR 31, have why neither is ``ops/moe_pallas.py``,
+whose kernel pads every expert's run to a capacity). The pairs go back to
+token order by a gather and a weighted sum over each token's ``k``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from . import grouped_matmul as _grouped
 
 __all__ = ["route_topk", "dropless_experts", "gated_ffn"]
 
@@ -48,14 +54,16 @@ def gated_ffn(x, w_gate_up, w_down):
                       preferred_element_type=jnp.float32)
 
 
-def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held):
+def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held, n_experts=None):
     """The held experts' part of the routed result.
 
     ``x [T, D]``; ``weights``/``experts`` ``[T, k]`` from :func:`route_topk`;
     ``w_gate_up [count, D, 2F]``, ``w_down [count, F, D]`` the held experts'
-    weights; ``held = (first, count)``. Returns ``(y [T, D] float32, stats
-    int32[2])`` with ``stats = (pairs routed to a held expert, held experts
-    with at least one pair)``."""
+    weights; ``held = (first, count)``; ``n_experts`` the router's width
+    (``count`` if not given: the layer holds them all), from which the
+    grouped matmul takes the run it should expect. Returns ``(y [T, D]
+    float32, stats int32[2])`` with ``stats = (pairs routed to a held expert,
+    held experts with at least one pair)``."""
     first, count = int(held[0]), int(held[1])
     T, k = experts.shape
     with jax.named_scope("moe_routed"):
@@ -65,13 +73,13 @@ def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held):
         order = jnp.argsort(key, stable=True)                           # [T*k]: held pairs first, by expert
         sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
         rows = jnp.take(x, order // k, axis=0)                          # [T*k, D]
-        # bf16 operands at the default precision, as the other kernels pin theirs: XLA's grouped matmul is a Mosaic
-        # kernel, and Mosaic refuses a higher one on bf16 (a process-wide "highest" must not reach it)
-        precision = jax.lax.Precision.DEFAULT if rows.dtype == jnp.bfloat16 else None
-        h = jax.lax.ragged_dot(rows, w_gate_up, sizes, precision=precision, preferred_element_type=jnp.float32)
+        # one selection for both projections (and so one per compiled program: every layer's call has these shapes)
+        expected_run = T * k / int(n_experts or count)
+        matmul = _grouped.select(rows, w_gate_up, w_down, expected_run=expected_run)
+        h = matmul(rows, w_gate_up, sizes, expected_run=expected_run)
         f = w_down.shape[-2]
         a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
-        y = jax.lax.ragged_dot(a, w_down, sizes, precision=precision, preferred_element_type=jnp.float32)
+        y = matmul(a, w_down, sizes, expected_run=expected_run)
         # back to token order: pair p sits at row inverse[p]; rows past the
         # held pairs belong to no run, so whatever they hold is masked out
         inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))

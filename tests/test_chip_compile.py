@@ -282,12 +282,15 @@ def test_gpt_serving_programs_are_the_parents_through_the_decoder_interface(as_t
     assert got == _PARENT_PROGRAMS[cell]
 
 
-def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu, one_chip):
+@pytest.mark.parametrize("program", ["decode_fn", "chunk_core"])
+def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu, one_chip, program):
     """The decode program of ``solar-open2-250b.serve-reasoning`` (128 slots x 16,384, one chip's share at the published
     widths, bf16, abstract arguments) compiles for the described chip: the GQA layer through ``decode_attn`` by group,
-    two grouped matmuls an expert layer, every slot buffer updated in place, and next to no temporaries — which holds
-    only while a layer's experts are an array of their own (a slice of a stack is copied out for the grouped matmul:
-    4.2 GB) and the recurrent state is one buffer a layer."""
+    two grouped matmuls an expert layer through ``ops/grouped_matmul.py`` (one selection for the program; its row tile
+    32 for 1,024 pairs over a router of 320), every slot buffer updated in place, and next to no temporaries — which
+    holds only while a layer's experts are an array of their own (a slice of a stack is copied out for the grouped
+    matmul: 4.2 GB) and the recurrent state is one buffer a layer. The chunk program at 1,024 tokens the same way: its
+    8,192 pairs in tiles of 128 rows."""
     import json
 
     from paddle_tpu.inference import DecodeEngine
@@ -297,27 +300,40 @@ def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu,
     with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
                            "solar-open2-250b.json")) as f:
         cfg = SolarOpen2Config.from_config_file(json.load(f))
-    B, S, bf = 128, 16384, jnp.bfloat16
+    B, S, C, bf = 128, 16384, 1024, jnp.bfloat16
     weights = {k: one_chip(shape, jnp.float32 if k in F32_WEIGHTS else bf) for k, shape in cfg.weight_shapes().items()}
     for k in PER_LAYER_WEIGHTS:
         weights[k] = tuple(one_chip(weights[k].shape[1:], bf) for _ in range(weights[k].shape[0]))
     decoder = SolarOpen2ForCausalLM(cfg, weights=weights).decoder()
     engine = DecodeEngine.__new__(DecodeEngine)       # the programs only: nothing is allocated, nothing runs
-    engine._dec, engine._ddec, engine._sample, engine.spec_k, engine._donate, engine._chunk = decoder, None, (False, 1.0, 0, 1.0), 0, True, 1024
+    engine._dec, engine._ddec, engine._sample, engine.spec_k, engine._donate, engine._chunk = decoder, None, (False, 1.0, 0, 1.0), 0, True, C
     engine._build()
     cache = tuple(one_chip(spec.shape, spec.dtype) for spec in decoder.buffer_specs(B, S))
     slots = lambda dt: one_chip((B,), dt)  # noqa: E731
-    metrics.reset_counters("kernels.decode_attention.")
-    compiled = engine._decode_jit.lower(weights, cache, slots(jnp.int32), slots(jnp.int32), slots(jnp.bool_),
-                                        slots(jnp.int32), slots(jnp.int32), slots(jnp.int32)).compile()
-    assert metrics.counters("kernels.decode_attention.")["kernels.decode_attention.picked"] == 1
-    text = compiled.as_text()
-    assert sum("custom-call(" in line and "decode_attn" in line for line in text.splitlines()) == len(cfg.gqa_layers)
-    assert sum("custom-call(" in line and '"ragged-dot-none"' in line for line in text.splitlines()) == 2 * cfg.num_hidden_layers
+    scalar = one_chip((), jnp.int32)
+    metrics.reset_counters("kernels.")
+    if program == "decode_fn":
+        compiled = engine._decode_jit.lower(weights, cache, slots(jnp.int32), slots(jnp.int32), slots(jnp.bool_),
+                                            slots(jnp.int32), slots(jnp.int32), slots(jnp.int32)).compile()
+        assert metrics.counters("kernels.decode_attention.")["kernels.decode_attention.picked"] == 1
+    else:
+        compiled = engine._chunk_jit.lower(weights, cache, one_chip((1, C), jnp.int32), scalar, scalar).compile()
+    assert metrics.counters("kernels.grouped_matmul.") == {"kernels.grouped_matmul.picked": 1, "kernels.grouped_matmul.fallback": 0}
+    calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+    # an intermediate chunk returns the buffers only, so the last layer's experts, which feed none, are not compiled
+    tm, layers = (32, cfg.num_hidden_layers) if program == "decode_fn" else (128, cfg.num_hidden_layers - 1)
+    assert sum(f"%moe_grouped_{tm}" in line for line in calls) == 2 * layers
+    assert not any("ragged-dot" in line for line in calls)
+    routed = [line for line in calls if "moe_grouped" in line]
+    assert all("/moe_routed/" in line for line in routed), routed[0]      # the scope the by-part readers take it by
     memory = compiled.memory_analysis()
     held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
-    assert memory.alias_size_in_bytes >= held and memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
-    assert 6.9e9 < memory.argument_size_in_bytes < 7.1e9
+    if program == "decode_fn":
+        assert 6.9e9 < memory.argument_size_in_bytes < 7.1e9
+        assert sum("decode_attn" in line for line in calls) == len(cfg.gqa_layers)
+        assert memory.alias_size_in_bytes >= held and memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
+    else:
+        assert memory.temp_size_in_bytes < 0.6e9, memory.temp_size_in_bytes      # 0.55 GB with XLA's grouped matmul
 
 
 def test_engine_imports_no_private_function_of_a_model():

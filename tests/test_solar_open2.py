@@ -208,28 +208,45 @@ def test_slot_state(models, case):
 
 
 # ------------------------------------------------ (e) dropless
-@pytest.mark.parametrize("case", ["all_to_one_held_expert", "counts_equal_numpy"])
+@pytest.mark.parametrize("case", ["all_to_one_held_expert", "counts_equal_numpy", "picked_and_declined_paths_agree"])
 def test_dropless_routing(case):
     rng = np.random.default_rng(2)
     T, D, F, E, k, held = 40, 32, 16, 12, 3, (4, 6)
-    x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
-    gate_up = jnp.asarray(rng.normal(size=(held[1], D, 2 * F)) * 0.1, jnp.float32)
-    down = jnp.asarray(rng.normal(size=(held[1], F, D)) * 0.1, jnp.float32)
+    if case == "picked_and_declined_paths_agree":
+        T, D, F, k = 64, 128, 128, 4         # bf16 at widths the grouped-matmul kernel tiles: 256 pairs, tiles of 64 rows
+    dtype = jnp.bfloat16 if case == "picked_and_declined_paths_agree" else jnp.float32
+    x = jnp.asarray(rng.normal(size=(T, D)), dtype)
+    gate_up = jnp.asarray(rng.normal(size=(held[1], D, 2 * F)) * 0.1, dtype)
+    down = jnp.asarray(rng.normal(size=(held[1], F, D)) * 0.1, dtype)
     if case == "all_to_one_held_expert":
         experts = jnp.full((T, k), 6, jnp.int32).at[:, 1].set(0).at[:, 2].set(11)      # one held, two absent
         weights = jnp.full((T, k), 1.0 / k, jnp.float32)
     else:
-        weights, experts = route_topk(x, jnp.asarray(rng.normal(size=(D, E)), jnp.float32), top_k=k)
-    y, stats = dropless_experts(x, weights, experts, gate_up, down, held=held)
+        weights, experts = route_topk(x, jnp.asarray(rng.normal(size=(D, E)), dtype), top_k=k)
+    y, stats = dropless_experts(x, weights, experts, gate_up, down, held=held, n_experts=E)
     idx, w = np.asarray(experts), np.asarray(weights)
     mine = (idx >= held[0]) & (idx < held[0] + held[1])
     assert [int(v) for v in stats] == [int(mine.sum()), len(np.unique(idx[mine]))]
+    if case == "picked_and_declined_paths_agree":
+        from paddle_tpu.observability import metrics
+        from paddle_tpu.ops import grouped_matmul
+
+        metrics.reset_counters("kernels.grouped_matmul.")
+        prior = grouped_matmul.set_interpret(True)
+        try:
+            y_picked, stats_picked = dropless_experts(x, weights, experts, gate_up, down, held=held, n_experts=E)
+        finally:
+            grouped_matmul.set_interpret(prior)
+        assert metrics.counters("kernels.grouped_matmul.")["kernels.grouped_matmul.picked"] == 1
+        np.testing.assert_array_equal(np.asarray(stats_picked), np.asarray(stats))
+        assert _rel(y_picked, y) < 2 ** -8      # the same bf16 operands summed in float32, in another order at most
+        x, gate_up, down = (a.astype(jnp.float32) for a in (x, gate_up, down))
     with jax.default_matmul_precision("highest"):
         want = np.zeros((T, D), np.float32)
         for e in np.unique(idx[mine]):
             w_e = np.where(idx == e, w, 0.0).sum(-1)
             want += w_e[:, None] * np.asarray(gated_ffn(x, gate_up[e - held[0]], down[e - held[0]]))
-    assert _rel(y, want) < 2e-5
+    assert _rel(y, want) < (2e-5 if dtype == jnp.float32 else 2 ** -7)   # bf16: the activation between the projections
     if case == "all_to_one_held_expert":
         assert int(stats[0]) == T and int(stats[1]) == 1            # every token's pair computed: nothing dropped
         assert np.all(np.abs(np.asarray(y)).sum(-1) > 0)
