@@ -38,8 +38,8 @@ import time
 
 import numpy as np
 
-# -- sizes: the flagship configuration at full width (bench.py's training
-# config, BASELINE.md's decode config). tests/test_chip_smoke.py swaps these
+# -- sizes: the flagship configuration at full width (gpt2-medium's widths at
+# 16 layers). tests/test_chip_smoke.py swaps these
 # for a tiny set; nothing else selects a size.
 MODEL = dict(vocab_size=50304, hidden_size=1024, num_layers=16, num_heads=16, max_seq_len=1024)
 TRAIN = dict(batch=8, seq=1024, steps=4, fused_k=8)

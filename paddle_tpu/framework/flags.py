@@ -62,7 +62,7 @@ def flag(name: str):
 define_flag("FLAGS_check_nan_inf", False, "check outputs for nan/inf after each eager op")
 define_flag("FLAGS_benchmark", False, "synchronize after each op for timing")
 define_flag("FLAGS_use_flash_attention", True, "use the Pallas flash-attention kernel when on TPU")
-define_flag("FLAGS_flash_flat", False, "use the flat-lane (zero-relayout) flash kernels for packed qkv attention. Microbench verdict (bench.py flash_micro phase, CPU interpret, fwd+bwd [1,256,2,64]): flat ~1.7x classic under the interpreter (one fused packed pallas_call vs the classic pair's separate fwd/bwd launches); interpreter timings don't transfer to TPU, so stays opt-in pending the on-chip step A/B (ROADMAP S2/D2)")
+define_flag("FLAGS_flash_flat", False, "use the flat-lane (zero-relayout) flash kernels for packed qkv attention; not timed on the chip against the classic pair (ROADMAP D2), so it stays opt-in")
 define_flag("FLAGS_kernel_overrides", "", "force kernel-registry implementations per kernel, e.g. 'moe=dense,sdpa=xla' (see paddle_tpu.ops.registry); forced impls bypass availability predicates; unknown impl names raise at dispatch")
 define_flag("FLAGS_eager_delete_tensor_gb", 0.0, "compat no-op: XLA/PJRT manages buffers")
 define_flag("FLAGS_allocator_strategy", "auto_growth", "compat no-op: PJRT BFC allocator is used")
@@ -73,7 +73,7 @@ define_flag("FLAGS_shard_check", False, "run the paddle_tpu.analysis.spmd PTA2xx
 define_flag("FLAGS_hbm_budget_mb", 0.0, "per-device memory budget in MiB for the PTA204 pre-flight: a lowered program whose XLA memory_analysis estimate exceeds this raises under FLAGS_shard_check before the first dispatch (0 = unlimited)")
 define_flag("FLAGS_compile_cache_dir", "", "persistent XLA compilation cache directory (jax_compilation_cache_dir) and root of the AOT executable store: repeated runs of the same program skip recompiles. When the environment sets JAX_COMPILATION_CACHE_DIR that directory is used and this flag places nothing (see compile_cache_dir())")
 
-#: where entry points (chip_smoke.py, the bench scripts, the launcher) keep
+#: where entry points (chip_smoke.py, benchmark/run.py, the launcher) keep
 #: the cache when nothing outside placed it: one fixed path inside the
 #: checkout — the path is part of the cache key, so a directory that moves
 #: never hits
@@ -124,12 +124,12 @@ define_flag("FLAGS_monitor", True, "always-on runtime telemetry: step/compile/ch
 define_flag("FLAGS_run_log_dir", "", "directory for the structured run log (JSONL, one run-<pid>.jsonl per process); empty keeps events only in the in-memory ring")
 define_flag("FLAGS_run_log_max_mb", 64.0, "size-based run-log rotation: when run-<pid>.jsonl exceeds this many MiB it is renamed to run-<pid>.1.jsonl (replacing any prior rotation) and a fresh file is opened; 0 disables rotation (unbounded growth)")
 define_flag("FLAGS_run_log_keep", 16, "keep-last-k GC of stale run logs: when a process opens its run log it deletes dead pids' run-*.jsonl files under FLAGS_run_log_dir beyond the newest k (by mtime); 0 disables the GC")
-define_flag("FLAGS_trace", True, "distributed tracing plane (observability/trace.py): deterministic per-request/per-run trace ids propagated through ServingFleet submit->route->prefill->decode->requeue->delivery and run_resilient per-step/per-incident spans, emitted as 'span' run-log events; off allocates no ids and emits no span events (the bench's tracing-off arm)")
+define_flag("FLAGS_trace", True, "distributed tracing plane (observability/trace.py): deterministic per-request/per-run trace ids propagated through ServingFleet submit->route->prefill->decode->requeue->delivery and run_resilient per-step/per-incident spans, emitted as 'span' run-log events; off allocates no ids and emits no span events")
 define_flag("FLAGS_metrics_port", 0, "live metrics export (observability/exporter.py): serve /metrics (Prometheus text), /healthz and /snapshot (JSON) on this localhost port from a stdlib HTTP server started by ServingFleet and run_resilient workers; 0 (default) disables the server")
 define_flag("FLAGS_flightrec_events", 256, "crash flight recorder (observability/flightrec.py): dump the last N run-log ring events plus a metrics snapshot to flightrec-<pid>.json on replica death, DivergenceFault, PTA204/205 analysis errors and unhandled dispatch exceptions; 0 disables the recorder")
 define_flag("FLAGS_slo", False, "judgment layer (observability/slo.py + regress.py): auto-install the default SLO spec set on the first serving/training tick and evaluate it on the FLAGS_slo_eval_every_s cadence — error budgets, multi-window burn-rate alerts ('alert' run-log events, /alerts, degraded /healthz while a page fires) and the perf-regression sentinel; off keeps every tick-loop hook a single flag check (explicit slo.install() still works)")
 define_flag("FLAGS_slo_eval_every_s", 5.0, "SLOMonitor evaluation cadence in seconds: tick-loop hooks (scheduler/fleet/procfleet step, TrainStep.run_steps) evaluate the registered spec set at most this often; evaluation is host-side reads of the lock-free metrics registries — never a device sync")
-define_flag("FLAGS_slo_fast_window_s", 300.0, "fast burn-rate window (seconds) for SLO alerting: the page-severity window — a burn rate >= the spec's page_burn sustained over this window pages. ~5 minutes in production; tests and the bench alerting arm shrink it to sub-second")
+define_flag("FLAGS_slo_fast_window_s", 300.0, "fast burn-rate window (seconds) for SLO alerting: the page-severity window — a burn rate >= the spec's page_burn sustained over this window pages. ~5 minutes in production; tests shrink it to sub-second")
 define_flag("FLAGS_slo_slow_window_s", 3600.0, "slow burn-rate window (seconds) for SLO alerting: the warn-severity window and the second gate of the page condition for ratio SLOs (classic multi-window burn-rate alerting). ~1 hour in production")
 
 # Fault-tolerance runtime (distributed/resilience.py).

@@ -9,8 +9,8 @@ the raw PJRT executable plus its call trees) under
 ``<dir>/serving/<key>.aotc``, keyed on the (kind, argument avals, engine
 fingerprint, jax version, device) specialization. A fresh engine with the
 same specialization loads the executable instead of recompiling: restart
-``time_to_first_token`` drops to deserialize+dispatch cost
-(bench_serve.py reports it as ``restart_ttft``).
+``time_to_first_token`` drops to deserialize+dispatch cost (the benchmark's
+``setup_s`` holds it: every cell starts from a warm compile cache).
 
 The same store serves *training*: ``TrainStep`` and the static ``Executor``
 round-trip their compiled step programs through ``<dir>/train_step/`` and

@@ -346,8 +346,7 @@ class ServingFleet:
     def scale_out(self, n: int = 1) -> List[int]:
         """Add ``n`` replicas live. With a warm ``FLAGS_compile_cache_dir``
         the new replicas' program family loads from the AOT executable cache
-        — first token at ``infer.compiles == 0`` (the bench's
-        ``scaleout_ttft_ms``)."""
+        — first token at ``infer.compiles == 0``."""
         new = [self._add_replica().rid for _ in range(int(n))]
         counter_inc("fleet.scale_outs", len(new))
         _runlog.emit("fleet", kind="scale_out", component="fleet", replicas=new)

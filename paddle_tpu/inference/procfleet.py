@@ -483,7 +483,7 @@ class ProcServingFleet:
         self.poll_s = float(poll_s)
         # socket fast path: children advertise a framed-TCP endpoint the
         # parent dials once ready; False pins everything to the store
-        # transport (the bench's socket_vs_store_overhead_pct baseline arm)
+        # transport
         self.use_sockets = bool(use_sockets)
         self.router = Router(chunk=engine_kwargs.get("prefill_chunk"),
                              affinity_load_slack=affinity_load_slack)

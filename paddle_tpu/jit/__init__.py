@@ -530,7 +530,7 @@ class TrainStep:
         batch-shape) signature with the XLA ``cost_analysis``/
         ``memory_analysis`` captured at compile time (flops, bytes accessed,
         peak device memory, compile seconds). Render with
-        ``paddle_tpu.observability.format_cost_table``; bench.py prints it.
+        ``paddle_tpu.observability.format_cost_table``.
 
         ``analyze=True`` additionally runs the SPMD sharding analyzer
         (paddle_tpu.analysis.spmd, PTA2xx) over each retained executable and

@@ -22,7 +22,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import nn
 from ..distributed.mp_layers import (
@@ -42,11 +42,12 @@ class GPTConfig:
     """Hyperparameters. ``gpt3_1p3b()`` is the BASELINE.json config #4 model.
 
     ``stacked=True`` (default) builds the trunk as :class:`GPTBlockStack` —
-    all L blocks as [L, ...]-stacked parameters run via lax.scan (one block
-    trace, fast compile) or, under a fleet mesh with pp_degree>1, via the
-    spmd_pipeline over the 'pp' axis. ``recompute=True`` turns on per-layer
-    rematerialization inside the scan/pipeline (activation memory ~O(L·input)
-    instead of O(L·all-intermediates)).
+    all L blocks as [L, ...]-stacked parameters, run as a statically unrolled
+    loop over the layers (:func:`_stack_forward` says why not ``lax.scan``)
+    or, under a fleet mesh with pp_degree>1, via the spmd_pipeline over the
+    'pp' axis. ``recompute=True`` turns on per-layer rematerialization inside
+    the loop/pipeline (activation memory ~O(L·input) instead of
+    O(L·all-intermediates)).
     """
 
     def __init__(
@@ -272,38 +273,20 @@ class GPTBlock(nn.Layer):
         return x
 
 
-def _attn_core(q, k, v, attn_dropout=0.0, key=None):
-    """Pure-array causal self-attention via the ``sdpa`` kernel-registry
-    entry: Pallas flash kernel on TPU when shapes allow, jnp reference
-    otherwise (same selection the eager F.scaled_dot_product_attention
-    makes)."""
-    from ..ops import registry
-
-    return registry.dispatch("sdpa", q, k, v, None, True, attn_dropout, key, None)
-
-
-def _attn_core_packed(qkv, attn_dropout=0.0, key=None):
-    """Same over the packed [b, s, 3, h, d] qkv-projection output, via the
-    ``attention_core`` registry entry: the flat-lane kernels read q/k/v via
-    index maps and return the packed d(qkv) in backward — avoiding the
-    slice/relayout copies of the split form — with the classic pair and the
-    jnp reference as ordered fallbacks."""
-    from ..ops import registry
-
-    return registry.dispatch("attention_core", qkv, attn_dropout, key)
-
-
 def _block_apply(lp, h, key, *, num_heads, dropout=0.0, attn_dropout=0.0, epsilon=1e-5):
-    """One pre-LN decoder block on raw arrays. ``lp`` = (12 stacked-param
-    slices, layer index); ``key`` = dropout PRNG key or None."""
+    """One pre-LN decoder block on raw arrays, the train step's. ``lp`` = (12
+    stacked-param slices, layer index); ``key`` = dropout PRNG key or None.
+
+    Not :func:`_serve_block` with a training mixer: besides its norm (the
+    closed-form vjp), its attention entry (packed qkv) and dropout, it adds the
+    bias before the residual (``h + drop(att @ ow + ob)``) where the serving
+    block adds the residual first (``h + att @ ow + ob``). Merging the two
+    changes the rounding, and so the compiled program, of one of them."""
+    # closed-form vjp: the autodiff of mean/var compiles to extra backward
+    # reduce fusions a layer on the TPU (ops/layer_norm.py)
     from ..ops.layer_norm import layer_norm_fused
 
     (n1w, n1b, qkvw, qkvb, ow, ob, n2w, n2b, f1w, f1b, f2w, f2b), idx = lp
-
-    def ln(v, w, b):
-        # fused closed-form vjp: autodiff-of-mean/var compiled to ~0.7ms/layer
-        # of backward reduce fusions on TPU (r4 profile); see ops/layer_norm.py
-        return layer_norm_fused(v, w, b, epsilon)
 
     def drop(v, p, k):
         if p == 0.0 or k is None:
@@ -319,15 +302,19 @@ def _block_apply(lp, h, key, *, num_heads, dropout=0.0, attn_dropout=0.0, epsilo
     b, s, d = h.shape
     hd = d // num_heads
     with jax.named_scope("norm"):
-        x1 = ln(h, n1w, n1b)
+        x1 = layer_norm_fused(h, n1w, n1b, epsilon)
     with jax.named_scope("attn_qkv"):
         qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
     with jax.named_scope("attn_core"):
-        att = _attn_core_packed(qkv, attn_dropout, k_attn).reshape(b, s, d)
+        # the packed [b, s, 3, H, dh] projection goes to the ``attention_core``
+        # registry entry whole: the flat-lane kernels read q/k/v through index
+        # maps and return the packed d(qkv) in backward, with the classic pair
+        # and the jnp reference as ordered fallbacks
+        att = _registry.dispatch("attention_core", qkv, attn_dropout, k_attn).reshape(b, s, d)
     with jax.named_scope("attn_out"):
         h = h + drop(att @ ow + ob, dropout, k_res1)
     with jax.named_scope("norm"):
-        x2 = ln(h, n2w, n2b)
+        x2 = layer_norm_fused(h, n2w, n2b, epsilon)
     with jax.named_scope("mlp"):
         y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
         h = h + drop(y @ f2w + f2b, dropout, k_res2)
@@ -353,10 +340,8 @@ def _layer_params(params, idx, i):
 
 
 def _stack_forward(x, *rest, num_layers, num_heads, dropout, attn_dropout, recompute, has_key, mesh, n_micro):
-    """Whole-trunk forward on raw arrays: scan over layers (pp==1) or
-    spmd_pipeline over the 'pp' mesh axis (pp>1)."""
-    from jax.sharding import NamedSharding
-
+    """Whole-trunk forward on raw arrays, the train step's: an unrolled loop
+    over the layers (pp==1) or spmd_pipeline over the 'pp' mesh axis (pp>1)."""
     from ..distributed.pipeline import active_pipeline_schedule, microbatch, spmd_pipeline, unmicrobatch
 
     if has_key:
@@ -368,10 +353,10 @@ def _stack_forward(x, *rest, num_layers, num_heads, dropout, attn_dropout, recom
     block = functools.partial(_block_apply, num_heads=num_heads, dropout=dropout, attn_dropout=attn_dropout)
 
     def constrain(h):
-        """Pin the scan carry's sharding (batch over dp×sdp, seq over 'sep',
-        hidden replicated). Without this GSPMD flip-flops the carry between
-        batch- and mp-sharded layouts at the loop boundary — the 'Involuntary
-        full rematerialization' warnings (VERDICT r2)."""
+        """Pin the hidden state's sharding between layers (batch over
+        dp×sdp, seq over 'sep', hidden replicated). Without this GSPMD
+        flip-flops it between batch- and mp-sharded layouts from one layer to
+        the next: 'Involuntary full rematerialization' warnings."""
         if mesh is None:
             return h
         spec = P(("dp", "sdp"), "sep" if mesh.shape.get("sep", 1) > 1 else None, None)
@@ -413,8 +398,8 @@ def _stack_forward(x, *rest, num_layers, num_heads, dropout, attn_dropout, recom
 class GPTBlockStack(nn.Layer):
     """All decoder blocks as [L, ...]-stacked parameters: the leading axis
     shards over 'pp', per-tensor dims over 'mp'.
-    pp==1 runs one lax.scan (single block trace — XLA compiles the block
-    once); pp>1 runs the GPipe-schedule spmd_pipeline. Parity: the trunk of
+    pp==1 runs the layers as a statically unrolled loop; pp>1 runs the
+    GPipe-schedule spmd_pipeline. Parity: the trunk of
     pp_layers.py:162 PipelineLayer + mp_layers.py TP layers, as shardings.
     """
 
@@ -446,29 +431,16 @@ class GPTBlockStack(nn.Layer):
         self.ffn1_b = mk([L, Ff], I.Constant(0.0), mp_dim=1)
         self.ffn2_w = mk([L, Ff, D], init, mp_dim=1)
         self.ffn2_b = mk([L, D], I.Constant(0.0))
-        self._order = ["norm1_w", "norm1_b", "qkv_w", "qkv_b", "out_w", "out_b",
-                       "norm2_w", "norm2_b", "ffn1_w", "ffn1_b", "ffn2_w", "ffn2_b"]
+        self._order = list(GPTModel._PER_LAYER_TO_STACKED.values())
 
     def load_blocks(self, blocks):
         """Copy weights from a list of eager :class:`GPTBlock` (parity/test
         helper: LayerList trunk -> stacked trunk)."""
         import numpy as np
 
-        def stack(get):
-            return jnp.asarray(np.stack([np.asarray(get(b)) for b in blocks]))
-
-        self.norm1_w.set_value(stack(lambda b: b.norm1.weight.numpy()))
-        self.norm1_b.set_value(stack(lambda b: b.norm1.bias.numpy()))
-        self.qkv_w.set_value(stack(lambda b: b.attn.qkv_proj.weight.numpy()))
-        self.qkv_b.set_value(stack(lambda b: b.attn.qkv_proj.bias.numpy()))
-        self.out_w.set_value(stack(lambda b: b.attn.out_proj.weight.numpy()))
-        self.out_b.set_value(stack(lambda b: b.attn.out_proj.bias.numpy()))
-        self.norm2_w.set_value(stack(lambda b: b.norm2.weight.numpy()))
-        self.norm2_b.set_value(stack(lambda b: b.norm2.bias.numpy()))
-        self.ffn1_w.set_value(stack(lambda b: b.ffn1.weight.numpy()))
-        self.ffn1_b.set_value(stack(lambda b: b.ffn1.bias.numpy()))
-        self.ffn2_w.set_value(stack(lambda b: b.ffn2.weight.numpy()))
-        self.ffn2_b.set_value(stack(lambda b: b.ffn2.bias.numpy()))
+        for path, name in GPTModel._PER_LAYER_TO_STACKED.items():  # noqa: PTA102 (host-side, never traced)
+            per_layer = [functools.reduce(getattr, path.split("."), b).numpy() for b in blocks]
+            getattr(self, name).set_value(jnp.asarray(np.stack(per_layer)))
 
     def forward(self, x):
         from ..distributed.pipeline import active_pipeline_plan
@@ -528,14 +500,19 @@ def _kvc_read(c, dt):
     return _kv_dequant(c, dt) if isinstance(c, dict) else c
 
 
-def _kvc_update(c, u, idx):
+def _kvc_update(c, u, idx, gate=None):
     """In-place cache write of a compute-dtype update ``u`` at index tuple
-    ``idx`` (scale plane takes ``idx[:-1]``); quantizes iff ``c`` is a pack."""
+    ``idx`` (scale plane takes ``idx[:-1]``); quantizes iff ``c`` is a pack.
+    Where a traced bool ``gate`` is False the cache keeps what it holds."""
+    def put(plane, rows, at):
+        if gate is not None:
+            rows = jnp.where(gate, rows, jax.lax.dynamic_slice(plane, at, rows.shape))
+        return jax.lax.dynamic_update_slice(plane, rows, at)
+
     if isinstance(c, dict):
         q, s = _kv_quantize(u)
-        return {"q": jax.lax.dynamic_update_slice(c["q"], q, idx),
-                "s": jax.lax.dynamic_update_slice(c["s"], s, idx[:-1])}
-    return jax.lax.dynamic_update_slice(c, u, idx)
+        return {"q": put(c["q"], q, idx), "s": put(c["s"], s, idx[:-1])}
+    return put(c, u, idx)
 
 
 def _kvc_copy(c, seg, idx):
@@ -583,136 +560,118 @@ def _kv_zeros(shape, dt, kv_dtype=None):
     return jnp.zeros(shape, dt)
 
 
-def _cache_block(lp, h, ck, cv, start_pos, *, num_heads, epsilon=1e-5):
-    """One decoder block with a fixed-size KV cache.
+def _layer_norm(x, w, b, epsilon=1e-5):
+    """LayerNorm by mean and variance, as the serving forwards compute it (the
+    train step's is ``layer_norm_fused``, for its backward)."""
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.var(x, axis=-1, keepdims=True)
+    return (x - mean) / jnp.sqrt(var + epsilon) * w + b
 
-    h [b, s, d] (s = prompt len at prefill, 1 at decode); ck/cv
-    [b, H, S, dh] (head-major, so that a head's rows are contiguous; this
-    did not spare the lax programs a relayout: compiled for the v5e, the
-    [b, S, H, dh] layout was re-laid-out whole every step, and so is this
-    one, layer by layer, for the q=1 dot — PERF.md §5; the decode step's way
-    out is the aliased kernel of :func:`_slot_window_forward`) hold
-    keys/values for positions < start_pos and are updated at
-    [start_pos, start_pos+s).
-    Attention masks cache positions beyond start_pos+row. Scores run as
-    bf16×bf16→f32 MXU dots (preferred_element_type) — no f32 cache
-    materialization. Returns (h, ck, cv). Parity: the per-layer decode of
-    fused_multi_transformer_op.cu, as lax ops on a static-shape cache.
-    """
+
+def _serve_block(lp, h, mix, *, num_heads):
+    """One pre-LN decoder block of a serving forward: ``h`` [b, s, d] is a
+    window of ``s`` tokens a batch row. The forwards differ only in where the
+    window's keys and values go and which cached rows it attends, which is
+    ``mix``: ``q, k, v`` [b, H, s, dh] -> ``(att [b, s, d], *cache)``. Returns
+    ``(h, *cache)``. The per-row math does not depend on the mixer, which is
+    what keeps bucketed prefill, chunked prefill, decode and the speculative
+    window bitwise equal on the same rows. Parity: the per-layer decode of
+    fused_multi_transformer_op.cu, as lax ops on a static-shape cache."""
     (n1w, n1b, qkvw, qkvb, ow, ob, n2w, n2b, f1w, f1b, f2w, f2b), _ = lp
-
-    def ln(v, w, bb):
-        mean = jnp.mean(v, axis=-1, keepdims=True)
-        var = jnp.var(v, axis=-1, keepdims=True)
-        return (v - mean) / jnp.sqrt(var + epsilon) * w + bb
-
     b, s, d = h.shape
-    S = (ck["q"] if isinstance(ck, dict) else ck).shape[2]
-    hd = d // num_heads
     with jax.named_scope("norm"):
-        x1 = ln(h, n1w, n1b)
+        x1 = _layer_norm(h, n1w, n1b)
     with jax.named_scope("attn_qkv"):
-        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
+        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, d // num_heads)
         q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, s, dh]
         k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
         v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-    with jax.named_scope("cache_write"):
-        ck = _kvc_update(ck, k, (0, 0, start_pos, 0))
-        cv = _kvc_update(cv, v, (0, 0, start_pos, 0))
-    with jax.named_scope("cache_read"):
-        rk = _kvc_read(ck, h.dtype)
-        rv = _kvc_read(cv, h.dtype)
+    att, *cache = mix(q, k, v)
+    with jax.named_scope("attn_out"):
+        h = h + att @ ow + ob
+    with jax.named_scope("norm"):
+        x2 = _layer_norm(h, n2w, n2b)
+    with jax.named_scope("mlp"):
+        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
+        h = h + y @ f2w + f2b
+    return (h, *cache)
+
+
+def _merge_heads(att):
+    """``att`` [b, H, s, dh] -> [b, s, H * dh]."""
+    b, H, s, hd = att.shape
+    return jnp.swapaxes(att, 1, 2).reshape(b, s, H * hd)
+
+
+def _attend_rows(q, rk, rv, first_pos):
+    """Causal attention of a window ``q`` [b, H, s, dh] whose row j stands at
+    absolute position ``first_pos + j`` (one scalar for the batch) over cached
+    rows ``rk``/``rv`` [b, H, S, dh]: row j sees positions up to its own.
+    Scores run as bf16 x bf16 -> f32 MXU dots (``preferred_element_type``), so
+    no f32 copy of the cache is made; masked lanes contribute exact zeros.
+    Returns [b, s, H * dh] in q's dtype."""
+    s, hd, S = q.shape[2], q.shape[3], rk.shape[2]
     with jax.named_scope("attn_core"):
         scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
         scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
                             preferred_element_type=jnp.float32)
-        q_pos = start_pos + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
+        q_pos = first_pos + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, S), 1)
         scores = jnp.where((k_pos <= q_pos)[None, None], scores, -jnp.inf)
         p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
         att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
-        att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
-    with jax.named_scope("attn_out"):
-        h = h + att @ ow + ob
-    with jax.named_scope("norm"):
-        x2 = ln(h, n2w, n2b)
-    with jax.named_scope("mlp"):
-        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-        h = h + y @ f2w + f2b
-    return h, ck, cv
+        return _merge_heads(att.astype(q.dtype))
 
 
-def _cache_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v, start_pos, *, num_heads, mesh=None):
-    """Trunk forward over a fixed cache; returns (logits, cache_k, cache_v).
+# The cache accessors: what a serving forward's mixer does with one layer of
+# the cache. Each writes the window's K/V before it attends, so a stale row
+# (a speculative window's rejected tail, an earlier request's) is overwritten
+# before it can become visible. They stay apart because they lower to
+# different update-slices.
 
-    cache_k/v: [L, b, H, S, dh]. ids [b, s]; positions start at start_pos.
-    With ``mesh``, caches/activations carry mp (heads / vocab) sharding
-    constraints so decode runs tensor-parallel (reference: the mp-sharded
-    fused_multi_transformer decode path).
-    """
-    params, idx = stacked
-    num_layers = params[0].shape[0]
-    b, s = ids.shape
+def _batch_write_attend(q, k, v, ck, cv, start_pos):
+    """Every batch row's window at the one ``start_pos`` (``generate``, the
+    bucketed prefill): ``ck``/``cv`` [b, H, S, dh] or int8 packs."""
+    with jax.named_scope("cache_write"):
+        ck = _kvc_update(ck, k, (0, 0, start_pos, 0))
+        cv = _kvc_update(cv, v, (0, 0, start_pos, 0))
+    with jax.named_scope("cache_read"):
+        rk = _kvc_read(ck, q.dtype)
+        rv = _kvc_read(cv, q.dtype)
+    return _attend_rows(q, rk, rv, start_pos), ck, cv
 
-    def mpc(x, *spec):
-        if mesh is None or mesh.shape.get("mp", 1) <= 1:
-            return x
-        from jax.sharding import NamedSharding
 
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
-
-    with jax.named_scope("embed"):
-        pos = start_pos + jnp.arange(s, dtype=jnp.int32)
-        h = jnp.take(wte, ids, axis=0) + jnp.take(wpe, pos, axis=0)[None]
-        h = h.astype(wte.dtype)
-    new_k, new_v = [], []
-    for i in range(num_layers):
-        lp = _layer_params(params, idx, i)
-        h, ck, cv = _cache_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
-                                 start_pos, num_heads=num_heads)
-        # int8 packs skip the mp constraint (the serving engine never meshes)
-        new_k.append(ck if isinstance(ck, dict) else mpc(ck, None, "mp"))  # noqa: PTA104 (static unroll, host loop bound)
-        new_v.append(cv if isinstance(cv, dict) else mpc(cv, None, "mp"))  # noqa: PTA104 (static unroll, host loop bound)
-    with jax.named_scope("norm"):
-        mean = jnp.mean(h, axis=-1, keepdims=True)
-        var = jnp.var(h, axis=-1, keepdims=True)
-        h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
-    with jax.named_scope("head_loss"):
-        logits = mpc(jnp.einsum("bsd,vd->bsv", h, wte), None, None, "mp")
-    return logits, _kv_stack(new_k), _kv_stack(new_v)
+def _chunk_write_attend(q, k, v, ck, cv, slot, start):
+    """One slot's chunk (``q`` [1, H, C, dh]) at ``(slot, start)`` of the
+    engine's cache ``ck``/``cv`` [B, H, S, dh]: the chunk attends the slot's
+    whole row, so everything earlier chunks or a prefix-cache insert wrote."""
+    _, H, _, hd = q.shape
+    S = (ck["q"] if isinstance(ck, dict) else ck).shape[2]
+    with jax.named_scope("cache_write"):
+        ck = _kvc_update(ck, k, (slot, 0, start, 0))
+        cv = _kvc_update(cv, v, (slot, 0, start, 0))
+    with jax.named_scope("cache_read"):
+        rk = _kvc_read(_kvc_slice(ck, (slot, 0, 0, 0), (1, H, S, hd)), q.dtype)
+        rv = _kvc_read(_kvc_slice(cv, (slot, 0, 0, 0), (1, H, S, hd)), q.dtype)
+    return _attend_rows(q, rk, rv, start), ck, cv
 
 
 def _slot_write_attend(q, k, v, ck, cv, pos, active, layer=None):
-    """The lax write-and-attend of one layer's cache: the ``decode_attention``
-    registry entry's fallback. ``q``/``k``/``v`` [b, H, W, dh]; ``ck``/``cv``
-    [b, H, S, dh] (or int8 packs) are ONE layer, cut from the stack by the
-    caller (so ``layer``, which the kernel needs to find it in the stack, is
-    not looked at). The window's K/V are written at ``pos[b]`` via a vmapped
-    ``dynamic_update_slice``, gated per slot by ``active`` (None: every
-    slot), then row j attends keys up to ``pos[b] + j``. Returns
-    (att [b, H, W, dh] in q's dtype, ck, cv)."""
+    """Per-slot windows (continuous-batching decode, the speculative window):
+    the ``decode_attention`` registry entry's lax fallback. ``q``/``k``/``v``
+    [b, H, W, dh]; ``ck``/``cv`` [b, H, S, dh] (or int8 packs) are ONE layer,
+    cut from the stack by the caller (``layer`` is the kernel's way to find it
+    in the stack, and not looked at). The window's K/V are written at ``pos[b]``,
+    gated per slot by ``active`` (None: every slot): an inactive slot's cache
+    stays bitwise untouched, so a decode dispatch interleaved with that slot's
+    chunked prefill cannot clobber its fresh rows at a stale ``pos``. Row j then
+    attends keys up to ``pos[b] + j``: slots at different depths share one
+    program. Returns (att [b, H, W, dh] in q's dtype, ck, cv)."""
     b, _, s, hd = q.shape
     S = (ck["q"] if isinstance(ck, dict) else ck).shape[2]
-    if active is None:
-        with jax.named_scope("cache_write"):
-            ck = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(ck, k, pos)
-            cv = jax.vmap(lambda c, u, p: _kvc_update(c, u, (0, p, 0)))(cv, v, pos)
-    else:
-        def upd(c, u, p, a):
-            if isinstance(c, dict):
-                uq, us = _kv_quantize(u)
-                cq = jax.lax.dynamic_slice(c["q"], (0, p, 0), uq.shape)
-                cs = jax.lax.dynamic_slice(c["s"], (0, p), us.shape)
-                return {"q": jax.lax.dynamic_update_slice(
-                            c["q"], jnp.where(a, uq, cq), (0, p, 0)),
-                        "s": jax.lax.dynamic_update_slice(
-                            c["s"], jnp.where(a, us, cs), (0, p))}
-            cur = jax.lax.dynamic_slice(c, (0, p, 0), u.shape)
-            return jax.lax.dynamic_update_slice(c, jnp.where(a, u, cur), (0, p, 0))
-
-        with jax.named_scope("cache_write"):
-            ck = jax.vmap(upd)(ck, k, pos, active)
-            cv = jax.vmap(upd)(cv, v, pos, active)
+    write = jax.vmap(lambda c, u, p, a: _kvc_update(c, u, (0, p, 0), a))
+    with jax.named_scope("cache_write"):
+        ck, cv = write(ck, k, pos, active), write(cv, v, pos, active)
     with jax.named_scope("cache_read"):
         rk = _kvc_read(ck, q.dtype)
         rv = _kvc_read(cv, q.dtype)
@@ -746,213 +705,138 @@ _registry.register(
     doc="lax write-and-attend on one layer cut from the stack (any cache, any device)")
 
 
-def _slot_cache_block(lp, h, ck, cv, pos, *, num_heads, attend, layer=None, epsilon=1e-5, active=None):
-    """One decoder block over PER-SLOT cache positions (continuous-batching
-    decode). ``h`` [b, W, d] holds a W-token window per batch slot (W=1 for
-    plain decode, W=K+1 for the speculative verification forward); ``pos``
-    [b] int32 is each slot's write index for window row 0. The window's K/V
-    are written at ``pos[b]`` (write BEFORE attend, so a stale cache entry
-    — including a speculative window's rejected tail — is always
-    overwritten before it can become visible) and row j attends keys up to
-    ``pos[b] + j`` — slots at different sequence depths share one compiled
-    program. ``active`` [b] bool gates the write per slot: an inactive
-    slot's cache stays bitwise untouched, so decode dispatches interleaved
-    with another slot's chunked prefill cannot clobber its freshly written
-    K/V at a stale ``pos``. Same per-row math as :func:`_cache_block` at s=1
-    (the bitwise basis of both the chunked-prefill and the greedy
-    speculative-decoding pins).
+def _embed(wte, wpe, ids, positions):
+    """Token plus learned position embedding: ``ids`` [b, s]; ``positions``
+    [s] (the same for every batch row) or [b, s]."""
+    with jax.named_scope("embed"):
+        te, pe = jnp.take(wte, ids, axis=0), jnp.take(wpe, positions, axis=0)
+        return (te + pe).astype(wte.dtype)
 
-    ``attend`` is the ``decode_attention`` registry entry's choice
-    (:func:`_decode_attention_impl`). For the lax one
-    (:func:`_slot_write_attend`) ``ck``/``cv`` are one layer [b, H, S, dh]
-    (or int8 packs); for the aliased kernel they are the whole stack
-    [L, b, H, S, dh] and ``layer`` is the block's index in it: the kernel
-    writes and reads the cache where it is stored.
+
+def _serve_layers(stacked, h, cache_k, cache_v, mix, *, num_heads, in_place=False):
+    """The layer walk of every serving forward. ``mix(q, k, v, ck, cv, layer)``
+    -> ``(att [b, s, d], ck, cv)`` is a cache accessor. A lax accessor is given
+    layer i cut from the stacked [L, ...] caches and ``layer=None``; the layers'
+    updated caches come back as two lists for the caller to :func:`_kv_stack`
+    (before or after its head: the order is part of a program's text).
+    ``in_place`` (the aliased kernel) threads the stacked caches through the
+    layers whole with ``layer=i``: the kernel writes and reads layer i where it
+    is stored. Statically unrolled, like :func:`_stack_forward`."""
+    params, idx = stacked
+    ks, vs = [], []
+    for i in range(params[0].shape[0]):
+        lp = _layer_params(params, idx, i)
+        if in_place:
+            h, cache_k, cache_v = _serve_block(
+                lp, h, functools.partial(mix, ck=cache_k, cv=cache_v, layer=i), num_heads=num_heads)
+        else:
+            h, ck, cv = _serve_block(
+                lp, h, functools.partial(mix, ck=_kv_layer(cache_k, i), cv=_kv_layer(cache_v, i), layer=None),
+                num_heads=num_heads)
+            ks.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
+            vs.append(cv)  # noqa: PTA104 (static unroll, host loop bound)
+    return (h, cache_k, cache_v) if in_place else (h, ks, vs)
+
+
+def _head(h, fnw, fnb, wte):
+    """Final LayerNorm and the tied head: ``h`` [b, s, d] -> logits [b, s, V]."""
+    with jax.named_scope("norm"):
+        h = _layer_norm(h, fnw, fnb)
+    with jax.named_scope("head_loss"):
+        return jnp.einsum("bsd,vd->bsv", h, wte)
+
+
+def _cache_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v, start_pos, *, num_heads, mesh=None):
+    """Trunk forward over a fixed cache; returns (logits, cache_k, cache_v).
+
+    cache_k/v: [L, b, H, S, dh] hold keys/values for positions < start_pos and
+    are updated at [start_pos, start_pos + s). ids [b, s] (s = prompt length at
+    prefill, 1 at decode). With ``mesh``, caches/activations carry mp (heads /
+    vocab) sharding constraints so decode runs tensor-parallel (reference: the
+    mp-sharded fused_multi_transformer decode path).
     """
-    (n1w, n1b, qkvw, qkvb, ow, ob, n2w, n2b, f1w, f1b, f2w, f2b), _ = lp
+    def mpc(x, *spec):
+        # int8 packs skip the mp constraint (the serving engine never meshes)
+        if mesh is None or mesh.shape.get("mp", 1) <= 1 or isinstance(x, dict):
+            return x
+        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
 
-    def ln(v, w, bb):
-        mean = jnp.mean(v, axis=-1, keepdims=True)
-        var = jnp.var(v, axis=-1, keepdims=True)
-        return (v - mean) / jnp.sqrt(var + epsilon) * w + bb
+    def mix(q, k, v, ck, cv, layer):
+        return _batch_write_attend(q, k, v, ck, cv, start_pos)
 
-    b, s, d = h.shape
-    hd = d // num_heads
-    with jax.named_scope("norm"):
-        x1 = ln(h, n1w, n1b)
-    with jax.named_scope("attn_qkv"):
-        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, hd)
-        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, W, dh]
-        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-    att, ck, cv = attend(q, k, v, ck, cv, pos, active, layer)
-    att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(b, s, d)
-    with jax.named_scope("attn_out"):
-        h = h + att @ ow + ob
-    with jax.named_scope("norm"):
-        x2 = ln(h, n2w, n2b)
-    with jax.named_scope("mlp"):
-        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-        h = h + y @ f2w + f2b
-    return h, ck, cv
+    with jax.named_scope("embed"):
+        pos = start_pos + jnp.arange(ids.shape[1], dtype=jnp.int32)
+    h = _embed(wte, wpe, ids, pos)
+    h, ks, vs = _serve_layers(stacked, h, cache_k, cache_v, mix, num_heads=num_heads)
+    ks, vs = [mpc(c, None, "mp") for c in ks], [mpc(c, None, "mp") for c in vs]
+    logits = mpc(_head(h, fnw, fnb, wte), None, None, "mp")
+    return logits, _kv_stack(ks), _kv_stack(vs)
 
 
 def _slot_window_forward(stacked, wte, wpe, fnw, fnb, toks, cache_k, cache_v, pos, *, num_heads, active=None):
     """W-token trunk forward with per-slot start positions: row j of
     ``toks`` [b, W] runs at absolute position ``pos[b] + j`` against the
-    engine's big cache — the speculative-decoding verification program (the
-    target model scores the whole drafted window in ONE forward). Returns
-    (logits [b, W, V], cache_k, cache_v); per-row math identical to the
-    W=1 decode step, so greedy accepted tokens stay bitwise equal to
-    sequential decode."""
-    params, idx = stacked
-    num_layers = params[0].shape[0]
-    b, W = toks.shape
+    engine's cache ``[L, b, H, S, dh]`` (or int8 packs): the decode step at
+    W=1, the speculative verification of a drafted window in ONE forward at
+    W=K+1. ``active`` [b] bool gates the cache write per slot. Returns (logits
+    [b, W, V], cache_k, cache_v); per-row math is the W=1 step's, so greedy
+    accepted tokens stay bitwise equal to sequential decode."""
+    W = toks.shape[1]
     rows = pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None]
     # a speculative window near the sequence limit can index past the
     # positional table; clamp (those rows are never emitted — an unclamped
     # jnp.take fills NaN, which the window's own KV writes would spread to
     # later rows). No-op at W=1, where pos < max_seq_len always holds.
     rows = jnp.minimum(rows, jnp.int32(wpe.shape[0] - 1))
-    with jax.named_scope("embed"):
-        h = jnp.take(wte, toks, axis=0) + jnp.take(wpe, rows, axis=0)
-        h = h.astype(wte.dtype)
     impl = _decode_attention_impl(cache_k, W)
+
+    def mix(q, k, v, ck, cv, layer):
+        att, ck, cv = impl.fn(q, k, v, ck, cv, pos, active, layer)
+        return _merge_heads(att), ck, cv
+
+    h = _embed(wte, wpe, toks, rows)
+    h, cache_k, cache_v = _serve_layers(stacked, h, cache_k, cache_v, mix, num_heads=num_heads,
+                                        in_place=not impl.fallback)
     if impl.fallback:
-        new_k, new_v = [], []
-        for i in range(num_layers):
-            lp = _layer_params(params, idx, i)
-            h, ck, cv = _slot_cache_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
-                                          pos, num_heads=num_heads, active=active, attend=impl.fn)
-            new_k.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
-            new_v.append(cv)  # noqa: PTA104 (static unroll, host loop bound)
-        cache_k, cache_v = _kv_stack(new_k), _kv_stack(new_v)
-    else:
-        # the stacked caches thread through the layers whole: the kernel
-        # writes and reads layer i where it is stored
-        for i in range(num_layers):
-            lp = _layer_params(params, idx, i)
-            h, cache_k, cache_v = _slot_cache_block(lp, h, cache_k, cache_v, pos, num_heads=num_heads,
-                                                    active=active, layer=i, attend=impl.fn)
-    with jax.named_scope("norm"):
-        mean = jnp.mean(h, axis=-1, keepdims=True)
-        var = jnp.var(h, axis=-1, keepdims=True)
-        h = (h - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
-    with jax.named_scope("head_loss"):
-        logits = jnp.einsum("bsd,vd->bsv", h, wte)
-    return logits, cache_k, cache_v
+        cache_k, cache_v = _kv_stack(cache_k), _kv_stack(cache_v)
+    return _head(h, fnw, fnb, wte), cache_k, cache_v
 
 
 def _slot_decode_forward(stacked, wte, wpe, fnw, fnb, tok, cache_k, cache_v, pos, *, num_heads, active=None):
-    """One-token trunk forward with per-slot positions: the decode-step
-    program of the serving engine. ``tok`` [b] int32 (last token per slot),
-    ``cache_k``/``cache_v`` [L, b, H, S, dh] (or int8 packs), ``pos`` [b]
-    int32, ``active`` [b] bool (optional) gates cache writes per slot.
-    Returns (logits [b, V], cache_k, cache_v) — exactly one compiled
-    program serves every step of every request regardless of each slot's
-    depth. The W=1 case of :func:`_slot_window_forward` (single shared
-    definition, so the speculative window stays bitwise-aligned with it).
-    """
+    """The serving engine's decode step, the W=1 case of
+    :func:`_slot_window_forward`: ``tok`` [b] int32 (last token per slot),
+    ``pos`` [b] int32. Returns (logits [b, V], cache_k, cache_v): one compiled
+    program serves every step of every request whatever each slot's depth."""
     logits, cache_k, cache_v = _slot_window_forward(
         stacked, wte, wpe, fnw, fnb, tok[:, None], cache_k, cache_v, pos,
         num_heads=num_heads, active=active)
     return logits[:, 0], cache_k, cache_v
 
 
-def _chunk_prefill_block(lp, h, ck, cv, slot, start, *, num_heads, epsilon=1e-5):
-    """One decoder block over a CHUNK of one slot's prompt (chunked prefill).
-
-    ``h`` [1, C, d] holds C consecutive prompt tokens for batch slot
-    ``slot``; ``ck``/``cv`` [B, H, S, dh] are one layer of the engine's big
-    cache. K/V for the chunk are written in place at ``(slot, start)`` and
-    attention reads the slot's WHOLE cache row, masked to each row's own
-    prefix — so the chunk attends to everything earlier chunks (or a
-    prefix-cache insert) already wrote. One compiled program serves every
-    chunk of every prompt at every depth; same per-row math as
-    :func:`_cache_block`, so tokens stay bitwise equal to the bucketed
-    prefill path (masked lanes contribute exact zeros).
-    """
-    (n1w, n1b, qkvw, qkvb, ow, ob, n2w, n2b, f1w, f1b, f2w, f2b), _ = lp
-
-    def ln(v, w, bb):
-        mean = jnp.mean(v, axis=-1, keepdims=True)
-        var = jnp.var(v, axis=-1, keepdims=True)
-        return (v - mean) / jnp.sqrt(var + epsilon) * w + bb
-
-    _, s, d = h.shape
-    raw = ck["q"] if isinstance(ck, dict) else ck
-    H = raw.shape[1]
-    S = raw.shape[2]
-    hd = d // num_heads
-    with jax.named_scope("norm"):
-        x1 = ln(h, n1w, n1b)
-    with jax.named_scope("attn_qkv"):
-        qkv = (x1 @ qkvw + qkvb).reshape(1, s, 3, num_heads, hd)
-        q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [1, H, C, dh]
-        k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
-        v = jnp.swapaxes(qkv[:, :, 2], 1, 2)
-    with jax.named_scope("cache_write"):
-        ck = _kvc_update(ck, k, (slot, 0, start, 0))
-        cv = _kvc_update(cv, v, (slot, 0, start, 0))
-    with jax.named_scope("cache_read"):
-        rk = _kvc_read(_kvc_slice(ck, (slot, 0, 0, 0), (1, H, S, hd)), h.dtype)
-        rv = _kvc_read(_kvc_slice(cv, (slot, 0, 0, 0), (1, H, S, hd)), h.dtype)
-    with jax.named_scope("attn_core"):
-        scale = jnp.asarray(1.0 / (hd ** 0.5), q.dtype)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q * scale, rk,
-                            preferred_element_type=jnp.float32)
-        q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (s, S), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, S), 1)
-        scores = jnp.where((k_pos <= q_pos)[None, None], scores, -jnp.inf)
-        p = jax.nn.softmax(scores, axis=-1).astype(rv.dtype)
-        att = jnp.einsum("bhqk,bhkd->bhqd", p, rv, preferred_element_type=jnp.float32)
-        att = jnp.swapaxes(att.astype(h.dtype), 1, 2).reshape(1, s, d)
-    with jax.named_scope("attn_out"):
-        h = h + att @ ow + ob
-    with jax.named_scope("norm"):
-        x2 = ln(h, n2w, n2b)
-    with jax.named_scope("mlp"):
-        y = jax.nn.gelu(x2 @ f1w + f1b, approximate=True)
-        h = h + y @ f2w + f2b
-    return h, ck, cv
-
-
 def _chunk_prefill_forward(stacked, wte, wpe, fnw, fnb, ids, cache_k, cache_v,
                            slot, start, *, num_heads, last_row=None):
     """Trunk forward over one prompt chunk of one slot, directly against the
-    engine's big [L, B, H, S, dh] cache. ``ids`` [1, C] (C fixed — long
-    prompts run as a sequence of these dispatches, interleaved with decode);
-    ``start`` is the chunk's first absolute position. With ``last_row`` a
-    traced row index, also returns the final-norm logits of that row (the
-    sampling row of the prompt's last chunk); intermediate chunks skip the
-    logits work entirely. Returns (logits|None, cache_k, cache_v).
-    """
-    params, idx = stacked
-    num_layers = params[0].shape[0]
-    s = ids.shape[1]
+    engine's [L, B, H, S, dh] cache. ``ids`` [1, C] (C fixed: a long prompt is
+    a sequence of these dispatches, interleaved with decode, and one program
+    serves every chunk at every depth); ``start`` is the chunk's first absolute
+    position. With ``last_row`` a traced row index, also returns the logits of
+    that row (the sampling row of the prompt's last chunk); intermediate chunks
+    skip the head. Returns (logits|None, cache_k, cache_v)."""
+    def mix(q, k, v, ck, cv, layer):
+        return _chunk_write_attend(q, k, v, ck, cv, slot, start)
+
     with jax.named_scope("embed"):
-        pos = start + jnp.arange(s, dtype=jnp.int32)
-        h = jnp.take(wte, ids, axis=0) + jnp.take(wpe, pos, axis=0)[None]
-        h = h.astype(wte.dtype)
-    new_k, new_v = [], []
-    for i in range(num_layers):
-        lp = _layer_params(params, idx, i)
-        h, ck, cv = _chunk_prefill_block(lp, h, _kv_layer(cache_k, i), _kv_layer(cache_v, i),
-                                         slot, start, num_heads=num_heads)
-        new_k.append(ck)  # noqa: PTA104 (static unroll, host loop bound)
-        new_v.append(cv)  # noqa: PTA104 (static unroll, host loop bound)
-    cache_k = _kv_stack(new_k)
-    cache_v = _kv_stack(new_v)
+        pos = start + jnp.arange(ids.shape[1], dtype=jnp.int32)
+    h = _embed(wte, wpe, ids, pos)
+    h, ks, vs = _serve_layers(stacked, h, cache_k, cache_v, mix, num_heads=num_heads)
+    cache_k, cache_v = _kv_stack(ks), _kv_stack(vs)
     if last_row is None:
         return None, cache_k, cache_v
     with jax.named_scope("norm"):
         hl = jax.lax.dynamic_slice(h, (0, last_row, 0), (1, 1, h.shape[2]))
-        mean = jnp.mean(hl, axis=-1, keepdims=True)
-        var = jnp.var(hl, axis=-1, keepdims=True)
-        hl = (hl - mean) / jnp.sqrt(var + 1e-5) * fnw + fnb
+    logits = _head(hl, fnw, fnb, wte)
     with jax.named_scope("head_loss"):
-        logits = jnp.einsum("bsd,vd->bsv", hl, wte)[:, 0]  # [1, V]
-    return logits, cache_k, cache_v
+        return logits[:, 0], cache_k, cache_v  # [1, V]
 
 
 # the token samplers are the serving engine's as much as ``generate``'s: they
@@ -973,8 +857,6 @@ def _generate_jit(params, ids, key, *, num_heads, num_layers, head_dim, max_new,
     cache_k = jnp.zeros((num_layers, b, num_heads, S, head_dim), dt)
     cache_v = jnp.zeros((num_layers, b, num_heads, S, head_dim), dt)
     if mesh is not None and mesh.shape.get("mp", 1) > 1:
-        from jax.sharding import NamedSharding
-
         csh = NamedSharding(mesh, P(None, None, "mp"))
         cache_k = jax.lax.with_sharding_constraint(cache_k, csh)
         cache_v = jax.lax.with_sharding_constraint(cache_v, csh)
@@ -1013,8 +895,9 @@ class GPTDecoder(Decoder):
     (:mod:`paddle_tpu.models.decoder`): the stacked trunk's parameter pack, a
     key and a value cache ``[L, B, H, S, dh]`` over query heads (plain arrays
     or int8 packs; rows need no reset at admission), and the cache forwards
-    above, called exactly as the engine called them before it had an
-    interface — its compiled programs are the same."""
+    above: bucketed prefill (:func:`_cache_forward`), chunked prefill
+    (:func:`_chunk_prefill_forward`), decode and the speculative window
+    (:func:`_slot_window_forward`)."""
 
     has_window = True
 
@@ -1289,7 +1172,7 @@ class GPTForPretraining(nn.Layer):
         Parity: the reference decodes through gen_cache/Cache plumbing
         (python/paddle/nn/layer/transformer.py:284) or the fused decoder
         (fused_multi_transformer_op.cu); here the cache has a static
-        [L, b, s0+max_new, H, dh] shape so the whole loop jits once.
+        [L, b, H, s0+max_new, dh] shape so the whole loop jits once.
         Greedy by default; ``do_sample`` enables temperature / top-k /
         top-p sampling. Returns [b, s0 + max_new_tokens] token ids.
         """
@@ -1314,8 +1197,6 @@ class GPTForPretraining(nn.Layer):
         if _fleet._hcg is not None:
             fm = _fleet.mesh
             if fm is not None and fm.shape.get("mp", 1) > 1 and fm.shape.get("pp", 1) == 1:
-                from jax.sharding import NamedSharding
-
                 mesh = fm
                 stack = self.gpt.layers
                 specs = [getattr(getattr(stack, n), "dist_spec", None) for n in stack._order]

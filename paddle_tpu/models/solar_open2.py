@@ -395,46 +395,59 @@ def _admitting(start):
     return start == 0
 
 
-def _split(cfg: SolarOpen2Config, cache):
-    """The engine's flat tuple of buffers as ``(k, v, [state a linear layer],
-    [conv tail a linear layer])``."""
+def _layers(cfg: SolarOpen2Config, p: dict, cache, ids, gqa, linear, routed=None):
+    """Both forwards' walk from token ids ``[T]`` to hidden rows ``[T, D]``: the
+    embedding, then ``norm -> mixer -> residual -> norm -> experts -> residual``
+    a layer. ``cache`` is the engine's flat tuple of buffers: ``k``, ``v``, a
+    state a linear layer, a conv tail a linear layer. The forward's two mixers,
+    ``gqa(gi, x, ck, cv) -> (y, ck, cv)`` and ``linear(li, x, state, tail) ->
+    (y, state, tail)``, are told which GQA or linear layer this is and handed
+    its buffers whole. Returns ``(h, cache, stats int32[2])`` with the routed
+    experts' load summed over the layers."""
     n = len(cfg.linear_layers)
-    return cache[0], cache[1], list(cache[2:2 + n]), list(cache[2 + n:2 + 2 * n])
-
-
-def _chunk_forward(cfg: SolarOpen2Config, p: dict, cache, ids, slot, start, n_valid, want_rows, routed=None):
-    """``C`` tokens ``ids [C]`` of slot ``slot`` at ``start`` through every
-    layer, against the engine's buffers (``k``, ``v``, a state and a conv tail a
-    linear layer). At ``start
-    == 0`` the slot is being admitted: its state and tail start from zero,
-    whatever an earlier request left there. ``want_rows``: ``None`` (no
-    logits), a traced row index (that row's logits ``[1, V]``) or ``"all"``
-    (``[C, V]``). Returns ``(logits | None, cache)``."""
-    ck, cv, states, tails = _split(cfg, cache)
-    fresh = _admitting(start)
+    ck, cv, states, tails = cache[0], cache[1], list(cache[2:2 + n]), list(cache[2 + n:2 + 2 * n])
     with jax.named_scope("embed"):
         h = jnp.take(p["embed"], ids, axis=0)
     gi = li = 0
+    stats = jnp.zeros((2,), jnp.int32)
     for layer in range(cfg.num_hidden_layers):  # noqa: PTA104 (static unroll, host loop bound)
         with jax.named_scope("norm"):
             x = _rms_norm(h, p["norm1"][layer], cfg.rms_norm_eps)
         if layer in cfg.gqa_layers:
-            y, ck, cv = _gqa_chunk(cfg, _layer(p, "attn_", gi), x, ck, cv, gi, slot, start)
+            y, ck, cv = gqa(gi, x, ck, cv)
             gi += 1
         else:
-            st = jax.lax.dynamic_slice_in_dim(states[li], slot, 1, axis=0)[0].astype(jnp.float32)
-            tl = jax.lax.dynamic_slice_in_dim(tails[li], slot, 1, axis=0)[0]
-            st, tl = jnp.where(fresh, 0.0, st), jnp.where(fresh, jnp.zeros_like(tl), tl)
-            y, st, tl = _linear_chunk(cfg, _layer(p, "lin_", li), x, st, tl, n_valid)
-            states[li] = jax.lax.dynamic_update_slice(states[li], st[None].astype(states[li].dtype), (slot, 0, 0, 0))  # noqa: PTA104 (static unroll, host loop bound)
-            tails[li] = jax.lax.dynamic_update_slice(tails[li], tl[None], (slot, 0, 0))  # noqa: PTA104 (static unroll, host loop bound)
+            y, states[li], tails[li] = linear(li, x, states[li], tails[li])  # noqa: PTA104 (static unroll, host loop bound)
             li += 1
         h = h + y
         with jax.named_scope("norm"):
             x = _rms_norm(h, p["norm2"][layer], cfg.rms_norm_eps)
-        y, _ = _moe(cfg, p, layer, x, routed)
+        y, s = _moe(cfg, p, layer, x, routed)
+        stats = stats + s
         h = h + y
-    cache = (ck, cv, *states, *tails)
+    return h, (ck, cv, *states, *tails), stats
+
+
+def _chunk_forward(cfg: SolarOpen2Config, p: dict, cache, ids, slot, start, n_valid, want_rows, routed=None):
+    """``C`` tokens ``ids [C]`` of slot ``slot`` at ``start`` through every
+    layer. At ``start == 0`` the slot is being admitted: its state and tail
+    start from zero, whatever an earlier request left there. ``want_rows``:
+    ``None`` (no logits), a traced row index (that row's logits ``[1, V]``) or
+    ``"all"`` (``[C, V]``). Returns ``(logits | None, cache)``."""
+    fresh = _admitting(start)
+
+    def gqa(gi, x, ck, cv):
+        return _gqa_chunk(cfg, _layer(p, "attn_", gi), x, ck, cv, gi, slot, start)
+
+    def linear(li, x, states, tails):
+        st = jax.lax.dynamic_slice_in_dim(states, slot, 1, axis=0)[0].astype(jnp.float32)
+        tl = jax.lax.dynamic_slice_in_dim(tails, slot, 1, axis=0)[0]
+        st, tl = jnp.where(fresh, 0.0, st), jnp.where(fresh, jnp.zeros_like(tl), tl)
+        y, st, tl = _linear_chunk(cfg, _layer(p, "lin_", li), x, st, tl, n_valid)
+        return (y, jax.lax.dynamic_update_slice(states, st[None].astype(states.dtype), (slot, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(tails, tl[None], (slot, 0, 0)))
+
+    h, cache, _ = _layers(cfg, p, cache, ids, gqa, linear, routed)
     if want_rows is None:
         return None, cache
     if not isinstance(want_rows, str):
@@ -446,28 +459,15 @@ def _decode_forward(cfg: SolarOpen2Config, p: dict, cache, tok, pos, active, rou
     """One token of every slot: ``tok``, ``pos`` ``[B]``; writes gated by
     ``active``. Returns ``(logits [B, V], cache, stats int32[2])`` with the
     routed experts' load summed over the layers."""
-    ck, cv, states, tails = _split(cfg, cache)
-    with jax.named_scope("embed"):
-        h = jnp.take(p["embed"], tok, axis=0)
-    gi = li = 0
-    stats = jnp.zeros((2,), jnp.int32)
-    for layer in range(cfg.num_hidden_layers):  # noqa: PTA104 (static unroll, host loop bound)
-        with jax.named_scope("norm"):
-            x = _rms_norm(h, p["norm1"][layer], cfg.rms_norm_eps)
-        if layer in cfg.gqa_layers:
-            y, ck, cv = _gqa_decode(cfg, _layer(p, "attn_", gi), x, ck, cv, gi, pos, active)
-            gi += 1
-        else:
-            y, st, tails[li] = _linear_decode(cfg, _layer(p, "lin_", li), x, states[li].astype(jnp.float32), tails[li], active)  # noqa: PTA104 (static unroll, host loop bound)
-            states[li] = st.astype(states[li].dtype)  # noqa: PTA104 (static unroll, host loop bound)
-            li += 1
-        h = h + y
-        with jax.named_scope("norm"):
-            x = _rms_norm(h, p["norm2"][layer], cfg.rms_norm_eps)
-        y, s = _moe(cfg, p, layer, x, routed)
-        stats = stats + s
-        h = h + y
-    return _head(cfg, p, h), (ck, cv, *states, *tails), stats
+    def gqa(gi, x, ck, cv):
+        return _gqa_decode(cfg, _layer(p, "attn_", gi), x, ck, cv, gi, pos, active)
+
+    def linear(li, x, state, tail):
+        y, st, tail = _linear_decode(cfg, _layer(p, "lin_", li), x, state.astype(jnp.float32), tail, active)
+        return y, st.astype(state.dtype), tail
+
+    h, cache, stats = _layers(cfg, p, cache, tok, gqa, linear, routed)
+    return _head(cfg, p, h), cache, stats
 
 
 def chunk_routing(cfg: SolarOpen2Config, p: dict, cache, ids, slot, start, n_valid):

@@ -935,8 +935,8 @@ def build_watch_snapshot(root: str, window_s: float = _WATCH_WINDOW_S,
                     key = (f"slo/{a.get('slo')}" if a.get("slo")
                            else f"regress/{a.get('kind')}/{a.get('fingerprint')}")
                     firing.setdefault(key, a)
-    # local SLO state (a monitor installed in THIS process — the bench and
-    # the tests drive watch in-process): per-spec budget + burn table
+    # local SLO state (a monitor installed in THIS process — the tests
+    # drive watch in-process): per-spec budget + burn table
     from . import slo as _slo
 
     mon = _slo.installed()

@@ -16,7 +16,7 @@ instead of only writing a post-mortem run log:
   registered provider (the SLO engine's burn-rate alerts, the
   perf-regression sentinel), ``{"alerts": [...], "firing": n, "page": n}``;
 - ``GET /snapshot`` — the full JSON metrics snapshot (counters, gauges,
-  histogram summaries), the same document ``bench.py`` embeds.
+  histogram summaries): ``metrics.snapshot()``.
 
 The server is ``http.server`` + a daemon thread — no dependencies, no
 event loop, bounded cost (scrapes are rare; the handler renders on the

@@ -10,8 +10,7 @@ flattened (CPU builds omit fields) into one dict::
      "temp_bytes", "peak_bytes", "generated_code_bytes"}
 
 ``Executor.explain()`` / ``TrainStep.explain()`` return one such row per
-cached specialization; :func:`format_cost_table` renders them for humans
-(bench.py prints it).
+cached specialization; :func:`format_cost_table` renders them for humans.
 
 The retained handles also join a device trace back to the model:
 :func:`op_scopes` maps each compiled program's HLO instruction names
@@ -119,6 +118,20 @@ def _is_single_device(lowered_text: str) -> bool:
     return found.get("partitions", 1) * found.get("replicas", 1) == 1
 
 
+_PRIVATE_FUNC = re.compile(r"func\.func private @([\w.]+)")
+_SYMBOL = re.compile(r"@([\w.]+)")
+
+
+def _program_key(lowered_text: str) -> str:
+    """The lowered text with its private functions renamed in order of
+    definition. jax numbers them (``@_where_32``) from a counter that two
+    lowerings of one program do not share — the planner's, on abstract
+    arguments, and the dispatch's, on arrays, differ in nothing else — so the
+    raw text names an executable once and never finds it again."""
+    names = {n: f"f{i}" for i, n in enumerate(_PRIVATE_FUNC.findall(lowered_text))}
+    return _SYMBOL.sub(lambda m: "@" + names.get(m.group(1), m.group(1)), lowered_text)
+
+
 def cost_summary(compiled) -> Dict[str, Any]:
     """Normalized cost/memory analysis of one XLA ``Compiled`` executable.
     Every field degrades to None when the backend does not report it, so
@@ -158,8 +171,8 @@ def aot_compile(jitfn, args: Tuple,
     With ``cache_scope`` (and ``FLAGS_compile_cache_dir`` set), the
     executable round-trips through the on-disk AOT store
     (``inference.aot_cache``) under ``<dir>/<cache_scope>/``, keyed on the
-    *lowered program text* — identical trace, identical executable, no
-    fingerprint guessing. A hit skips the XLA compile entirely
+    *lowered program text* (:func:`_program_key`) — identical trace, identical
+    executable, no fingerprint guessing. A hit skips the XLA compile entirely
     (``info["from_disk_cache"] = True``); a fresh compile is serialized
     back (``info["aot_cache_stored"] = True``) so the next process restart
     — or an elastic resume onto a mesh the planner already evaluated —
@@ -185,7 +198,7 @@ def aot_compile(jitfn, args: Tuple,
     persistent_cache_on = bool(jax.config.jax_compilation_cache_dir)
     if persistent_cache_on or cache_scope is not None:
         try:
-            text = lowered.as_text()
+            text = _program_key(lowered.as_text())
             single_device = _is_single_device(text)
         except Exception:
             text = None

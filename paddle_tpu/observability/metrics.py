@@ -11,8 +11,8 @@ single always-on metrics store for the runtime. Design constraints:
 - **Histograms are bounded.** A histogram is a fixed vector of bucket
   counts plus (count, sum, min, max); observing never allocates, so a
   billion-step run holds the same few hundred bytes per series.
-- **Two exports.** ``snapshot()`` returns plain JSON-able dicts (bench.py,
-  tests); ``prometheus_text()`` renders the Prometheus text exposition
+- **Two exports.** ``snapshot()`` returns plain JSON-able dicts (the
+  exporter's ``/snapshot``, tests); ``prometheus_text()`` renders the Prometheus text exposition
   format (counters, gauges, and histograms with ``_bucket``/``_sum``/
   ``_count`` series) for scraping.
 
@@ -248,7 +248,7 @@ INGRESS_COUNTERS: Tuple[str, ...] = (
 # Kernel-registry selection series (paddle_tpu.ops.registry): one
 # ``picked`` (a real kernel won) or ``fallback`` (the XLA composite served)
 # increment per distinct call signature — so ``kernels.<k>.picked`` equals
-# the compile count, the invariant bench.py and the tests pin. The registry
+# the compile count, the invariant tests/test_kernel_registry.py pins. The registry
 # also declares these at define_kernel time; listing the built-in kernels
 # here keeps idle-process scrapes complete.
 KERNEL_COUNTERS: Tuple[str, ...] = (
@@ -305,7 +305,7 @@ PLANNER_COUNTERS: Tuple[str, ...] = (
 # the eager-mode unique-row count (traced steps report through
 # embedding_exchange run-log events); rows_checkpointed counts table rows
 # published by EmbeddingCheckpointRotation. recsys.steps/examples are the
-# training-driver counters bench_recsys and the DLRM example bump.
+# training-driver counters the DLRM example (examples/train_dlrm.py) bumps.
 RECSYS_COUNTERS: Tuple[str, ...] = (
     "recsys.steps", "recsys.examples",
     "embedding.lookups", "embedding.ids_exchanged", "embedding.a2a_bytes",
@@ -374,7 +374,7 @@ KNOWN_HISTOGRAMS: Tuple[str, ...] = (
     "ingress.request_seconds", "ingress.ttft_seconds",
     "hapi.step",
     # judgment layer (PR 19): cost of one SLOMonitor.evaluate pass — the
-    # series behind the bench's slo_eval_overhead_pct budget
+    # series that says what the monitor itself costs
     "slo.eval_seconds",
 )
 

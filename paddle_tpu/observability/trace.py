@@ -32,8 +32,7 @@ reads every peer's, and records the offset against rank 0 as a
 timeline by that offset so cross-host skew doesn't scramble the lanes.
 
 Gated by ``FLAGS_trace`` AND ``FLAGS_monitor``: when either is off no ids
-are allocated and no span events are emitted (the bench's tracing-off arm
-measures exactly this).
+are allocated and no span events are emitted.
 """
 from __future__ import annotations
 

@@ -16,8 +16,8 @@ key also folds in the backend, the kernel's watched flag values, and
 invalidation hook. Because the predicate walk runs once per distinct
 signature, the ``kernels.<name>.{picked,fallback}`` counters (metrics
 registry, PR 4) count exactly one selection per compiled specialization —
-the invariant the bench and tests pin (``kernels.moe.picked`` == compile
-count). Each selection also emits a ``kernel_select`` run-log event that
+the invariant tests/test_kernel_registry.py pins (``kernels.moe.picked`` ==
+compile count). Each selection also emits a ``kernel_select`` run-log event that
 ``observability report`` renders as the kernel-selection section.
 
 ``FLAGS_kernel_overrides`` (e.g. ``"moe=dense,sdpa=xla"``) forces a named

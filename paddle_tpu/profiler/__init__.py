@@ -109,7 +109,7 @@ _session_active = False  # set by Profiler.start/stop: gates _HOST_EVENTS
 #   executor.compiles / executor.donated_runs — Executor.run bookkeeping
 #   train_step.dispatches / train_step.steps — TrainStep __call__/run_steps
 # ``run_steps(k)`` adds 1 dispatch and k steps: dispatches-per-step is the
-# amortization ratio bench.py reports.
+# amortization ratio (tests/test_multistep.py pins the counts).
 
 
 def counter_inc(name: str, n: int = 1) -> None:

@@ -227,16 +227,64 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
     assert set(touched) <= _CACHE_MAY_PASS_THROUGH, touched
 
 
-# What the engine's three GPT programs lower to at the two serving cells' shapes, as PR 29's engine (which imported
-# ``models/gpt.py``'s private forwards by name) lowered them: sha256 of the StableHLO text, and its lines. Not in the
-# hash (taken under this suite's conftest: its matmul-precision pin is in the text): the names of the results (``jax.result_info``: the cache is one argument of two leaves now) and the Mosaic
-# kernel's serialized body, which carries the kernel file's line numbers. A PR that means to change a GPT serving
-# program changes these with it.
+# What each cell's programs lower to for the described chip: sha256 of the StableHLO text, and its lines. The GPT
+# serving cells' first three are as PR 29's engine (which imported ``models/gpt.py``'s private forwards by name)
+# lowered them; ``prefill_core``, Solar's three, the train step and the four-chip step were taken at 094436e, before
+# PR 32 folded the cache forwards of ``models/gpt.py`` onto one block body and one layer walk. Not in the hash (taken
+# under this suite's conftest: its matmul-precision pin is in the text): the names of the results
+# (``jax.result_info``) and the Mosaic kernels' serialized bodies, which carry the kernel files' line numbers.
+# ``_PARENT_SCOPES``: the operations under each scope (``_scope_counts``), taken at the same commit. A PR that means to
+# change a program changes its entries with it; a refactor that moves one reordered an operation or moved a scope.
 _PARENT_PROGRAMS = {
-    "cerebras-gpt-1.3b.serve-longgen": {"decode_fn": ("bc2c4b6c6f63602d", 3274), "chunk_core": ("a62eab5d420e6b87", 4747),
-                                        "chunk_final_core": ("bda3c0fbf1929d7c", 4988)},
-    "gpt2-medium.serve-chat": {"decode_fn": ("f76958b28793439a", 3418), "chunk_core": ("72c1ac6ef53f24dc", 4747),
-                               "chunk_final_core": ("83884956b5b900e0", 4988)},
+    "cerebras-gpt-1.3b.serve-longgen": {
+        "decode_fn": ("bc2c4b6c6f63602d", 3274),
+        "chunk_core": ("a62eab5d420e6b87", 4747),
+        "chunk_final_core": ("bda3c0fbf1929d7c", 4988),
+        "prefill_core": ("d0d2618f83915cc7", 4575),
+    },
+    "gpt2-medium.serve-chat": {
+        "decode_fn": ("f76958b28793439a", 3418),
+        "chunk_core": ("72c1ac6ef53f24dc", 4747),
+        "chunk_final_core": ("83884956b5b900e0", 4988),
+        "prefill_core": ("8bdd3a355668f227", 4575),
+    },
+    "solar-open2-250b.serve-reasoning": {
+        "decode_fn": ("fc41d4693a7dfe37", 2028),
+        "chunk_core": ("b34ae03bffc9098e", 2374),
+        "chunk_final_core": ("6151ba245647615e", 2777),
+    },
+    "gpt2-medium.train": {
+        "_step": ("af707415b346d11f", 1303),
+    },
+    "cerebras-gpt-1.3b.train-zero2mp2": {
+        "_step": ("819e0ecbdaf33112", 1319),
+    },
+}
+
+_PARENT_SCOPES = {
+    "cerebras-gpt-1.3b.serve-longgen": {
+        "decode_fn": {"unscoped": 621, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
+        "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 429, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
+        "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
+        "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
+    },
+    "gpt2-medium.serve-chat": {
+        "decode_fn": {"unscoped": 765, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
+        "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 429, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
+        "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
+        "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
+    },
+    "solar-open2-250b.serve-reasoning": {
+        "decode_fn": {"unscoped": 608, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 612, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
+        "chunk_core": {"unscoped": 896, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 426, "moe_shared": 36, "linear_proj": 92, "linear_core": 553, "linear_out": 38},
+        "chunk_final_core": {"unscoped": 1011, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 568, "moe_shared": 48, "linear_proj": 102, "linear_core": 597, "linear_out": 57, "head_loss": 2},
+    },
+    "gpt2-medium.train": {
+        "_step": {"unscoped": 370, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 37, "head_loss": 47, "optimizer": 439},
+    },
+    "cerebras-gpt-1.3b.train-zero2mp2": {
+        "_step": {"unscoped": 413, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 4, "head_loss": 49, "optimizer": 439},
+    },
 }
 
 
@@ -248,13 +296,36 @@ def _fingerprint(lowered_text):
     return hashlib.sha256(text.encode()).hexdigest()[:16], text.count("\n")
 
 
-@pytest.mark.parametrize("cell", sorted(_DECODE))
-def test_gpt_serving_programs_are_the_parents_through_the_decoder_interface(as_tpu, one_chip, cell):
-    """``DecodeEngine`` takes GPT's forwards from ``model.decoder()`` and no longer by name from ``models/gpt.py``.
-    Its decode, chunk and final-chunk programs, lowered for the described chip at the two cells' shapes (chunk 128,
-    bf16, abstract arguments), are the programs the parent's engine lowered: same fingerprint, same line count.
-    The engine is built from a one-layer model of the cell's width and its decoder told the cell's depth: the
-    programs take every shape from their arguments and only the layer indices from the decoder."""
+# Every ``jax.named_scope`` a by-part metric reads (PERF.md §3; ``benchmark/families/*.py:PART_OF_SCOPE``).
+_SCOPES = {"norm", "attn_qkv", "attn_core", "attn_out", "mlp", "embed", "head_loss", "cache_write", "cache_read",
+           "optimizer", "amp_cast", "linear_proj", "linear_core", "linear_out", "moe_router", "moe_routed", "moe_shared"}
+_LOC_NAME = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
+_LOC_USE = re.compile(r" loc\((#loc\d*)\)$", re.M)
+
+
+def _scope_counts(lowered):
+    """``{scope: ops}`` of a lowered program: each operation of the StableHLO text under the first scope of
+    ``_SCOPES`` in its name path (``jit(decode_fn)/norm/reduce_sum`` -> norm; file and line are not looked at),
+    ``unscoped`` where the path has none. The fingerprint does not see these names and the by-part metrics do."""
+    text = lowered.as_text(debug_info=True)
+    path_of = dict(_LOC_NAME.findall(text))
+    counts = {}
+    for ref in _LOC_USE.findall(text):
+        scope = next((w for w in re.findall(r"[A-Za-z_]\w*", path_of.get(ref, "")) if w in _SCOPES), "unscoped")
+        counts[scope] = counts.get(scope, 0) + 1
+    return counts
+
+
+_LOWERED = {}      # cell -> {program: lowered}: each cell's programs are lowered once for this file's tests
+
+
+def _gpt_serving_lowered(cell, one_chip):
+    """The engine's four GPT programs lowered for the described chip at a serving cell's shapes (chunk 128, a
+    one-shot prefill bucket of 512, bf16, abstract arguments). The engine is built from a one-layer model of the
+    cell's width and its decoder told the cell's depth: the programs take every shape from their arguments and only
+    the layer indices from the decoder."""
+    if cell in _LOWERED:
+        return _LOWERED[cell]
     from paddle_tpu.inference import DecodeEngine
 
     L, B, H, S, D = (_DECODE[cell][k] for k in "LBHSD")
@@ -277,20 +348,29 @@ def test_gpt_serving_programs_are_the_parents_through_the_decoder_interface(as_t
         "decode_fn": (engine._decode_jit, (p, cache) + state + (slots(jnp.int32),) * 3),
         "chunk_core": (engine._chunk_jit, (p, cache, ids, scalar, scalar)),
         "chunk_final_core": (engine._chunk_final_jit, (p, cache) + state + (ids,) + (scalar,) * 7),
+        "prefill_core": (engine._prefill_jit, (p, cache) + state + (one_chip((1, 512), jnp.int32),) + (scalar,) * 5),
     }
-    got = {name: _fingerprint(fn.lower(*args).as_text()) for name, (fn, args) in programs.items()}
+    _LOWERED[cell] = {name: fn.lower(*args) for name, (fn, args) in programs.items()}
+    return _LOWERED[cell]
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE))
+def test_gpt_serving_programs_are_the_parents_through_the_decoder_interface(as_tpu, one_chip, cell):
+    """``DecodeEngine`` takes GPT's forwards from ``model.decoder()`` and no longer by name from ``models/gpt.py``.
+    Its decode, chunk, final-chunk and one-shot prefill programs, lowered for the described chip at the two cells'
+    shapes, are the programs the parent's engine lowered: same fingerprint, same line count."""
+    got = {name: _fingerprint(lowered.as_text()) for name, lowered in _gpt_serving_lowered(cell, one_chip).items()}
     assert got == _PARENT_PROGRAMS[cell]
 
 
-@pytest.mark.parametrize("program", ["decode_fn", "chunk_core"])
-def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu, one_chip, program):
-    """The decode program of ``solar-open2-250b.serve-reasoning`` (128 slots x 16,384, one chip's share at the published
-    widths, bf16, abstract arguments) compiles for the described chip: the GQA layer through ``decode_attn`` by group,
-    two grouped matmuls an expert layer through ``ops/grouped_matmul.py`` (one selection for the program; its row tile
-    32 for 1,024 pairs over a router of 320), every slot buffer updated in place, and next to no temporaries — which
-    holds only while a layer's experts are an array of their own (a slice of a stack is copied out for the grouped
-    matmul: 4.2 GB) and the recurrent state is one buffer a layer. The chunk program at 1,024 tokens the same way: its
-    8,192 pairs in tiles of 128 rows."""
+_SOLAR = "solar-open2-250b.serve-reasoning"
+
+
+def _solar_lowered(one_chip):
+    """``(cfg, decoder, {program: lowered})`` of the Solar cell: 128 slots x 16,384, chunk 1,024, one chip's share at
+    the published widths, bf16, abstract arguments. The engine holds the programs only: nothing is allocated."""
+    if _SOLAR in _LOWERED:
+        return _LOWERED[_SOLAR]
     import json
 
     from paddle_tpu.inference import DecodeEngine
@@ -305,20 +385,43 @@ def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu,
     for k in PER_LAYER_WEIGHTS:
         weights[k] = tuple(one_chip(weights[k].shape[1:], bf) for _ in range(weights[k].shape[0]))
     decoder = SolarOpen2ForCausalLM(cfg, weights=weights).decoder()
-    engine = DecodeEngine.__new__(DecodeEngine)       # the programs only: nothing is allocated, nothing runs
+    engine = DecodeEngine.__new__(DecodeEngine)
     engine._dec, engine._ddec, engine._sample, engine.spec_k, engine._donate, engine._chunk = decoder, None, (False, 1.0, 0, 1.0), 0, True, C
     engine._build()
     cache = tuple(one_chip(spec.shape, spec.dtype) for spec in decoder.buffer_specs(B, S))
     slots = lambda dt: one_chip((B,), dt)  # noqa: E731
-    scalar = one_chip((), jnp.int32)
-    metrics.reset_counters("kernels.")
+    scalar, ids = one_chip((), jnp.int32), one_chip((1, C), jnp.int32)
+    state = (slots(jnp.int32), slots(jnp.int32), slots(jnp.bool_))
+    programs = {
+        "decode_fn": (engine._decode_jit, (weights, cache) + state + (slots(jnp.int32),) * 3),
+        "chunk_core": (engine._chunk_jit, (weights, cache, ids, scalar, scalar)),
+        "chunk_final_core": (engine._chunk_final_jit, (weights, cache) + state + (ids,) + (scalar,) * 7),
+    }
+    lowered = {}     # program -> (lowered, the kernels its lowering picked)
+    for name, (fn, args) in programs.items():
+        metrics.reset_counters("kernels.")
+        lowered[name] = (fn.lower(*args), metrics.counters("kernels."))
+    _LOWERED[_SOLAR] = cfg, decoder, lowered
+    return _LOWERED[_SOLAR]
+
+
+@pytest.mark.parametrize("program", ["decode_fn", "chunk_core"])
+def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu, one_chip, program):
+    """The decode program of ``solar-open2-250b.serve-reasoning`` (128 slots x 16,384, one chip's share at the published
+    widths, bf16, abstract arguments) compiles for the described chip: the GQA layer through ``decode_attn`` by group,
+    two grouped matmuls an expert layer through ``ops/grouped_matmul.py`` (one selection for the program; its row tile
+    32 for 1,024 pairs over a router of 320), every slot buffer updated in place, and next to no temporaries — which
+    holds only while a layer's experts are an array of their own (a slice of a stack is copied out for the grouped
+    matmul: 4.2 GB) and the recurrent state is one buffer a layer. The chunk program at 1,024 tokens the same way: its
+    8,192 pairs in tiles of 128 rows."""
+    cfg, decoder, lowered = _solar_lowered(one_chip)
+    B, S = 128, 16384
+    lowered, picked = lowered[program]
+    compiled = lowered.compile()
     if program == "decode_fn":
-        compiled = engine._decode_jit.lower(weights, cache, slots(jnp.int32), slots(jnp.int32), slots(jnp.bool_),
-                                            slots(jnp.int32), slots(jnp.int32), slots(jnp.int32)).compile()
-        assert metrics.counters("kernels.decode_attention.")["kernels.decode_attention.picked"] == 1
-    else:
-        compiled = engine._chunk_jit.lower(weights, cache, one_chip((1, C), jnp.int32), scalar, scalar).compile()
-    assert metrics.counters("kernels.grouped_matmul.") == {"kernels.grouped_matmul.picked": 1, "kernels.grouped_matmul.fallback": 0}
+        assert picked["kernels.decode_attention.picked"] == 1
+    assert {k: v for k, v in picked.items() if "grouped_matmul" in k} == {
+        "kernels.grouped_matmul.picked": 1, "kernels.grouped_matmul.fallback": 0}
     calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
     # an intermediate chunk returns the buffers only, so the last layer's experts, which feed none, are not compiled
     tm, layers = (32, cfg.num_hidden_layers) if program == "decode_fn" else (128, cfg.num_hidden_layers - 1)
@@ -352,26 +455,26 @@ def _train_step(model, make_step):
     return make_step(model, opt, GPTPretrainingCriterion())
 
 
-def test_train_step_picks_and_compiles_the_flash_kernels_for_v5e(as_tpu, one_chip):
-    """``TrainStep``'s whole AMP-O2 step at the flagship width (depth cut to
-    one layer: the block is compiled once whatever the depth) with the
-    Pallas attention kernels in it, forward and backward."""
+def _train_lowered(one_chip):
+    """``TrainStep``'s whole AMP-O2 step at ``gpt2-medium.train``'s shape (b8 s1024; depth cut to one layer: the
+    block is compiled once whatever the depth), lowered for the described chip."""
     from paddle_tpu.jit import TrainStep
 
     step = _train_step(_wide_model(1), lambda m, o, c: TrainStep(m, o, c, amp_level="O2"))
     ids = one_chip((8, 1024), jnp.int32)
-    assert _compile(step._jit, _abstract(step.state, one_chip), ((ids,), (ids,))) >= 3
+    return step._jit.lower(_abstract(step.state, one_chip), ((ids,), (ids,)))
 
 
-@pytest.mark.parametrize("layout", [dict(dp=2, mp=2, sdp=1, stage=0),
-                                    dict(dp=1, mp=2, sdp=2, stage=2)],
-                         ids=["dp2xmp2", "sharding2xmp2"])
-def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, topo, layout):
-    """``fleet.distributed_step`` at the flagship width on the described
-    four-chip mesh. A Mosaic kernel cannot be partitioned automatically —
-    the compiler's own words are "wrap the call in a shard_map" — so the
-    attention kernels must run per shard (batch over dp x sdp, heads over
-    mp) inside the GSPMD-partitioned step."""
+def test_train_step_picks_and_compiles_the_flash_kernels_for_v5e(as_tpu, one_chip):
+    """The step compiles with the Pallas attention kernels in it, forward and backward."""
+    assert _train_lowered(one_chip).compile().as_text().count("tpu_custom_call") >= 3
+
+
+_LAYOUTS = {"dp2xmp2": dict(dp=2, mp=2, sdp=1, stage=0), "sharding2xmp2": dict(dp=1, mp=2, sdp=2, stage=2)}
+
+
+def _distributed_lowered(topo, layout):
+    """``fleet.distributed_step`` at the flagship width, lowered for the described four-chip mesh."""
     from paddle_tpu.distributed import fleet
     from paddle_tpu.distributed.sharding import state_shardings
     from paddle_tpu.distributed.strategy import DistributedStrategy
@@ -402,6 +505,38 @@ def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, topo, layout):
         ids = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=batch)
         jitted = jax.jit(step._step, donate_argnums=0, in_shardings=(shardings, batch),
                          out_shardings=(shardings, None))
-        assert _compile(jitted, state, ((ids,), (ids,))) >= 3
+        return jitted.lower(state, ((ids,), (ids,)))
     finally:
         fleet._hcg = None
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, topo, layout):
+    """A Mosaic kernel cannot be partitioned automatically — the compiler's own words are "wrap the call in a
+    shard_map" — so the attention kernels must run per shard (batch over dp x sdp, heads over mp) inside the
+    GSPMD-partitioned step."""
+    assert _distributed_lowered(topo, _LAYOUTS[layout]).compile().as_text().count("tpu_custom_call") >= 3
+
+
+_TRAIN, _TRAIN4 = "gpt2-medium.train", "cerebras-gpt-1.3b.train-zero2mp2"
+_PROGRAMS = ([(cell, name) for cell in sorted(_DECODE) for name in ("decode_fn", "chunk_core", "chunk_final_core", "prefill_core")]
+             + [(_SOLAR, name) for name in ("decode_fn", "chunk_core", "chunk_final_core")]
+             + [(_TRAIN, "_step"), (_TRAIN4, "_step")])
+
+
+@pytest.mark.parametrize("cell,program", _PROGRAMS, ids=[f"{c}-{n}" for c, n in _PROGRAMS])
+def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_chip, cell, program):
+    """Each cell's program lowers to the text it lowered to at 094436e, and the operations under each
+    ``jax.named_scope`` the by-part metrics read are as many as they were: the hash does not see a scope's name, the
+    metrics see nothing else. The four-chip cell's step is ``test_distributed_step``'s ``sharding2xmp2`` layout, the
+    one-chip train cell's ``test_train_step``'s."""
+    if cell in _DECODE:
+        lowered = _gpt_serving_lowered(cell, one_chip)[program]
+    elif cell == _SOLAR:
+        lowered = _solar_lowered(one_chip)[2][program][0]
+    elif cell == _TRAIN:
+        lowered = _train_lowered(one_chip)
+    else:
+        lowered = _distributed_lowered(topo, _LAYOUTS["sharding2xmp2"])
+    assert _fingerprint(lowered.as_text()) == _PARENT_PROGRAMS[cell][program]
+    assert _scope_counts(lowered) == _PARENT_SCOPES[cell][program]

@@ -159,7 +159,7 @@ def test_jit_save_preserves_int_input_dtype():
 
 
 def test_train_step_amp_o2_converges():
-    """bf16-compute/f32-master AMP step trains (the bench.py flagship path)."""
+    """bf16-compute/f32-master AMP step trains (the training cells' path)."""
     paddle.seed(0)
     net = nn.Sequential(nn.Linear(8, 32), nn.GELU(), nn.Linear(32, 4))
     step = TrainStep(net, paddle.optimizer.Adam(learning_rate=1e-2),

@@ -81,8 +81,9 @@ class TestSigkillExactlyOnce:
     def test_sigkill_mid_decode_bitwise_exactly_once_streaming(
             self, model, run_log_dir):
         """The acceptance pin, against a real kill -9: a 2-replica
-        subprocess fleet with FLAGS_chaos_replica_sigkill_at armed loses
-        replica 1 to SIGKILL mid-decode; every request — including the
+        subprocess fleet with FLAGS_chaos_replica_sigkill_at armed (once both
+        children have reported their first tick) loses replica 1 to SIGKILL
+        mid-decode; every request — including the
         stream=True client — finishes exactly once, bitwise-equal to the
         unkilled in-process reference; the streamed chunk sequence has no
         gaps/dups/reordering across the requeue; children boot warm at
@@ -92,9 +93,24 @@ class TestSigkillExactlyOnce:
         prompts = _prompts(5)
         want = _reference_tokens(model, prompts)  # also warms the AOT cache
         flightrec.reset()
-        with chaos.inject(FLAGS_chaos_replica_sigkill_at="1:1"):
-            with ProcServingFleet(GPTConfig.tiny(), replicas=2,
-                                  heartbeat_timeout=60.0, **KW) as fleet:
+        with ProcServingFleet(GPTConfig.tiny(), replicas=2,
+                              heartbeat_timeout=60.0, **KW) as fleet:
+            # A replica loads its programs in its first tick, and the parent
+            # learns a child's counters from its heartbeat, every 50 ms: a
+            # kill at replica 1's first tick outruns the beat that reports
+            # the load. So each replica first serves one request, the parent
+            # waits (bounded) for both beats, and the kill is armed for the
+            # next tick it harvests from replica 1: mid-decode of what
+            # follows.
+            for p in prompts[:2]:
+                fleet.submit(p[::-1].copy(), max_new_tokens=2)
+            fleet.run(timeout_s=120)
+            deadline = time.monotonic() + 30.0
+            while (min(c["aot_cache_hits"] for c in fleet.child_counters().values()) < 1
+                   and time.monotonic() < deadline):
+                fleet.step()
+                time.sleep(0.02)
+            with chaos.inject(FLAGS_chaos_replica_sigkill_at=f"1:{fleet.replicas[1].ticks + 1}"):
                 stream = fleet.submit(prompts[0], max_new_tokens=6, seed=0,
                                       stream=True)
                 fids = [stream.fid]
