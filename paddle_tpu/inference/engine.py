@@ -17,7 +17,10 @@ request stream:
   ``lax.scan`` dispatch (the ``TrainStep.run_steps`` idiom via
   ``jit.scan_steps``), with the eos/max-token stop flags carried in the
   scan state so finished slots self-deactivate without a host round-trip —
-  one dispatch and one host sync per D tokens;
+  one dispatch and one host sync per D tokens. At depth 1 a caller that
+  asks for it (``decode_step(ahead=True)``, the scheduler) **runs one step
+  ahead**: each call launches the next step before it pulls the last one's
+  tokens, so the device always has a program queued while the host works;
 - **prefix reuse** — ``prefix_cache_mb=M`` keeps an LRU cache of
   chunk-aligned prompt-prefix KV segments (:mod:`.prefix_cache`); a request
   whose prefix matches copies the cached chunks into its slot with one
@@ -99,10 +102,11 @@ class _PrefillJob:
     """Host-side progress of one in-flight prompt admission: which slot it
     owns, how far the cache is written (``next_pos``), how many tokens the
     prefix cache supplied, and — once the final chunk ran — the sampled
-    first token."""
+    first token (``pending``: the final program ran and its first token is
+    still on the device, for the next :meth:`DecodeEngine.prefill_step`)."""
 
     __slots__ = ("slot", "prompt", "n", "eos", "limit", "seed",
-                 "next_pos", "reused_tokens", "done", "first", "more")
+                 "next_pos", "reused_tokens", "done", "first", "more", "pending")
 
     def __init__(self, slot, prompt, n, eos, limit, seed):
         self.slot = slot
@@ -116,14 +120,29 @@ class _PrefillJob:
         self.done = False
         self.first: Optional[int] = None
         self.more: Optional[bool] = None
+        self.pending = None        # (first, more) on the device, not yet pulled
 
     def chunks_left(self, chunk: Optional[int]) -> int:
         """Model dispatches still needed to finish this prefill."""
-        if self.done:
+        if self.done or self.pending is not None:
             return 0
         if chunk is None:
             return 1
         return max(1, -(-(self.n - self.next_pos) // chunk))
+
+
+class _DecodeInFlight:
+    """One launched decode step whose report the host has not pulled yet:
+    the program's int32 ``report`` (tokens, which slots emitted them, which
+    stay active, the decoder's counters), the slots the host has freed or
+    admitted into since the launch (``touched``: the step's word on them is
+    another request's)."""
+
+    __slots__ = ("report", "touched")
+
+    def __init__(self, report, touched):
+        self.report = report
+        self.touched = touched
 
 
 class DecodeEngine:
@@ -294,12 +313,18 @@ class DecodeEngine:
             self._pos = jnp.zeros((B,), jnp.int32)
             self._tok = jnp.zeros((B,), jnp.int32)
             self._active = jnp.zeros((B,), bool)
-        # host mirrors / per-slot request metadata (tiny, resent per dispatch)
+        # host mirrors / per-slot request metadata. eos, limit and seed also
+        # live on the device (``_slot_consts``) and are copied there where
+        # they change — admission, reset — not with every dispatch
         self._active_np = np.zeros((B,), bool)
         self._occupied = np.zeros((B,), bool)
         self._eos = np.full((B,), -1, np.int32)
         self._limit = np.zeros((B,), np.int32)
         self._seed = np.zeros((B,), np.int32)
+        with self._device_scope():
+            self._put_slot_consts()
+        # run-ahead: the decode step launched and not yet pulled
+        self._inflight: Optional[_DecodeInFlight] = None
         self._spec_drafted = 0
         self._spec_accepted = 0
         self.last_stats = None    # the decoder's counters of the last decode dispatch (``n_stats`` int32)
@@ -568,16 +593,13 @@ class DecodeEngine:
 
         self._decode_body = decode_body
 
-        if n_stats:
-            def decode_fn(p, cache, pos, tok, active, eos_v, limit_v, seed_v):
-                carry, ys = decode_body((p, eos_v, limit_v, seed_v), (cache, pos, tok, active), None)
-                # the step's tokens and the decoder's counters leave in one array: one pull
-                return carry + (jnp.concatenate([carry[2], ys[2].astype(jnp.int32)]),)
-        else:
-            def decode_fn(p, cache, pos, tok, active, eos_v, limit_v, seed_v):
-                carry, _ys = decode_body((p, eos_v, limit_v, seed_v),
-                                         (cache, pos, tok, active), None)
-                return carry
+        def decode_fn(p, cache, pos, tok, active, eos_v, limit_v, seed_v):
+            carry, ys = decode_body((p, eos_v, limit_v, seed_v), (cache, pos, tok, active), None)
+            # what the host needs of the step leaves in one int32 array that no later launch donates (``tok`` and
+            # ``active`` are the next step's): the tokens, which slots emitted them, which stay active, and the
+            # decoder's counters where it counts — one pull, a step later for a caller that runs ahead
+            report = (carry[2], ys[1], carry[3]) + tuple(ys[2:])
+            return carry + (jnp.concatenate([r.astype(jnp.int32) for r in report]),)
 
         if has_draft:
             # state args shift by one (draft params at arg 1) and both caches
@@ -739,6 +761,7 @@ class DecodeEngine:
         self._eos[slot] = eos
         self._limit[slot] = limit
         self._seed[slot] = int(seed)
+        self._put_slot_consts()
         if self.prefix_cache is not None:
             # reuse at most n-1 tokens: the prompt's last token must run
             # through the model (its logits pick the first generated token)
@@ -771,12 +794,22 @@ class DecodeEngine:
         bucketed mode, or one C-token chunk in chunked mode. Returns True
         when the prompt is fully prefilled (``job.first``/``job.more`` are
         then set and the slot starts decoding on the next decode dispatch).
-        """
+
+        The program that samples the first token also activates the slot,
+        in-graph, so the next decode dispatch serves it whether or not the
+        host has seen that token. With a decode step in flight (a caller
+        that runs ahead) pulling it here would wait for that step and for
+        every chunk queued behind it, and every running request's tokens
+        with it: the token is left on the device, the call returns False,
+        and the next call — the scheduler's next tick, before its decode
+        step — pulls it and returns True."""
         from ..observability import span as _span
         from ..profiler import counter_inc
 
         if job.done:
             return True
+        if job.pending is not None:
+            return self._take_first_token(job, *job.pending)
         n, slot = job.n, job.slot
         spec = self._dparams is not None
         if self._chunk is None:
@@ -829,15 +862,36 @@ class DecodeEngine:
             self._pos, self._tok, self._active, first, more = out  # noqa: PTA104 (host-side serving state)
             job.next_pos = n
             counter_inc("infer.prefill_chunk_dispatches")
+        self._touch(slot)
+        if self._inflight is not None:
+            # until ``more`` is pulled the host takes the slot for active, as the device may
+            self._active_np[slot] = True
+            job.pending = (first, more)
+            counter_inc("infer.prefill_first_deferred")
+            return False
+        return self._take_first_token(job, first, more)
+
+    def _take_first_token(self, job: _PrefillJob, first, more) -> bool:
+        """Pull the first token and whether the request goes on (the wait
+        for the program that sampled them), and close the job."""
+        from ..profiler import counter_inc
+
         job.first = int(first)
         job.more = bool(more)
-        job.done = True
-        self._active_np[slot] = job.more
+        job.done, job.pending = True, None
+        self._active_np[job.slot] = job.more
         counter_inc("infer.prefill_dispatches")
         counter_inc("infer.tokens")
         if self.prefix_cache is not None:
             self._store_prefix_chunks(job)
         return True
+
+    def _put_slot_consts(self) -> None:
+        """Copy the per-slot eos, limit and seed to the device. They change
+        at admission (a free slot's, which no step in flight reads a live
+        value of) and at reset; every decode dispatch takes the copies."""
+        # jnp.array, not asarray: the host arrays are written in place later
+        self._slot_consts = (jnp.array(self._eos), jnp.array(self._limit), jnp.array(self._seed))
 
     def _program_state(self):
         """What every prefill program takes first: the weights and the slot
@@ -887,14 +941,36 @@ class DecodeEngine:
 
     # ------------------------------------------------------------- decode
     @_placed
-    def decode_step(self, fuse: Optional[int] = None):
+    def decode_step(self, fuse: Optional[int] = None, ahead: bool = False):
         """Advance every active slot in ONE dispatch. At fuse depth 1
         returns ``(tokens[B], emitted[B], active[B])``; at depth D > 1 the
         dispatch runs D decode iterations inside one donated ``lax.scan``
         and returns ``(tokens[D, B], emitted[D, B], active[B])`` — the
         eos/limit stop flags ride the scan carry, so a slot that finishes at
         iteration j self-deactivates in-graph (``emitted[j+1:, slot]`` is
-        False) with no host round-trip until the stack is drained."""
+        False) with no host round-trip until the stack is drained.
+
+        **When a token reaches the host.** By default the call is
+        synchronous: it returns the tokens of the step it launched. With
+        ``ahead=True`` (the scheduler's form) it *runs one step ahead*: it
+        launches step *k* and then pulls step *k - 1*, the one the previous
+        such call launched, so a token reaches the host one call after the
+        call that launched its step, and the device has step *k* queued
+        while the host pulls, drains and admits. Same shapes and meaning of
+        the result; ``emitted`` is all False when nothing was in flight (the
+        first call after an idle spell). Everything step *k* needs is on the
+        device — eos and limit are applied in-graph, so a slot that finished
+        in step *k - 1* sits step *k* out without the host — and no token is
+        computed that the synchronous order would not compute. A slot freed
+        or admitted into between a step's launch and its pull belongs to
+        another request by then: its ``emitted`` reads False and the token is
+        dropped, so a late token never lands in a slot's next request. A step
+        left in flight when no slot is active any more has nothing to
+        deliver and is forgotten unpulled; :meth:`reset` drops one too. The
+        engine declines, and the synchronous step runs, where one dispatch
+        already returns a stack: with a draft model and at depth > 1. A
+        synchronous call while a step is in flight would lose that step's
+        tokens, and raises."""
         from ..observability import span as _span
         from ..profiler import counter_inc
 
@@ -906,11 +982,17 @@ class DecodeEngine:
         if spec and depth != 1:
             raise ValueError("speculative decode runs at fuse depth 1 (one "
                              "dispatch already emits up to spec_k+1 tokens)")
+        ahead = bool(ahead) and depth == 1 and not spec
+        if self._inflight is not None and not ahead:
+            raise RuntimeError("a decode step launched ahead is still in flight: pull it with "
+                               "decode_step(ahead=True) or drop it with reset() before a synchronous step")
+        stats = None
         # infer.decode_step runs from entry to the tokens on the host. Its
-        # children: infer.decode_launch (the three host->device copies of
-        # eos/limit/seed and the dispatch) and infer.decode_sync (the pulls of
-        # tokens / emitted / active, which wait for the device) — the end of
-        # infer.decode_sync is when this tick's tokens reached the host.
+        # children: infer.decode_launch (the dispatch; eos/limit/seed are on
+        # the device already) and infer.decode_sync (the pull, which waits
+        # for the device) — of this call's step, or running ahead of the
+        # step before it: the end of infer.decode_sync is when the tokens
+        # this call returns reached the host.
         with _span("infer.decode_step") as step_span:
             if spec:
                 from ..observability.metrics import gauge_set
@@ -919,8 +1001,7 @@ class DecodeEngine:
                     out = self._dispatch(
                         "spec_decode", self._spec_jit,
                         (self._params, self._dparams, self._cache, self._dcache,
-                         self._pos, self._tok, self._active,
-                         jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)),
+                         self._pos, self._tok, self._active) + self._slot_consts,
                         label=f"spec_decode/K{self.spec_k}")
                 (self._cache, self._dcache,  # noqa: PTA104 (host-side serving state)
                  self._pos, self._tok, self._active, toks, emitted) = out  # noqa: PTA104 (host-side serving state)
@@ -938,25 +1019,19 @@ class DecodeEngine:
                     gauge_set("serving.spec_acceptance_rate",
                               self._spec_accepted / self._spec_drafted)
             elif depth == 1:
-                emitted = self._active_np.copy()
                 with _span("infer.decode_launch"):
-                    out = self._dispatch(
-                        "decode", self._decode_jit,
-                        (self._params, self._cache, self._pos, self._tok, self._active,
-                         jnp.asarray(self._eos), jnp.asarray(self._limit), jnp.asarray(self._seed)))
-                self._cache, self._pos, self._tok, self._active = out[:4]  # noqa: PTA104 (host-side serving state)
+                    step = self._launch_decode()
+                if ahead:
+                    # the launch half was this call's step, the collect half is the last call's
+                    if self._inflight is not None:
+                        counter_inc("infer.decode_ahead")
+                    step, self._inflight = self._inflight, step  # noqa: PTA104 (host-side serving state)
                 with _span("infer.decode_sync"):
-                    if n_stats:
-                        # the tokens and the decoder's counters: one array, one pull
-                        pulled = np.asarray(out[4])
-                        toks, stats = pulled[:-n_stats], pulled[-n_stats:]
-                    else:
-                        toks = np.asarray(self._tok)
-                    self._active_np = np.array(self._active)  # writable host mirror  # noqa: PTA104 (host-side serving state)
+                    toks, emitted, stats = self._collect_decode(step)
+                self._forget_idle_flight()
             else:
                 with _span("infer.decode_launch"):
-                    consts = (self._params, jnp.asarray(self._eos), jnp.asarray(self._limit),
-                              jnp.asarray(self._seed))
+                    consts = (self._params,) + self._slot_consts
                     carry = (self._cache, self._pos, self._tok, self._active)
                     out = self._dispatch(f"decode_x{depth}", self._fused(depth), (consts, carry))
                 (self._cache, self._pos, self._tok, self._active), ys = out  # noqa: PTA104 (host-side serving state)
@@ -968,26 +1043,63 @@ class DecodeEngine:
                     self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
             counter_inc("infer.decode_dispatches")
             counter_inc("infer.tokens", int(emitted.sum()))
-            if n_stats:
+            if stats is not None:
                 self.last_stats = stats  # noqa: PTA104 (host-side serving state)
                 for name, value in zip(self._dec.stat_counters, stats):
                     counter_inc(name, int(value))
-                # per tick, on the step's own span record: what a reader of the traced ticks takes
+                # on the span record of the call that pulled them: what a reader of the traced ticks takes
                 step_span.note(**{n.rsplit(".", 1)[-1]: int(v) for n, v in zip(self._dec.stat_counters, stats)})
         return toks, emitted, self._active_np.copy()
 
+    def _launch_decode(self) -> _DecodeInFlight:
+        """The launch half of a depth-1 step: dispatch the decode program on
+        the carry and keep its report for whoever pulls it."""
+        out = self._dispatch("decode", self._decode_jit,
+                             (self._params, self._cache, self._pos, self._tok, self._active) + self._slot_consts)
+        self._cache, self._pos, self._tok, self._active = out[:4]  # noqa: PTA104 (host-side serving state)
+        return _DecodeInFlight(out[4], np.zeros((self.max_batch_slots,), bool))
+
+    def _collect_decode(self, step: Optional[_DecodeInFlight]):
+        """The collect half: pull a launched step's report (the one wait for
+        the device) and take its word on the slots the host has not touched
+        since its launch. ``(tokens, emitted, the decoder's counters or
+        None)``; of no step, nothing emitted."""
+        B = self.max_batch_slots
+        if step is None:
+            return np.zeros((B,), np.int32), np.zeros((B,), bool), None
+        report = np.asarray(step.report)
+        toks, emitted, active = report[:B], report[B:2 * B] != 0, report[2 * B:3 * B] != 0
+        self._active_np = np.where(step.touched, self._active_np, active)  # writable host mirror  # noqa: PTA104 (host-side serving state)
+        return toks, emitted & ~step.touched, (report[3 * B:] if len(report) > 3 * B else None)
+
+    def _touch(self, slot: int) -> None:
+        """The host freed ``slot`` or admitted a request into it: what a
+        step in flight reports of it is no longer its owner's."""
+        if self._inflight is not None:
+            self._inflight.touched[slot] = True
+
+    def _forget_idle_flight(self) -> None:
+        """A step in flight with no slot still active on the host was
+        launched on free slots, or every request it served has been freed
+        since: it has no token to deliver, so it is forgotten unpulled."""
+        if self._inflight is not None and not self._active_np.any():
+            self._inflight = None  # noqa: PTA104 (host-side serving state)
+
     def free_slot(self, slot: int) -> None:
-        """Release a slot for the next admission (cancels it if still live)."""
+        """Release a slot for the next admission (cancels it if still live;
+        a token a step in flight computed for it is dropped at the pull)."""
         if self._active_np[slot]:
             self._active = self._active.at[slot].set(False)  # noqa: PTA104 (host-side serving state)
             self._active_np[slot] = False  # noqa: PTA104 (host-side serving state)
         self._occupied[slot] = False
+        self._touch(slot)
+        self._forget_idle_flight()
 
     @_placed
     def reset(self) -> None:
-        """Drop every in-flight request and zero the slot state (the cache
-        keeps its buffers — stale K/V is always overwritten before it can be
-        attended)."""
+        """Drop every in-flight request, a decode step launched ahead with
+        them, and zero the slot state (the cache keeps its buffers — stale
+        K/V is always overwritten before it can be attended)."""
         B = self.max_batch_slots
         self._pos = jnp.zeros((B,), jnp.int32)
         self._tok = jnp.zeros((B,), jnp.int32)
@@ -997,6 +1109,8 @@ class DecodeEngine:
         self._eos[:] = -1
         self._limit[:] = 0
         self._seed[:] = 0
+        self._put_slot_consts()
+        self._inflight = None
 
     # ------------------------------------------------------------- helpers
     def generate(self, ids, max_new_tokens: int = 32, eos_token_id: Optional[int] = None,

@@ -289,14 +289,18 @@ class ContinuousBatchingScheduler:
             r = self.prefilling[slot]
             job = self._jobs[slot]
             decode_waiting = bool(self.running)
+            # the last chunk ran a tick ago and left its first token on the
+            # device (a decode step was in flight): this call only pulls it
+            pull_only = job.pending is not None
             t0 = time.perf_counter()
             done = self.engine.prefill_step(job)
             dt = time.perf_counter() - t0
-            r.prefill_chunks += 1  # noqa: PTA104 (host-side serving loop)
-            if r.trace_id is not None:
-                _trace.span_event("serving.prefill_chunk", trace_id=r.trace_id,
-                                  seconds=dt, id=r.rid, slot=slot,
-                                  chunk=r.prefill_chunks, done=bool(done))
+            if not pull_only:
+                r.prefill_chunks += 1  # noqa: PTA104 (host-side serving loop)
+                if r.trace_id is not None:
+                    _trace.span_event("serving.prefill_chunk", trace_id=r.trace_id,
+                                      seconds=dt, id=r.rid, slot=slot,
+                                      chunk=r.prefill_chunks, done=job.pending is not None or bool(done))
             if decode_waiting:
                 r.stall_seconds += dt  # noqa: PTA104 (host-side serving loop)
             if not done:
@@ -354,6 +358,26 @@ class ContinuousBatchingScheduler:
         at fuse depth D, drained in order). Returns requests finished this
         tick.
 
+        **When a token reaches the host.** The tick asks the engine to run
+        one step ahead (``decode_step(ahead=True)``): it launches this
+        tick's decode step and drains the *previous* tick's tokens, so the
+        device works on the next step while the host drains, logs and
+        admits. A request's first token comes from its prefill, in the tick
+        that finishes it — or, when a decode step was in flight as the
+        last chunk was launched, at the start of the next tick: the engine
+        leaves it on the device rather than wait for everything queued
+        before it (:meth:`DecodeEngine.prefill_step`) — and every later one
+        arrives one tick after the tick that launched its step. A request finishes, and frees its slot, in
+        the tick its last token arrives (the step launched meanwhile already
+        sits that slot out, in-graph), and stays in ``running`` until then,
+        so :meth:`run` and the fleet's loop keep ticking until it is
+        delivered. Tokens are appended by slot: the engine reports a slot as
+        emitted only if nobody freed it or was admitted into it since the
+        step's launch, so a request cancelled or expired while its step was
+        in flight, and a new request admitted to its slot in the same tick,
+        never see the late token. An engine with a draft model or a fuse
+        depth over 1 declines and steps synchronously, as before.
+
         The tick is one ``infer.sched.step`` span with a child per phase:
         ``infer.sched.admit`` (deadline sweep, slot claim, prefix inserts),
         ``infer.sched.prefill``, the engine's ``infer.decode_step`` (launch
@@ -374,7 +398,7 @@ class ContinuousBatchingScheduler:
                 self._prefill_tick()
             decoded = len(self.running)
             if decoded:
-                toks, emitted, active = self.engine.decode_step()
+                toks, emitted, active = self.engine.decode_step(ahead=True)
             with _span("infer.sched.drain", slots=decoded):
                 if decoded:
                     toks = np.atleast_2d(toks)

@@ -191,6 +191,10 @@ _HELP: Dict[str, str] = {}
 SERVING_COUNTERS: Tuple[str, ...] = (
     "infer.compiles", "infer.runs",
     "infer.prefill_dispatches", "infer.decode_dispatches", "infer.tokens",
+    # run-ahead of one decode step (engine.decode_step(ahead=True)): launches made with the step before not yet pulled
+    # (the decode dispatches less the pipe's fills), and admissions whose first token was left on the device for the
+    # next tick because a step was in flight (pulling it at once would have drained the pipe)
+    "infer.decode_ahead", "infer.prefill_first_deferred",
     # the routed experts' load of a decode step, pulled with its tokens: (token, expert) pairs routed to an
     # expert held here, and held experts with at least one pair, both summed over the layers
     "infer.moe.assignments_local", "infer.moe.experts_hit",

@@ -235,21 +235,26 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
 # (``jax.result_info``) and the Mosaic kernels' serialized bodies, which carry the kernel files' line numbers.
 # ``_PARENT_SCOPES``: the operations under each scope (``_scope_counts``), taken at the same commit. A PR that means to
 # change a program changes its entries with it; a refactor that moves one reordered an operation or moved a scope.
+# PR 33 re-pinned the three ``decode_fn``: only the result tuple changed. The step's report to the host — tokens, which
+# slots emitted them, which stay active, and Solar's counters — leaves as one int32 array beside the carry (GPT gains
+# the output: two converts and a concatenate, ``unscoped`` 621 -> 624 and 765 -> 768; Solar's widens by two operands,
+# 608 -> 610), so that a step launched ahead can be pulled after the next launch has donated ``tok`` and ``active``.
+# The body (``decode_body``) is untouched: every named scope's count, and every other program, is as it was.
 _PARENT_PROGRAMS = {
     "cerebras-gpt-1.3b.serve-longgen": {
-        "decode_fn": ("bc2c4b6c6f63602d", 3274),
+        "decode_fn": ("c1b2b9e757190693", 3277),
         "chunk_core": ("a62eab5d420e6b87", 4747),
         "chunk_final_core": ("bda3c0fbf1929d7c", 4988),
         "prefill_core": ("d0d2618f83915cc7", 4575),
     },
     "gpt2-medium.serve-chat": {
-        "decode_fn": ("f76958b28793439a", 3418),
+        "decode_fn": ("464871152afee3f3", 3421),
         "chunk_core": ("72c1ac6ef53f24dc", 4747),
         "chunk_final_core": ("83884956b5b900e0", 4988),
         "prefill_core": ("8bdd3a355668f227", 4575),
     },
     "solar-open2-250b.serve-reasoning": {
-        "decode_fn": ("fc41d4693a7dfe37", 2028),
+        "decode_fn": ("f442acc65555ca7d", 2030),
         "chunk_core": ("b34ae03bffc9098e", 2374),
         "chunk_final_core": ("6151ba245647615e", 2777),
     },
@@ -263,19 +268,19 @@ _PARENT_PROGRAMS = {
 
 _PARENT_SCOPES = {
     "cerebras-gpt-1.3b.serve-longgen": {
-        "decode_fn": {"unscoped": 621, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
+        "decode_fn": {"unscoped": 624, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
         "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 429, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
         "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
         "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
     },
     "gpt2-medium.serve-chat": {
-        "decode_fn": {"unscoped": 765, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
+        "decode_fn": {"unscoped": 768, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
         "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 429, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
         "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
         "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
     },
     "solar-open2-250b.serve-reasoning": {
-        "decode_fn": {"unscoped": 608, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 612, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
+        "decode_fn": {"unscoped": 610, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 612, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
         "chunk_core": {"unscoped": 896, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 426, "moe_shared": 36, "linear_proj": 92, "linear_core": 553, "linear_out": 38},
         "chunk_final_core": {"unscoped": 1011, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 568, "moe_shared": 48, "linear_proj": 102, "linear_core": 597, "linear_out": 57, "head_loss": 2},
     },

@@ -19,6 +19,19 @@ from paddle_tpu.ops import decode_attention as da
 from paddle_tpu.ops import registry
 
 
+@pytest.fixture(autouse=True)
+def _no_mesh_left_over():
+    """The kernel declines under a fleet mesh, and the registry remembers its choice by whether there is one: none
+    may be left over from an earlier test file of this worker (several initialise the fleet and leave it so)."""
+    from paddle_tpu.distributed import fleet
+
+    prev, fleet._hcg = fleet._hcg, None
+    registry.clear_cache("decode_attention")
+    yield
+    fleet._hcg = prev
+    registry.clear_cache("decode_attention")
+
+
 @pytest.fixture
 def interpret():
     prior = da.set_interpret(True)

@@ -425,6 +425,7 @@ class _FakeJob:
         self.reused_tokens = 0
         self.first = 7
         self.more = True
+        self.pending = None
 
 
 class _FakeEngine:
@@ -455,7 +456,7 @@ class _FakeEngine:
     def prefill_step(self, job):
         return True
 
-    def decode_step(self):
+    def decode_step(self, ahead=False):
         toks = np.zeros((1, self.slots), np.int32)
         emitted = np.zeros((1, self.slots), bool)
         active = np.ones(self.slots, bool)

@@ -149,6 +149,38 @@ def test_a_pure_decode_tick_has_no_prefill_child(fleet):
     fleet.run()
 
 
+def test_a_run_ahead_tick_keeps_the_tree_and_pulls_the_step_before(fleet):
+    """PR 33: the tick launches its decode step and pulls the one the tick before launched. Same spans, same nesting:
+    ``infer.decode_launch`` is this tick's dispatch, ``infer.decode_sync`` the pull of the step before."""
+    from paddle_tpu.observability import metrics
+
+    engine = next(iter(fleet.replicas.values())).engine
+    fleet.submit(np.random.default_rng(4).integers(0, 512, (5,)).astype("int32"), max_new_tokens=6, seed=0)
+    fleet.step()                                    # the one-chunk prefill and the first decode step: the pipe fills
+    in_flight = engine._inflight.report
+    before = metrics.counters("infer.decode")
+    t0 = time.perf_counter_ns()
+    fleet.step()
+    parents, by_name = _tree(spans.recent(since_ns=t0))
+    assert {n: p for n, p in parents.items() if n.startswith("infer.")} == {
+        "infer.fleet.step": None,
+        "infer.sched.step": "infer.fleet.step",
+        "infer.sched.admit": "infer.sched.step",
+        "infer.sched.prefill": "infer.sched.step",
+        "infer.decode_step": "infer.sched.step",
+        "infer.decode_launch": "infer.decode_step",
+        "infer.decode_sync": "infer.decode_step",
+        "infer.sched.drain": "infer.sched.step",
+    }
+    assert by_name["infer.decode_launch"].end_ns <= by_name["infer.decode_sync"].start_ns
+    assert engine._inflight is not None and engine._inflight.report is not in_flight   # pulled: the step before's
+    after = metrics.counters("infer.decode")
+    assert after["infer.decode_ahead"] == before["infer.decode_ahead"] + 1
+    assert after["infer.decode_dispatches"] == before["infer.decode_dispatches"] + 1
+    fleet.run()
+    assert engine._inflight is None
+
+
 @pytest.mark.parametrize("name", ["infer.tokens_per_decode_dispatch", "serving.prefill_stall_seconds"])
 def test_unread_histograms_are_gone(fleet, name):
     from paddle_tpu.observability import metrics
