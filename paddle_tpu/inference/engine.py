@@ -33,10 +33,11 @@ parameter pack, the per-slot buffers — rows of keys and values, which
 admission leaves as they are, and for a model with linear-attention layers a
 recurrent state, which the slot's first prefill program zeroes — and the
 prefill, chunk, decode and window forwards over them. GPT
-(``models/gpt.py:GPTDecoder``) and Solar Open 2
-(``models/solar_open2.py:SolarOpen2Decoder``) are its two clients; for a model
-with recurrent state the engine refuses a prefix cache, a draft and an int8
-cache.
+(``models/gpt.py:GPTDecoder``), Solar Open 2
+(``models/solar_open2.py:SolarOpen2Decoder``) and GigaChat 3.5
+(``models/gigachat3_5.py:GigaChat35Decoder``, whose cached rows are latents
+``[B, S, rank + rope]`` with no head axis) are its clients; for a model with
+recurrent state the engine refuses a prefix cache, a draft and an int8 cache.
 
 The slot buffers (and the slot state) are donated, so what the engine
 *holds* stays flat for its life. Whether a program also updates them in
@@ -358,6 +359,7 @@ class DecodeEngine:
         from ..observability.metrics import gauge_set
         gauge_set("infer.kv_bytes_per_slot", self.kv_bytes_per_slot())
         gauge_set("infer.state_bytes_per_slot", self.state_bytes_per_slot())
+        gauge_set("infer.latent_bytes_per_slot", self.latent_bytes_per_slot())
 
     # ------------------------------------------------------------ programs
     @property
@@ -1180,12 +1182,13 @@ class DecodeEngine:
         the int8 payload plus the f32 scale planes, not the compute dtype."""
         return self._buffer_bytes(reset=False)
 
-    def _buffer_bytes(self, reset: bool) -> int:
+    def _buffer_bytes(self, reset: bool, named: str = "") -> int:
         """Stored bytes of the slot buffers that are (``reset``) or are not
-        zeroed at admission: recurrent state, or cache rows."""
+        zeroed at admission: recurrent state, or cache rows; of those, the
+        ones whose spec's name starts with ``named``."""
         total = 0
         for spec, buf in zip(self._specs, self._cache):
-            if spec.reset_at_admission == reset:
+            if spec.reset_at_admission == reset and spec.name.startswith(named):
                 total += sum(l.size * jnp.dtype(l.dtype).itemsize for l in jax.tree_util.tree_leaves(buf))
         return int(total)
 
@@ -1203,6 +1206,13 @@ class DecodeEngine:
         gauge — sizing concurrent-slot capacity from this number stays
         honest under int8 KV)."""
         return self.cache_bytes() // self.max_batch_slots
+
+    def latent_bytes_per_slot(self) -> int:
+        """Of :meth:`kv_bytes_per_slot`, the bytes in latent caches (buffers
+        named ``latent*``: rows of ``[c | k_r]`` with no head axis): the
+        ``infer.latent_bytes_per_slot`` gauge. 0 for a model that caches keys
+        and values."""
+        return self._buffer_bytes(reset=False, named="latent") // self.max_batch_slots
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state a slot holds beside its cache rows (the

@@ -26,3 +26,7 @@ from .solar_open2 import (  # noqa: F401
     SolarOpen2Config,
     SolarOpen2ForCausalLM,
 )
+from .gigachat3_5 import (  # noqa: F401
+    GigaChat35Config,
+    GigaChat35ForCausalLM,
+)

@@ -357,6 +357,8 @@ KNOWN_GAUGES: Tuple[str, ...] = (
     "serving.spec_acceptance_rate", "infer.kv_bytes_per_slot",
     # what a slot holds beside its cache rows: the recurrent state admission zeroes (0 for a key/value-only model)
     "infer.state_bytes_per_slot",
+    # of kv_bytes_per_slot, the part in latent caches ([c | k_r] rows with no head axis; 0 for a key/value cache)
+    "infer.latent_bytes_per_slot",
     "fleet.replicas_alive", "fleet.replicas_dead", "fleet.queue_depth",
     "stability.lr", "amp.loss_scale",
     # judgment layer (PR 19): age of the stalest alive replica heartbeat

@@ -1,8 +1,9 @@
 """paddle_tpu.ops — the Pallas kernel tier and its registry.
 
 Public surface: the flash-attention kernel families (classic pair +
-flat-lane/packed), the serving engine's aliased decode attention, the grouped
-matmul of the dropless experts, the fused layer norm, the fused sort-based MoE
+flat-lane/packed), the serving engine's aliased decode attention, latent (MLA)
+attention over a latent cache, rotary positions, the grouped matmul of the
+dropless experts, the fused layer norm, the fused sort-based MoE
 dispatch/combine, and the kernel registry every ``nn`` layer dispatches
 through (``registry.dispatch(<kernel>, ...)`` with per-signature selection
 caching and an XLA-composite fallback).
@@ -12,7 +13,8 @@ Note: the ``flash_attention`` *function* is reached as
 the submodule name existing imports rely on.
 """
 from . import (  # noqa: F401
-    decode_attention, flash_attention, flash_attention_flat, grouped_matmul, layer_norm, moe_pallas, registry,
+    decode_attention, flash_attention, flash_attention_flat, grouped_matmul, layer_norm, mla_attention, moe_pallas,
+    registry, rope,
 )
 from .flash_attention import flash_attention_available, flash_attention_qkv  # noqa: F401
 from .flash_attention_flat import flash_flat, flash_flat_gqa, flash_packed  # noqa: F401
@@ -28,8 +30,8 @@ from .registry import (  # noqa: F401
 )
 
 __all__ = [
-    "decode_attention", "flash_attention", "flash_attention_flat", "grouped_matmul", "layer_norm", "moe_pallas",
-    "registry",
+    "decode_attention", "flash_attention", "flash_attention_flat", "grouped_matmul", "layer_norm", "mla_attention",
+    "moe_pallas", "registry", "rope",
     "flash_attention_available", "flash_attention_qkv",
     "flash_flat", "flash_flat_gqa", "flash_packed",
     "layer_norm_fused",

@@ -9,7 +9,12 @@ Per head the state is a matrix ``S [dk, dv]`` (float32), ``S_0 = 0``:
     o_t = S_t^T q_t
 
 with ``alpha_t`` in (0, 1)^dk given as its logarithm (``log_alpha <= 0``) and
-``beta_t`` a scalar (up to 2 where negative eigenvalues are allowed). Written
+``beta_t`` a scalar (up to 2 where negative eigenvalues are allowed). A decay
+that is the same in every channel (the gated delta net, arXiv:2412.06464: one
+scalar a head) is given with a last axis of 1 and broadcast; value heads that
+share a key head (``q`` and ``k`` with fewer heads than ``v``: head ``h`` of
+``v`` takes head ``h // group`` of ``q`` and ``k``) are served by repeating
+the key heads, not by a second recurrence. Written
 with ``u_t = beta_t (v_t - S_{t-1}^T (alpha_t * k_t))`` the update is
 ``S_t = Diag(alpha_t) S_{t-1} + k_t u_t^T``: a decay of the rows and a rank-one
 write.
@@ -40,10 +45,20 @@ __all__ = ["delta_rule_step", "delta_rule_chunked"]
 _HI = jax.lax.Precision.HIGHEST
 
 
+def _per_value_head(a, heads: int, axis: int):
+    """``a`` with its key heads along ``axis`` repeated, each for the group of
+    value heads it serves; ``a`` itself where there are as many already."""
+    return a if a.shape[axis] == heads else jnp.repeat(a, heads // a.shape[axis], axis=axis)
+
+
 def delta_rule_step(q, k, v, log_alpha, beta, state):
-    """One token for every row of a batch. ``q``, ``k``, ``log_alpha``
-    ``[..., dk]``, ``v`` ``[..., dv]``, ``beta`` ``[...]``, ``state``
-    ``[..., dk, dv]``, all float32. Returns ``(o [..., dv], state)``."""
+    """One token for every row of a batch. ``q``, ``k`` ``[..., dk]``,
+    ``log_alpha`` ``[..., dk]`` or ``[..., 1]``, ``v`` ``[..., dv]``, ``beta``
+    ``[...]``, ``state`` ``[..., dk, dv]``, all float32; with a head axis
+    before the last, ``q`` and ``k`` may have fewer heads than ``v``. Returns
+    ``(o [..., dv], state)``."""
+    if q.ndim > 1:
+        q, k = (_per_value_head(a, v.shape[-2], -2) for a in (q, k))
     state = state * jnp.exp(log_alpha)[..., None]
     seen = jnp.sum(state * k[..., None], axis=-2)                 # S^T k, on the VPU: exact float32
     u = beta[..., None] * (v - seen)
@@ -68,10 +83,12 @@ def _solve_unit_lower(m, rhs):
 
 def delta_rule_chunked(q, k, v, log_alpha, beta, state, *, chunk: int = 64):
     """A run of ``T`` tokens of one sequence, every head at once. ``q``,
-    ``k``, ``log_alpha`` ``[H, T, dk]``, ``v`` ``[H, T, dv]``, ``beta``
-    ``[H, T]``, ``state`` ``[H, dk, dv]``, all float32; ``T`` a multiple of
-    ``chunk``. Returns ``(o [H, T, dv], state)``: the same numbers as ``T``
-    calls of :func:`delta_rule_step`, up to float32 rounding."""
+    ``k`` ``[H, T, dk]`` (or fewer, shared, heads), ``log_alpha`` ``[H, T, dk]``
+    or ``[H, T, 1]``, ``v`` ``[H, T, dv]``, ``beta`` ``[H, T]``, ``state``
+    ``[H, dk, dv]``, all float32; ``T`` a multiple of ``chunk``. Returns
+    ``(o [H, T, dv], state)``: the same numbers as ``T`` calls of
+    :func:`delta_rule_step`, up to float32 rounding."""
+    q, k = (_per_value_head(a, v.shape[0], 0) for a in (q, k))
     H, T, dk = q.shape
     dv = v.shape[-1]
     c = int(chunk)
@@ -86,9 +103,15 @@ def delta_rule_chunked(q, k, v, log_alpha, beta, state, *, chunk: int = 64):
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     # exp(g_t - g_s) for s <= t, 0 above the diagonal; never over 1
     rel = jnp.where((s_idx <= t_idx)[None, None, :, :, None], g[:, :, :, None, :] - g[:, :, None, :, :], -jnp.inf)
-    kd = k[:, :, None, :, :] * jnp.exp(rel)                                         # [H, n, C(t), C(s), dk]
-    a_kk = jnp.sum(k[:, :, :, None, :] * kd, axis=-1)                               # [H, n, C, C]: k_t . Diag(G_t/G_s) k_s
-    a_qk = jnp.sum(q[:, :, :, None, :] * kd, axis=-1)
+    if log_alpha.shape[-1] == 1 and dk > 1:
+        # one decay a head: it leaves the sum over the channels, which is then a matmul ([C, C, dk] is never made)
+        k_t, pair = jnp.swapaxes(k, -1, -2), jnp.exp(rel[..., 0])
+        a_kk = jnp.matmul(k, k_t, precision=_HI) * pair                               # [H, n, C, C]: G_t/G_s k_t . k_s
+        a_qk = jnp.matmul(q, k_t, precision=_HI) * pair
+    else:
+        kd = k[:, :, None, :, :] * jnp.exp(rel)                                     # [H, n, C(t), C(s), dk]
+        a_kk = jnp.sum(k[:, :, :, None, :] * kd, axis=-1)                           # [H, n, C, C]: k_t . Diag(G_t/G_s) k_s
+        a_qk = jnp.sum(q[:, :, :, None, :] * kd, axis=-1)
     strict = (s_idx < t_idx)[None, None]
     m = jnp.where(strict, beta[..., None] * a_kk, 0.0)
     decay = jnp.exp(g)

@@ -45,23 +45,32 @@ def route_topk(x, router_w, *, top_k: int, norm_topk: bool = True, scale: float 
         return w * scale, idx.astype(jnp.int32)
 
 
-def gated_ffn(x, w_gate_up, w_down):
+def _gated(h, f: int, limit):
+    """``SiLU(gate) * up`` of ``h = [gate | up]``; with ``limit`` (a
+    ``swiglu_limit``) the gate clamped from above and the up-projection on
+    both sides first."""
+    if limit is None:
+        return jax.nn.silu(h[..., :f]) * h[..., f:]
+    return jax.nn.silu(jnp.minimum(h[..., :f], limit)) * jnp.clip(h[..., f:], -limit, limit)
+
+
+def gated_ffn(x, w_gate_up, w_down, limit=None):
     """``W_down(SiLU(W_gate x) * W_up x)`` with gate and up packed side by
-    side in ``w_gate_up [D, 2F]``."""
+    side in ``w_gate_up [D, 2F]``; ``limit`` clamps them (:func:`_gated`)."""
     h = jnp.matmul(x, w_gate_up, preferred_element_type=jnp.float32)
     f = w_down.shape[-2]
-    return jnp.matmul((jax.nn.silu(h[..., :f]) * h[..., f:]).astype(x.dtype), w_down,
-                      preferred_element_type=jnp.float32)
+    return jnp.matmul(_gated(h, f, limit).astype(x.dtype), w_down, preferred_element_type=jnp.float32)
 
 
-def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held, n_experts=None):
+def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held, n_experts=None, limit=None):
     """The held experts' part of the routed result.
 
     ``x [T, D]``; ``weights``/``experts`` ``[T, k]`` from :func:`route_topk`;
     ``w_gate_up [count, D, 2F]``, ``w_down [count, F, D]`` the held experts'
     weights; ``held = (first, count)``; ``n_experts`` the router's width
     (``count`` if not given: the layer holds them all), from which the
-    grouped matmul takes the run it should expect. Returns ``(y [T, D]
+    grouped matmul takes the run it should expect; ``limit`` clamps the gated
+    product's two factors (:func:`_gated`). Returns ``(y [T, D]
     float32, stats int32[2])`` with ``stats = (pairs routed to a held expert,
     held experts with at least one pair)``."""
     first, count = int(held[0]), int(held[1])
@@ -78,7 +87,7 @@ def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held, n_experts=
         matmul = _grouped.select(rows, w_gate_up, w_down, expected_run=expected_run)
         h = matmul(rows, w_gate_up, sizes, expected_run=expected_run)
         f = w_down.shape[-2]
-        a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(x.dtype)
+        a = _gated(h, f, limit).astype(x.dtype)
         y = matmul(a, w_down, sizes, expected_run=expected_run)
         # back to token order: pair p sits at row inverse[p]; rows past the
         # held pairs belong to no run, so whatever they hold is masked out

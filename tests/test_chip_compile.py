@@ -258,6 +258,13 @@ _PARENT_PROGRAMS = {
         "chunk_core": ("b34ae03bffc9098e", 2374),
         "chunk_final_core": ("6151ba245647615e", 2777),
     },
+    # PR 34, the cell's first programs: what a later refactor of ``models/gigachat3_5.py`` or of the ops it shares with
+    # Solar's (``ops/delta_rule.py``, ``ops/moe_dropless.py``) has to leave as it is
+    "gigachat3.5-432b-a28b.serve-longdoc": {
+        "decode_fn": ("968730bb3bdb7bbc", 2897),
+        "chunk_core": ("db726409c4a62105", 3398),
+        "chunk_final_core": ("2ed8e86a3afcb6a9", 3889),
+    },
     "gpt2-medium.train": {
         "_step": ("af707415b346d11f", 1303),
     },
@@ -284,6 +291,11 @@ _PARENT_SCOPES = {
         "chunk_core": {"unscoped": 896, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 426, "moe_shared": 36, "linear_proj": 92, "linear_core": 553, "linear_out": 38},
         "chunk_final_core": {"unscoped": 1011, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 568, "moe_shared": 48, "linear_proj": 102, "linear_core": 597, "linear_out": 57, "head_loss": 2},
     },
+    "gigachat3.5-432b-a28b.serve-longdoc": {
+        "decode_fn": {"unscoped": 752, "embed": 1, "norm": 566, "linear_proj": 100, "linear_core": 404, "linear_out": 124, "mlp": 14, "mla_q": 35, "mla_kv": 27, "rope": 46, "mla_core": 3, "mla_out": 17, "moe_router": 68, "moe_routed": 624, "moe_shared": 60, "head_loss": 2},
+        "chunk_core": {"unscoped": 1156, "embed": 1, "norm": 459, "linear_proj": 99, "linear_core": 752, "linear_out": 93, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 51, "moe_routed": 435, "moe_shared": 45},
+        "chunk_final_core": {"unscoped": 1279, "embed": 1, "norm": 565, "linear_proj": 100, "linear_core": 796, "linear_out": 124, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 68, "moe_routed": 580, "moe_shared": 60, "head_loss": 2},
+    },
     "gpt2-medium.train": {
         "_step": {"unscoped": 370, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 37, "head_loss": 47, "optimizer": 439},
     },
@@ -303,7 +315,8 @@ def _fingerprint(lowered_text):
 
 # Every ``jax.named_scope`` a by-part metric reads (PERF.md §3; ``benchmark/families/*.py:PART_OF_SCOPE``).
 _SCOPES = {"norm", "attn_qkv", "attn_core", "attn_out", "mlp", "embed", "head_loss", "cache_write", "cache_read",
-           "optimizer", "amp_cast", "linear_proj", "linear_core", "linear_out", "moe_router", "moe_routed", "moe_shared"}
+           "optimizer", "amp_cast", "linear_proj", "linear_core", "linear_out", "moe_router", "moe_routed", "moe_shared",
+           "mla_q", "mla_kv", "rope", "mla_core", "mla_out"}
 _LOC_NAME = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
 _LOC_USE = re.compile(r" loc\((#loc\d*)\)$", re.M)
 
@@ -371,25 +384,27 @@ def test_gpt_serving_programs_are_the_parents_through_the_decoder_interface(as_t
 _SOLAR = "solar-open2-250b.serve-reasoning"
 
 
-def _solar_lowered(one_chip):
-    """``(cfg, decoder, {program: lowered})`` of the Solar cell: 128 slots x 16,384, chunk 1,024, one chip's share at
-    the published widths, bf16, abstract arguments. The engine holds the programs only: nothing is allocated."""
-    if _SOLAR in _LOWERED:
-        return _LOWERED[_SOLAR]
+def _share_lowered(cell, module, name, config_file, one_chip, B, S, C=1024):
+    """``(cfg, decoder, {program: (lowered, the kernels its lowering picked)})`` of a cell that serves one chip's share
+    of the model ``<name>ForCausalLM`` of ``paddle_tpu.models.<module>``: ``B`` slots x ``S``, chunk ``C``, at the published widths, bf16,
+    abstract arguments. The engine holds the programs only: nothing is allocated."""
+    if cell in _LOWERED:
+        return _LOWERED[cell]
+    import importlib
     import json
 
     from paddle_tpu.inference import DecodeEngine
-    from paddle_tpu.models.solar_open2 import F32_WEIGHTS, PER_LAYER_WEIGHTS, SolarOpen2Config, SolarOpen2ForCausalLM
     from paddle_tpu.observability import metrics
 
-    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
-                           "solar-open2-250b.json")) as f:
-        cfg = SolarOpen2Config.from_config_file(json.load(f))
-    B, S, C, bf = 128, 16384, 1024, jnp.bfloat16
-    weights = {k: one_chip(shape, jnp.float32 if k in F32_WEIGHTS else bf) for k, shape in cfg.weight_shapes().items()}
-    for k in PER_LAYER_WEIGHTS:
+    model = importlib.import_module(f"paddle_tpu.models.{module}")
+    config, causal_lm = getattr(model, name + "Config"), getattr(model, name + "ForCausalLM")
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs", config_file)) as f:
+        cfg = config.from_config_file(json.load(f))
+    bf = jnp.bfloat16
+    weights = {k: one_chip(shape, jnp.float32 if k in model.F32_WEIGHTS else bf) for k, shape in cfg.weight_shapes().items()}
+    for k in model.PER_LAYER_WEIGHTS:
         weights[k] = tuple(one_chip(weights[k].shape[1:], bf) for _ in range(weights[k].shape[0]))
-    decoder = SolarOpen2ForCausalLM(cfg, weights=weights).decoder()
+    decoder = causal_lm(cfg, weights=weights).decoder()
     engine = DecodeEngine.__new__(DecodeEngine)
     engine._dec, engine._ddec, engine._sample, engine.spec_k, engine._donate, engine._chunk = decoder, None, (False, 1.0, 0, 1.0), 0, True, C
     engine._build()
@@ -402,12 +417,17 @@ def _solar_lowered(one_chip):
         "chunk_core": (engine._chunk_jit, (weights, cache, ids, scalar, scalar)),
         "chunk_final_core": (engine._chunk_final_jit, (weights, cache) + state + (ids,) + (scalar,) * 7),
     }
-    lowered = {}     # program -> (lowered, the kernels its lowering picked)
+    lowered = {}
     for name, (fn, args) in programs.items():
         metrics.reset_counters("kernels.")
         lowered[name] = (fn.lower(*args), metrics.counters("kernels."))
-    _LOWERED[_SOLAR] = cfg, decoder, lowered
-    return _LOWERED[_SOLAR]
+    _LOWERED[cell] = cfg, decoder, lowered
+    return _LOWERED[cell]
+
+
+def _solar_lowered(one_chip):
+    """The Solar cell's programs: 128 slots x 16,384, chunk 1,024."""
+    return _share_lowered(_SOLAR, "solar_open2", "SolarOpen2", "solar-open2-250b.json", one_chip, 128, 16384)
 
 
 @pytest.mark.parametrize("program", ["decode_fn", "chunk_core"])
@@ -442,6 +462,54 @@ def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu,
         assert memory.alias_size_in_bytes >= held and memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
     else:
         assert memory.temp_size_in_bytes < 0.6e9, memory.temp_size_in_bytes      # 0.55 GB with XLA's grouped matmul
+
+
+_GIGA = "gigachat3.5-432b-a28b.serve-longdoc"
+
+
+def _giga_lowered(one_chip):
+    """The GigaChat cell's programs: 48 slots x 32,768, chunk 1,024."""
+    return _share_lowered(_GIGA, "gigachat3_5", "GigaChat35", "gigachat3.5-432b-a28b.json", one_chip, 48, 32768)
+
+
+@pytest.mark.parametrize("program", ["decode_fn", "chunk_core", "chunk_final_core"])
+def test_gigachat3_5_programs_compile_for_v5e_at_the_cells_shapes(as_tpu, one_chip, program):
+    """The programs of ``gigachat3.5-432b-a28b.serve-longdoc`` (48 slots x 32,768, chunk 1,024, one chip's share at the
+    published widths, bf16, abstract arguments) compile for the described chip: the latent layer's decode through the
+    ``mla_decode`` kernel on the ``[B, S, 576]`` cache, aliased; two grouped matmuls an expert layer through
+    ``ops/grouped_matmul.py`` at D 7,168 and width 2,048 (row tile 16 for 384 pairs over a router of 256, 128 for a
+    chunk's 8,192), none through XLA's; every slot buffer updated in place; and the chunk programs' temporaries beside
+    the 12.1 GB held inside the chip's 16 GB."""
+    cfg, decoder, lowered = _giga_lowered(one_chip)
+    B, S = 48, 32768
+    lowered, picked = lowered[program]
+    compiled = lowered.compile()
+    # one selection a kernel a set of shapes: the final chunk's are the chunk's, selected when that was lowered
+    once = 0 if program == "chunk_final_core" else 1
+    new = "mla_decode" if program == "decode_fn" else "mla_prefill"
+    assert {k: v for k, v in picked.items() if v} == {k: v for k, v in {
+        "kernels.grouped_matmul.picked": once, f"kernels.{new}.picked": once,
+        "kernels.rope.picked": 2 * once}.items() if v}                                       # the queries' shape and the key's
+    calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+    experts = len(cfg.expert_layers) - (program == "chunk_core")     # an intermediate chunk's last expert layer feeds nothing
+    tm = 16 if program == "decode_fn" else 128
+    assert sum(f"%moe_grouped_{tm}" in line for line in calls) == 2 * experts
+    assert not any("ragged-dot" in line for line in calls)
+    assert all("/moe_routed/" in line for line in calls if "moe_grouped" in line)
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
+    print(f"{_GIGA} {program}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB; {picked}")
+    # 9.46 GB of weights + 2.84 GB of slots (a latent row padded to 640 lanes); an intermediate chunk takes neither
+    # the head nor the last layer's experts
+    assert (10.5e9 if program == "chunk_core" else 11.9e9) < memory.argument_size_in_bytes < 12.6e9
+    assert memory.alias_size_in_bytes >= held
+    if program == "decode_fn":
+        mla = [line for line in calls if "mla_decode" in line]
+        assert len(mla) == len(cfg.full_attention_layers) and all("/mla_core/" in line for line in mla)
+        assert memory.temp_size_in_bytes < 0.3e9, memory.temp_size_in_bytes
+    else:
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory.temp_size_in_bytes
 
 
 def test_engine_imports_no_private_function_of_a_model():
@@ -525,13 +593,14 @@ def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, topo, layout):
 
 _TRAIN, _TRAIN4 = "gpt2-medium.train", "cerebras-gpt-1.3b.train-zero2mp2"
 _PROGRAMS = ([(cell, name) for cell in sorted(_DECODE) for name in ("decode_fn", "chunk_core", "chunk_final_core", "prefill_core")]
-             + [(_SOLAR, name) for name in ("decode_fn", "chunk_core", "chunk_final_core")]
+             + [(cell, name) for cell in (_SOLAR, _GIGA) for name in ("decode_fn", "chunk_core", "chunk_final_core")]
              + [(_TRAIN, "_step"), (_TRAIN4, "_step")])
 
 
 @pytest.mark.parametrize("cell,program", _PROGRAMS, ids=[f"{c}-{n}" for c, n in _PROGRAMS])
 def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_chip, cell, program):
-    """Each cell's program lowers to the text it lowered to at 094436e, and the operations under each
+    """Each cell's program lowers to the text it lowered to at 094436e (the GigaChat cell's: at PR 34, which brought
+    them), and the operations under each
     ``jax.named_scope`` the by-part metrics read are as many as they were: the hash does not see a scope's name, the
     metrics see nothing else. The four-chip cell's step is ``test_distributed_step``'s ``sharding2xmp2`` layout, the
     one-chip train cell's ``test_train_step``'s."""
@@ -539,6 +608,8 @@ def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_ch
         lowered = _gpt_serving_lowered(cell, one_chip)[program]
     elif cell == _SOLAR:
         lowered = _solar_lowered(one_chip)[2][program][0]
+    elif cell == _GIGA:
+        lowered = _giga_lowered(one_chip)[2][program][0]
     elif cell == _TRAIN:
         lowered = _train_lowered(one_chip)
     else:
