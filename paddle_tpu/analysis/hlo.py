@@ -19,6 +19,8 @@ process.
 from __future__ import annotations
 
 import hashlib
+import itertools
+import math
 import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -31,6 +33,7 @@ __all__ = [
     "parse_collectives",
     "collective_counts",
     "moved_bytes",
+    "moved_bytes_by_type",
     "total_moved_bytes",
     "schedule_fingerprint",
     "entry_memory_lower_bound",
@@ -59,11 +62,13 @@ _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*?)\s+"
     r"(" + "|".join(COLLECTIVE_KINDS) + r")(-start)?\(")
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([^=]*?)\}\}")
-_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
 _CHANNEL_RE = re.compile(r"channel_id=(\d+)")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _SOURCE_RE = re.compile(r'source_file="([^"]*)"(?:\s+source_line=(\d+))?')
 _PARAM_RE = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*(.*?)\s+parameter\(\d+\)")
+# a computation's header: "%name (arg: shape, ...) -> result-shape {"
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s+\((.*)\)\s*->\s*(.*?)\s*\{\s*$")
 
 
 def parse_shapes(text: str) -> List[Tuple[str, Tuple[int, ...]]]:
@@ -99,6 +104,7 @@ class HloCollective:
     operand_shapes: List[Tuple[str, Tuple[int, ...]]] = field(default_factory=list)
     group_size: int = 1                         # devices per replica group
     num_groups: int = 1
+    groups: Tuple[Tuple[int, ...], ...] = ()     # the replica groups' device ids
     channel_id: Optional[int] = None
     op_name: str = ""                           # metadata: the forcing op
     source: str = ""                            # "file:line" when recorded
@@ -125,29 +131,54 @@ class HloCollective:
                 f"dispatch{via}{loc}")
 
 
-def _parse_groups(line: str) -> Tuple[int, int]:
-    """(group_size, num_groups) from either replica-group spelling:
-    explicit ``{{0,1},{2,3}}`` or iota ``[num_groups,group_size]<=[N]``."""
+def _parse_groups(line: str) -> Tuple[Tuple[int, ...], ...]:
+    """The replica groups, each a tuple of device ids, from either spelling:
+    explicit ``{{0,1},{2,3}}`` or iota ``[num_groups,group_size]<=[dims]``
+    with an optional ``T(perm)`` (the ids 0..N-1 laid out as ``dims``,
+    transposed by ``perm``, then cut into the groups in row-major order)."""
     m = _GROUPS_IOTA_RE.search(line)
     if m:
-        return int(m.group(2)), int(m.group(1))
+        ngr, gsz = int(m.group(1)), int(m.group(2))
+        dims = [int(d) for d in m.group(3).split(",")]
+        perm = [int(d) for d in m.group(4).split(",")] if m.group(4) else list(range(len(dims)))
+        strides = [math.prod(dims[i + 1:]) for i in range(len(dims))]
+        ids = [sum(i * strides[p] for i, p in zip(index, perm))
+               for index in itertools.product(*(range(dims[p]) for p in perm))]
+        return tuple(tuple(ids[g * gsz:(g + 1) * gsz]) for g in range(ngr))
     m = _GROUPS_LIST_RE.search(line)
     if m:
-        groups = [g for g in m.group(1).split("},{")]
-        first = [t for t in groups[0].split(",") if t.strip()]
-        return max(1, len(first)), max(1, len(groups))
-    return 1, 1
+        return tuple(tuple(int(t) for t in g.split(",") if t.strip()) for g in m.group(1).split("},{"))
+    return ()
 
 
 def parse_collectives(hlo_text: str) -> List[HloCollective]:
     """Every collective instruction in ``hlo_text``, in program order.
 
-    Works on the optimized (post-partitioning) module text; async pairs are
-    collapsed onto their ``-start`` half so each transfer counts once.
+    Works on the optimized (post-partitioning) module text; each transfer
+    counts once. Async pairs are collapsed onto their ``-start`` half. The
+    TPU compiler's two spellings are read for what they run as. One async
+    collective is written out three times, in the wrapped computations of
+    its start, of the work it overlaps (``async_collective_fusion``) and of
+    its done (a ``custom-call`` to ``AsyncCollectiveDone``): the start's
+    counts. An ``all-reduce`` with the ``dynamic-slice`` of its result
+    inside a fused computation named ``all-reduce-scatter`` is the chip's
+    reduce-scatter, from the fusion's operand to its result.
     """
     out: List[HloCollective] = []
+    first_of_computation = 0        # out[first_of_computation:] belong to the computation being read
+    wrapper = False                 # inside an async_collective_fusion
+    fused_scatter = None            # (operand text, result text) inside an all-reduce-scatter fusion
     for lineno, line in enumerate(hlo_text.splitlines(), 1):
-        m = _INSTR_RE.match(line)
+        head = _COMPUTATION_RE.match(line)
+        if head is not None:
+            first_of_computation = len(out)
+            fused_scatter = (head.group(2), head.group(3)) if head.group(1).startswith("all-reduce-scatter") else None
+            wrapper = head.group(1).startswith("async_collective_fusion")
+            continue
+        if 'custom_call_target="AsyncCollectiveDone"' in line:
+            del out[first_of_computation:]      # the tail: the indices of what stays are still 0..len(out)-1
+            continue
+        m = None if wrapper else _INSTR_RE.match(line)
         if m is None:
             continue
         name, result, kind = m.group(1), m.group(2), m.group(3)
@@ -163,8 +194,10 @@ def parse_collectives(hlo_text: str) -> List[HloCollective]:
                     end = i
                     break
         operands = tail[:end]
-        gsz, ngr = _parse_groups(line)
+        groups = _parse_groups(line)
         ch_m = _CHANNEL_RE.search(line)
+        if fused_scatter is not None and kind == "all-reduce":
+            kind, (operands, result) = "reduce-scatter", fused_scatter
         op_m = _OPNAME_RE.search(line)
         src_m = _SOURCE_RE.search(line)
         src = ""
@@ -176,7 +209,7 @@ def parse_collectives(hlo_text: str) -> List[HloCollective]:
             kind=kind, name=name, index=len(out), line=lineno,
             result_shapes=parse_shapes(result),
             operand_shapes=parse_shapes(operands),
-            group_size=gsz, num_groups=ngr,
+            group_size=max(1, len(groups[0])) if groups else 1, num_groups=max(1, len(groups)), groups=groups,
             channel_id=int(ch_m.group(1)) if ch_m else None,
             op_name=op_m.group(1) if op_m else "",
             source=src))
@@ -188,6 +221,18 @@ def collective_counts(collectives: Sequence[HloCollective]) -> Dict[str, int]:
     for c in collectives:
         counts[c.kind] = counts.get(c.kind, 0) + 1
     return counts
+
+
+def moved_bytes_by_type(collectives: Sequence[HloCollective]) -> Dict[str, int]:
+    """``{"all-gather bf16": bytes, ...}``: the estimate of :func:`moved_bytes`
+    summed per kind and element type (a tuple result's first member names the
+    type), so that one line says what crosses the wire and how wide."""
+    totals: Dict[str, int] = {}
+    for c in collectives:
+        shapes = c.result_shapes or c.operand_shapes
+        key = f"{c.kind} {shapes[0][0] if shapes else '?'}"
+        totals[key] = totals.get(key, 0) + moved_bytes(c)  # noqa: PTA104 (host-side, never traced)
+    return totals
 
 
 def moved_bytes(c: HloCollective) -> int:

@@ -209,6 +209,7 @@ class SpmdReport:
             "collectives": self.counts(),
             "collective_count": len(self.collectives),
             "reshard_bytes": self.moved_bytes,
+            "moved_bytes_by_type": _hlo.moved_bytes_by_type(self.collectives),
             "peak_bytes": self.peak_bytes,
             "fingerprint": self.fingerprint,
             "codes": sorted({d.code for d in self.diagnostics}),

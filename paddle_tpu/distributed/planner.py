@@ -355,7 +355,7 @@ def _evaluate(plan: Plan, step, abstract_state, abstract_batch, devices,
     from ..cost_model import predict_step_time
     from ..observability.introspect import aot_compile
     from ..observability.metrics import counter_inc
-    from .sharding import state_shardings
+    from .sharding import param_placement, state_shardings
 
     counter_inc("planner.evaluations")
     mesh = plan.build_mesh(devices)
@@ -373,6 +373,9 @@ def _evaluate(plan: Plan, step, abstract_state, abstract_batch, devices,
         counter_inc("planner.pruned")
         return plan
     batch_sharding = NamedSharding(mesh, P(("dp", "sdp")))
+    # the trace below reads it: a ZeRO >= 2 candidate is scored on the
+    # program build_step will dispatch (gather of the cast, scatter of grads)
+    step._param_placement = param_placement(shardings, mp_specs)
     jitted = _sharded_jit(step, mesh, shardings, batch_sharding)
     compiled, info = aot_compile(jitted, (abstract_state, abstract_batch),
                                  cache_scope="train_step")
@@ -620,12 +623,14 @@ def build_step(model, optimizer, loss_fn, plan: Plan, devices=None,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..jit import TrainStep, scan_steps
-    from .sharding import place_state, state_shardings
+    from .sharding import param_placement, place_state, state_shardings
 
     mesh = plan.build_mesh(devices)
     step = TrainStep(model, optimizer, loss_fn, seed=seed, **step_kwargs)
+    mp_specs = plan.resolved_specs()
     shardings = state_shardings(step.state, mesh, stage=plan.stage,
-                                mp_specs=plan.resolved_specs())
+                                mp_specs=mp_specs)
+    step._param_placement = param_placement(shardings, mp_specs)
     batch_sharding = NamedSharding(mesh, P(("dp", "sdp")))
     step.mesh = mesh
     step.state = place_state(step.state, shardings)

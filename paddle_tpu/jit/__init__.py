@@ -150,6 +150,11 @@ class TrainStep:
         self._remat = remat
         self._batch_shardings = batch_shardings
         self._state_shardings = state_shardings
+        # {name: (spec in the state, spec in the computation)} for the
+        # parameters a sharded build keeps with the rank that updates them
+        # (distributed.sharding.param_placement: ZeRO >= 2); the step then
+        # gathers their compute copy and scatters their gradients
+        self._param_placement = None
         if mesh is not None and isinstance(state_shardings, dict):
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -195,9 +200,11 @@ class TrainStep:
         amp_dt, amp_level = self.amp_dtype, self.amp_level
         o2 = amp_level == "O2"
 
+        def _amp(a):
+            return a.astype(amp_dt) if a.dtype == jnp.float32 else a
+
         def _to_amp(tree):
-            return jax.tree_util.tree_map(
-                lambda a: a.astype(amp_dt) if a.dtype == jnp.float32 else a, tree)
+            return jax.tree_util.tree_map(_amp, tree)
 
         def _to_f32(x):
             return jax.tree_util.tree_map(
@@ -237,6 +244,38 @@ class TrainStep:
                 call = jax.checkpoint(call)
             return call(params)
 
+        def _owned():
+            """``{name: (owner's sharding, the computation's)}`` at trace time:
+            the mesh is the fleet's as the trace finds it, like the model's
+            own constraints; empty wherever the state holds every parameter
+            as the model computes with it (one chip, ZeRO 0/1)."""
+            if not self._param_placement:
+                return {}
+            from jax.sharding import NamedSharding
+
+            from ..distributed.fleet import fleet
+
+            mesh = fleet.mesh if fleet.mesh is not None else self.mesh
+            return {name: (NamedSharding(mesh, owner), NamedSharding(mesh, compute))
+                    for name, (owner, compute) in self._param_placement.items()}
+
+        def _compute_copy(params, owned):
+            """Cast the owner's shard, then gather the cast: what crosses the
+            wire is ``amp_dtype``, and the f32 master never leaves its rank."""
+            with jax.named_scope("amp_cast"):
+                return {**params, **{
+                    name: jax.lax.with_sharding_constraint(_amp(params[name]) if o2 else params[name], compute)
+                    for name, (_, compute) in owned.items()}}
+
+        def _to_owner(grads, params, owned):
+            """Each gradient to the rank that updates its parameter, in the
+            type it was computed in (partial sums over 'sdp' reduce-scatter);
+            only the shard is widened for the update."""
+            with jax.named_scope("amp_cast"):
+                return {**grads, **{
+                    name: jax.lax.with_sharding_constraint(grads[name], owner).astype(params[name].dtype)
+                    for name, (owner, _) in owned.items()}}
+
         k = self.accumulate_steps
         guard = self.guard
         nan_chaos = self._nan_chaos
@@ -244,10 +283,15 @@ class TrainStep:
         def _step(state, batch):
             inputs, labels = batch
             rng = jax.random.fold_in(state["rng"], state["step"])
+            owned = _owned()
+            compute = _compute_copy(state["params"], owned)
+
+            def grad_fn(buffers, inputs, labels, rng):
+                res, grads = jax.value_and_grad(loss_of, has_aux=True)(compute, buffers, inputs, labels, rng)
+                return res, _to_owner(grads, state["params"], owned)
+
             if k <= 1:
-                (loss, (out, new_buffers)), grads = jax.value_and_grad(loss_of, has_aux=True)(
-                    state["params"], state["buffers"], inputs, labels, rng
-                )
+                (loss, (out, new_buffers)), grads = grad_fn(state["buffers"], inputs, labels, rng)
             else:
                 # gradient merge (parity: fleet/meta_optimizers/
                 # gradient_merge_optimizer.py): k microbatches through a
@@ -262,9 +306,7 @@ class TrainStep:
                 def acc(carry, xs):
                     gsum, lsum, buffers = carry
                     i, mi, ml = xs
-                    (l, (o, nb)), g = jax.value_and_grad(loss_of, has_aux=True)(
-                        state["params"], buffers, mi, ml, jax.random.fold_in(rng, i)
-                    )
+                    (l, (o, nb)), g = grad_fn(buffers, mi, ml, jax.random.fold_in(rng, i))
                     gsum = jax.tree_util.tree_map(jnp.add, gsum, g)
                     # per-microbatch outputs stack up for hapi metrics (the
                     # scan ys); stacked as [k, mb, ...] and re-interleaved

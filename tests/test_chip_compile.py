@@ -240,6 +240,11 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
 # the output: two converts and a concatenate, ``unscoped`` 621 -> 624 and 765 -> 768; Solar's widens by two operands,
 # 608 -> 610), so that a step launched ahead can be pulled after the next launch has donated ``tok`` and ``active``.
 # The body (``decode_body``) is untouched: every named scope's count, and every other program, is as it was.
+# PR 35 re-pinned ``cerebras-gpt-1.3b.train-zero2mp2``'s ``_step`` and nothing else (819e0ecbdaf33112, 1319 lines
+# before): at ZeRO stage 2 the f32 master enters and leaves the program split over 'sdp' like its moments, and the step
+# gains, under ``amp_cast``, a sharding constraint on each of the six split parameters' bf16 cast (the gather) and one on
+# each of their gradients (the scatter to the owner) — ``amp_cast`` 32 -> 44, twelve lines; their casts moved out of
+# the differentiated function with them. No other scope's count moved; ``gpt2-medium.train`` has no mesh and is as it was.
 _PARENT_PROGRAMS = {
     "cerebras-gpt-1.3b.serve-longgen": {
         "decode_fn": ("c1b2b9e757190693", 3277),
@@ -269,7 +274,7 @@ _PARENT_PROGRAMS = {
         "_step": ("af707415b346d11f", 1303),
     },
     "cerebras-gpt-1.3b.train-zero2mp2": {
-        "_step": ("819e0ecbdaf33112", 1319),
+        "_step": ("71f99c73f25beaf1", 1331),
     },
 }
 
@@ -300,7 +305,7 @@ _PARENT_SCOPES = {
         "_step": {"unscoped": 370, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 37, "head_loss": 47, "optimizer": 439},
     },
     "cerebras-gpt-1.3b.train-zero2mp2": {
-        "_step": {"unscoped": 413, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 4, "head_loss": 49, "optimizer": 439},
+        "_step": {"unscoped": 413, "amp_cast": 44, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 4, "head_loss": 49, "optimizer": 439},
     },
 }
 
@@ -583,12 +588,65 @@ def _distributed_lowered(topo, layout):
         fleet._hcg = None
 
 
+_COMPILED4 = {}    # layout -> the four-chip step's optimized HLO text, compiled once for this file's tests
+
+
+def _distributed_compiled(topo, layout):
+    if layout not in _COMPILED4:
+        _COMPILED4[layout] = _distributed_lowered(topo, _LAYOUTS[layout]).compile().as_text()
+    return _COMPILED4[layout]
+
+
 @pytest.mark.parametrize("layout", sorted(_LAYOUTS))
 def test_distributed_step_compiles_for_a_v5e_2x2_mesh(as_tpu, topo, layout):
     """A Mosaic kernel cannot be partitioned automatically — the compiler's own words are "wrap the call in a
     shard_map" — so the attention kernels must run per shard (batch over dp x sdp, heads over mp) inside the
     GSPMD-partitioned step."""
-    assert _distributed_lowered(topo, _LAYOUTS[layout]).compile().as_text().count("tpu_custom_call") >= 3
+    assert _distributed_compiled(topo, layout).count("tpu_custom_call") >= 3
+
+
+# The four-chip step of 7835fc5 (PR 34), compiled as ``_distributed_lowered`` compiles it (one layer at width 1024,
+# b8 s1024, AMP O2, sdp 2 x mp 2 at stage 2) and read by ``analysis/hlo.py`` as this PR leaves it: bytes a chip moves a
+# step in the all-gathers over the 'sdp' groups — six f32 parameters gathered after the update (66.2 MB) and the
+# embedding gradient's bf16 rows (8.4 MB) — and in all all-gathers (with the 25.2 MB of qkv activations over 'mp',
+# which no ZeRO placement touches). Its weight gradients: one all-reduce of twelve bf16 tensors over 'sdp', 64.1 MB.
+_PARENT_SDP_ALL_GATHER_BYTES, _PARENT_ALL_GATHER_BYTES = 74_596_352, 99_774_464
+_SDP_GROUPS = ((0, 2), (1, 3))     # mesh (dp 1, pp 1, sdp 2, mp 2, sep 1): chip = 2 * sdp + mp
+
+
+def test_zero2_step_gathers_the_cast_and_scatters_the_gradients_on_v5e(as_tpu, topo):
+    """What crosses the wire in ``cerebras-gpt-1.3b.train-zero2mp2``'s step, read off the program compiled for the
+    described ``v5e:2x2``: every parameter all-gather is of the bf16 cast (none of 1 MiB or more has an f32 result)
+    and is named by the cast, none by the optimizer — nothing follows the update; over the 'sdp' groups the
+    all-gathers move at most 0.55 of the parent's bytes (0.444; over all groups 0.584, the 'mp' gathers of
+    activations being the parent's); and every weight matrix's gradient is reduce-scattered in bf16 to the rank that
+    updates it — the chip's fused ``all-reduce-scatter`` — where the parent all-reduced it and sliced: what is still
+    all-reduced over 'sdp' is vectors and the position table (2.1 MB of the parent's 64.1)."""
+    from paddle_tpu.analysis import hlo
+
+    collectives = hlo.parse_collectives(_distributed_compiled(topo, "sharding2xmp2"))
+    gathers = [c for c in collectives if c.kind == "all-gather"]
+    wide = [c for c in gathers if c.result_bytes >= 1 << 20 and any(dt == "f32" for dt, _ in c.result_shapes)]
+    assert not wide, [c.describe() for c in wide]
+    sdp = [c for c in collectives if c.groups == _SDP_GROUPS]
+    # (two 6-KB gathers of bias moments over 'mp' sit under ``optimizer`` here as in the parent; they are not ZeRO's)
+    assert not [c.describe() for c in gathers if "optimizer" in c.op_name.split("/") and (c in sdp or c.result_bytes >= 1 << 20)]
+    params = [c for c in sdp if c.kind == "all-gather"]
+    assert all("amp_cast" in c.op_name.split("/") for c in params), [c.op_name for c in params]
+    assert sum(hlo.moved_bytes(c) for c in params) <= 0.55 * _PARENT_SDP_ALL_GATHER_BYTES
+    assert sum(hlo.moved_bytes(c) for c in gathers) <= 0.6 * _PARENT_ALL_GATHER_BYTES
+    # pinned as this PR leaves them: (ops, bytes moved a chip a step) by kind and element type over the 'sdp' groups.
+    # reduce-scatter: the table's gradient (25.8 MB), ffn1's, ffn2's, and qkv's with the output projection's (2.1 MB
+    # each); all-to-all: the embedding rows' gradient re-laid from batch- to column-sharded for a local scatter-add
+    by_type, ops = hlo.moved_bytes_by_type(sdp), {}
+    for c in sdp:
+        ops[c.kind] = ops.get(c.kind, 0) + 1
+    assert (ops, by_type) == (
+        {"reduce-scatter": 4, "all-gather": 6, "all-reduce": 2, "all-to-all": 1},
+        {"reduce-scatter bf16": 32_047_104, "all-gather bf16": 33_095_680, "all-reduce bf16": 2_120_708,
+         "all-to-all bf16": 4_194_304})
+    matrices = [dims for c in sdp if c.kind == "all-reduce" for _, dims in c.result_shapes if len(dims) >= 2 and min(dims) >= 512]
+    assert matrices == [(1024, 1024)]     # the position table's gradient, a reduce_sum and no dot: the one weight still all-reduced
 
 
 _TRAIN, _TRAIN4 = "gpt2-medium.train", "cerebras-gpt-1.3b.train-zero2mp2"

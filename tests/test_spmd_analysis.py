@@ -243,6 +243,67 @@ def test_trainstep_explain_analyze_attaches_verdict():
     s = rows[0]["spmd"]
     assert s["fingerprint"] and s["collective_count"] >= 1
     assert s["diagnostics"]["error"] == 0
+    # what crosses the wire, per kind and element type: one line of the summary, summing to the whole
+    by_type = s["moved_bytes_by_type"]
+    assert by_type and all(len(k.split()) == 2 for k in by_type)
+    assert sum(by_type.values()) == s["reshard_bytes"]
+
+
+# What the TPU compiler writes (cut from the four-chip step compiled for a described v5e:2x2): one async all-gather
+# spelled three times — in the wrapped computations of its start, of the work it overlaps and of its done — and the
+# chip's reduce-scatter as an all-reduce with the dynamic-slice of its result, fused under the name all-reduce-scatter.
+_TPU_HLO = """\
+HloModule jit__step
+
+%all-reduce-scatter (input: bf16[2048,4096]) -> bf16[1024,4096] {
+  %input = bf16[2048,4096]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.103 = bf16[2048,4096]{1,0:T(8,128)(2,1)} all-reduce(%input), channel_id=139, replica_groups={{0,2},{1,3}}, use_global_device_ids=true, to_apply=%add.34.clone
+  %partition-id.9 = u32[] partition-id()
+  ROOT %dynamic-slice.296 = bf16[1024,4096]{1,0:T(8,128)(2,1)S(1)} dynamic-slice(%all-reduce.103, %partition-id.9, %partition-id.9), dynamic_slice_sizes={1024,4096}
+}
+
+%fused_computation.882 (param_0.2717: bf16[2,2048,4096]) -> (bf16[2,2048,4096], bf16[4,2048,4096]) {
+  %param_0.2717 = bf16[2,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %all-gather.121 = bf16[4,2048,4096]{2,1,0:T(8,128)(2,1)} all-gather(%param_0.2717), channel_id=24, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true, metadata={op_name="jit(_step)/amp_cast/convert_element_type"}
+  ROOT %custom-call.19 = (bf16[2,2048,4096]{2,1,0:T(8,128)(2,1)S(1)}, bf16[4,2048,4096]{2,1,0:T(8,128)(2,1)}) custom-call(%all-gather.121), custom_call_target="AsyncCollectiveStart"
+}
+
+%async_collective_fusion.694 (param_0.2721: bf16[2,2048,4096], param_1.3267: bf16[4,2048,4096]) -> (bf16[4,2048,2048], bf16[4,2048,4096]) {
+  %param_0.2721 = bf16[2,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %all-gather.123 = bf16[4,2048,4096]{2,1,0:T(8,128)(2,1)} all-gather(%param_0.2721), channel_id=24, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true, metadata={op_name="jit(_step)/amp_cast/convert_element_type"}
+  ROOT %tuple.161 = (bf16[4,2048,2048]{2,1,0:T(8,128)(2,1)}, bf16[4,2048,4096]{2,1,0:T(8,128)(2,1)}) tuple(%param_0.2721, %all-gather.123)
+}
+
+%fused_computation.884 (param_0.2723: bf16[2,2048,4096]) -> bf16[4,2048,4096] {
+  %param_0.2723 = bf16[2,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %all-gather.125 = bf16[4,2048,4096]{2,1,0:T(8,128)(2,1)} all-gather(%param_0.2723), channel_id=24, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true, metadata={op_name="jit(_step)/amp_cast/convert_element_type"}
+  ROOT %custom-call.21 = bf16[4,2048,4096]{2,1,0:T(8,128)(2,1)} custom-call(%param_0.2723, %all-gather.125), custom_call_target="AsyncCollectiveDone"
+}
+
+ENTRY %main.1 (Arg_0.1: bf16[2048,4096]) -> bf16[1024,4096] {
+  %Arg_0.1 = bf16[2048,4096]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.12 = bf16[2048,4096]{1,0:T(8,128)(2,1)} all-reduce(%Arg_0.1), channel_id=7, replica_groups=[2,2]<=[4], use_global_device_ids=true, to_apply=%add.34.clone
+  ROOT %fusion.7 = bf16[1024,4096]{1,0:T(8,128)(2,1)S(1)} fusion(%all-reduce.12), kind=kCustom, calls=%all-reduce-scatter
+}
+"""
+
+
+def test_hlo_parser_reads_the_tpu_compilers_spellings():
+    cols = hlo_mod.parse_collectives(_TPU_HLO)
+    assert [(c.kind, c.name, c.index) for c in cols] == [
+        ("reduce-scatter", "all-reduce.103", 0), ("all-gather", "all-gather.121", 1), ("all-reduce", "all-reduce.12", 2)]
+    rs, ag, ar = cols
+    # the fused reduce-scatter: from the fusion's operand to its result, half the operand on the wire in a group of 2
+    assert rs.operand_shapes == [("bf16", (2048, 4096))] and rs.result_shapes == [("bf16", (1024, 4096))]
+    assert hlo_mod.moved_bytes(rs) == 2048 * 4096 * 2 // 2
+    # the replica groups themselves, from both spellings and through the iota's transpose: sdp against mp on a 2 x 2
+    assert rs.groups == ag.groups == ((0, 2), (1, 3)) and ar.groups == ((0, 1), (2, 3))
+    assert (ag.group_size, ag.num_groups) == (2, 2)
+    assert ag.op_name.split("/")[1] == "amp_cast"
+    assert hlo_mod.moved_bytes_by_type(cols) == {
+        "reduce-scatter bf16": 2048 * 4096, "all-gather bf16": 4 * 2048 * 4096, "all-reduce bf16": 2 * 2048 * 4096}
+    assert hlo_mod._parse_groups("replica_groups=[2,4]<=[8]") == ((0, 1, 2, 3), (4, 5, 6, 7))
+    assert hlo_mod._parse_groups("replica_groups=[4,2]<=[2,2,2]T(2,1,0)") == ((0, 4), (2, 6), (1, 5), (3, 7))
 
 
 # ----------------------------------------------------------- PTA203 decode
