@@ -30,14 +30,17 @@ request stream:
 What depends on the architecture the engine takes from the model's *decoder*
 (``model.decoder()``, the interface of :mod:`paddle_tpu.models.decoder`): the
 parameter pack, the per-slot buffers — rows of keys and values, which
-admission leaves as they are, and for a model with linear-attention layers a
-recurrent state, which the slot's first prefill program zeroes — and the
-prefill, chunk, decode and window forwards over them. GPT
-(``models/gpt.py:GPTDecoder``), Solar Open 2
-(``models/solar_open2.py:SolarOpen2Decoder``) and GigaChat 3.5
+admission leaves as they are, and for a model with linear-attention or
+state-space layers a recurrent state (a delta-rule matrix, a Mamba-2
+``ssm_state``, and the ``conv_tail`` either keeps beside it), which the slot's
+first prefill program zeroes — and the prefill, chunk, decode and window
+forwards over them. GPT (``models/gpt.py:GPTDecoder``), Solar Open 2
+(``models/solar_open2.py:SolarOpen2Decoder``), GigaChat 3.5
 (``models/gigachat3_5.py:GigaChat35Decoder``, whose cached rows are latents
-``[B, S, rank + rope]`` with no head axis) are its clients; for a model with
-recurrent state the engine refuses a prefix cache, a draft and an int8 cache.
+``[B, S, rank + rope]`` with no head axis) and Granite 4.0-H
+(``models/granite_moe_hybrid.py:GraniteMoeHybridDecoder``) are its clients;
+for a model with recurrent state the engine refuses a prefix cache, a draft
+and an int8 cache.
 
 The slot buffers (and the slot state) are donated, so what the engine
 *holds* stays flat for its life. Whether a program also updates them in
@@ -1216,8 +1219,10 @@ class DecodeEngine:
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state a slot holds beside its cache rows (the
-        buffers admission zeroes): the ``infer.state_bytes_per_slot`` gauge.
-        0 for a model whose slots hold keys and values only."""
+        buffers admission zeroes — ``state*`` and ``conv*`` of the delta-rule
+        layers, ``ssm_state*`` and ``conv_tail*`` of the Mamba-2 layers): the
+        ``infer.state_bytes_per_slot`` gauge. 0 for a model whose slots hold
+        keys and values only."""
         return self._buffer_bytes(reset=True) // self.max_batch_slots
 
     def spec_stats(self) -> dict:

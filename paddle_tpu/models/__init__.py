@@ -30,3 +30,7 @@ from .gigachat3_5 import (  # noqa: F401
     GigaChat35Config,
     GigaChat35ForCausalLM,
 )
+from .granite_moe_hybrid import (  # noqa: F401
+    GraniteMoeHybridConfig,
+    GraniteMoeHybridForCausalLM,
+)

@@ -205,12 +205,13 @@ def _layer(p: dict, prefix: str, i: int) -> dict:
     return {k: v[i] for k, v in p.items() if k.startswith(prefix)}
 
 
-def _moe(cfg: SolarOpen2Config, p: dict, layer: int, x, routed=None):
+def _moe(cfg, p: dict, layer: int, x, routed=None, scoring: str = "sigmoid"):
     """``(routed part of the held experts + shared expert, stats)`` for rows
-    ``x [T, D]``. ``routed``, a list, is handed the experts each row chose
-    (``[T, k]``): what :func:`chunk_routing` and :func:`decode_probe` report."""
+    ``x [T, D]``, the router scored by ``scoring`` (``route_topk`` has the two).
+    ``routed``, a list, is handed the experts each row chose (``[T, k]``): what
+    :func:`chunk_routing` and :func:`decode_probe` report."""
     w, idx = route_topk(x, p["router"][layer], top_k=cfg.num_experts_per_tok,
-                        norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor)
+                        norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor, scoring=scoring)
     y, stats = dropless_experts(x, w, idx, p["experts_gate_up"][layer], p["experts_down"][layer],
                                 held=cfg.held_experts, n_experts=cfg.router_experts)
     with jax.named_scope("moe_shared"):
@@ -222,19 +223,24 @@ def _moe(cfg: SolarOpen2Config, p: dict, layer: int, x, routed=None):
 
 def _gqa_project(cfg, lp, x):
     """``q [T, Hkv, G, d]``, ``k``/``v`` ``[T, Hkv, d]`` and the output gate
-    ``[T, Hq * d]`` (float32) of rows ``x [T, D]``."""
+    ``[T, Hq * d]`` (float32; None for a layer that has no ``attn_gate``) of
+    rows ``x [T, D]``."""
     T = x.shape[0]
     Hq, Hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     with jax.named_scope("attn_qkv"):
         q = jnp.matmul(x, lp["attn_q"]).reshape(T, Hkv, Hq // Hkv, d)
         kv = jnp.matmul(x, lp["attn_kv"]).reshape(T, 2, Hkv, d)
-        gate = jax.nn.sigmoid(jnp.matmul(x, lp["attn_gate"], preferred_element_type=jnp.float32))
+        gate = None
+        if "attn_gate" in lp:
+            gate = jax.nn.sigmoid(jnp.matmul(x, lp["attn_gate"], preferred_element_type=jnp.float32))
     return q, kv[:, 0], kv[:, 1], gate
 
 
 def _gqa_out(lp, att, gate, dtype):
     with jax.named_scope("attn_out"):
-        return jnp.matmul((att.astype(jnp.float32) * gate).astype(dtype), lp["attn_out"])
+        if gate is not None:
+            att = att.astype(jnp.float32) * gate
+        return jnp.matmul(att.astype(dtype), lp["attn_out"])
 
 
 def _gqa_chunk(cfg, lp, x, ck, cv, li, slot, start):
@@ -265,7 +271,8 @@ def _gqa_chunk(cfg, lp, x, ck, cv, li, slot, start):
 def _gqa_write_attend(q, k, v, ck, cv, pos, active, li):
     """The lax write-and-attend of one GQA layer for a decode batch (where the
     ``decode_attention`` kernel declines): ``q [B, Hkv, G, d]``, ``k``/``v``
-    ``[B, Hkv, 1, d]``; the stacked cache ``[Lg, B, Hkv, S, d]``."""
+    ``[B, Hkv, 1, d]``; the stacked cache ``[Lg, B, Hkv, S, d]``. Scores times
+    ``d^-1/2``, as the kernel's."""
     B, Hkv, G, d = q.shape
     S = ck.shape[3]
 
@@ -288,10 +295,15 @@ def _gqa_write_attend(q, k, v, ck, cv, pos, active, li):
     return att.astype(q.dtype), ck, cv
 
 
-def _gqa_decode(cfg, lp, x, ck, cv, li, pos, active):
-    """The GQA mixer for one token of every slot, ``x [B, D]``."""
+def _gqa_decode(cfg, lp, x, ck, cv, li, pos, active, scale=None):
+    """The GQA mixer for one token of every slot, ``x [B, D]``. Kernel and lax
+    form alike scale the scores by ``d^-1/2``: a model with a ``scale`` of its
+    own has ``q`` times ``scale d^1/2`` first."""
     B = x.shape[0]
     q, k, v, gate = _gqa_project(cfg, lp, x)
+    if scale is not None:
+        with jax.named_scope("attn_qkv"):
+            q = q * jnp.asarray(scale * math.sqrt(cfg.head_dim), q.dtype)
     impl = _registry.select("decode_attention", ck, packed=False, window=1)
     if impl.fallback:
         att, ck, cv = _gqa_write_attend(q, k[:, :, None], v[:, :, None], ck, cv, pos, active, li)
@@ -395,7 +407,8 @@ def _admitting(start):
     return start == 0
 
 
-def _layers(cfg: SolarOpen2Config, p: dict, cache, ids, gqa, linear, routed=None):
+def _layers(cfg, p: dict, cache, ids, gqa, linear, routed=None, *, moe=_moe, embed_scale=None, residual_scale=None,
+            stream_dtype=None):
     """Both forwards' walk from token ids ``[T]`` to hidden rows ``[T, D]``: the
     embedding, then ``norm -> mixer -> residual -> norm -> experts -> residual``
     a layer. ``cache`` is the engine's flat tuple of buffers: ``k``, ``v``, a
@@ -403,28 +416,45 @@ def _layers(cfg: SolarOpen2Config, p: dict, cache, ids, gqa, linear, routed=None
     ``gqa(gi, x, ck, cv) -> (y, ck, cv)`` and ``linear(li, x, state, tail) ->
     (y, state, tail)``, are told which GQA or linear layer this is and handed
     its buffers whole. Returns ``(h, cache, stats int32[2])`` with the routed
-    experts' load summed over the layers."""
+    experts' load summed over the layers.
+
+    A family that shares the walk (``models/granite_moe_hybrid.py``) names its
+    own expert layer ``moe`` and, where it has them, a factor on the embedding
+    and one on every sub-layer's output before the residual sum, and a type
+    the residual stream is held in beside the model's (a sub-layer's input is
+    cast back to the model's)."""
     n = len(cfg.linear_layers)
     ck, cv, states, tails = cache[0], cache[1], list(cache[2:2 + n]), list(cache[2 + n:2 + 2 * n])
     with jax.named_scope("embed"):
         h = jnp.take(p["embed"], ids, axis=0)
+        dtype = h.dtype
+        if stream_dtype is not None:
+            h = h.astype(stream_dtype)
+        if embed_scale is not None:
+            h = h * embed_scale
+
+    def normed(h, name, layer):
+        with jax.named_scope("norm"):
+            return _rms_norm(h, p[name][layer], cfg.rms_norm_eps).astype(dtype)
+
+    def added(h, y):
+        return h + (y if residual_scale is None else residual_scale * y.astype(h.dtype))
+
     gi = li = 0
     stats = jnp.zeros((2,), jnp.int32)
     for layer in range(cfg.num_hidden_layers):  # noqa: PTA104 (static unroll, host loop bound)
-        with jax.named_scope("norm"):
-            x = _rms_norm(h, p["norm1"][layer], cfg.rms_norm_eps)
+        x = normed(h, "norm1", layer)
         if layer in cfg.gqa_layers:
             y, ck, cv = gqa(gi, x, ck, cv)
             gi += 1
         else:
             y, states[li], tails[li] = linear(li, x, states[li], tails[li])  # noqa: PTA104 (static unroll, host loop bound)
             li += 1
-        h = h + y
-        with jax.named_scope("norm"):
-            x = _rms_norm(h, p["norm2"][layer], cfg.rms_norm_eps)
-        y, s = _moe(cfg, p, layer, x, routed)
+        h = added(h, y)
+        x = normed(h, "norm2", layer)
+        y, s = moe(cfg, p, layer, x, routed)
         stats = stats + s
-        h = h + y
+        h = added(h, y)
     return h, (ck, cv, *states, *tails), stats
 
 

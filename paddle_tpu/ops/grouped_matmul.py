@@ -64,7 +64,8 @@ def plan(M: int, K: int, N: int, expected_run: float, dtype):
     sublane tile of the dtype (16 rows in bf16) to 128. Up to there the
     MXU's time on a tile stays under the time its weight block takes to
     arrive, so masked rows cost nothing and fewer, longer tiles mean fewer
-    grid steps; XLA's 512 are past it (PERF.md §6, PR 31, has the sweep).
+    grid steps; XLA's 512 are past it (PERF.md §6, PR 31, has the sweep);
+    halved from there until it divides ``M``, down to the sublane tile.
     ``tk``: the whole contraction where a lane tile's width of it fits
     ``_BLOCK_BYTES``, so that a run which straddles a row-tile edge visits its
     weight block twice and fetches it once (a block is fetched again only
@@ -73,6 +74,8 @@ def plan(M: int, K: int, N: int, expected_run: float, dtype):
     tm = _sublanes(dtype)
     while tm < min(8 * expected_run, _ROW_TILE_MAX):
         tm *= 2
+    while tm > _sublanes(dtype) and M % tm:     # 400 pairs (40 tokens x 10) are no multiple of 64: 16 divides them
+        tm //= 2
     item = jnp.dtype(dtype).itemsize
     tk = _largest_divisor(K, _LANES, _BLOCK_BYTES // (_LANES * item))
     if M % tm or tk is None:

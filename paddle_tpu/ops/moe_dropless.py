@@ -1,8 +1,10 @@
 """Dropless top-k routing over gated experts, for a layer that is *told which
 experts it holds*.
 
-The router scores every expert of the model (``sigmoid`` scores, the ``k``
-largest, weights normalised over the chosen); this layer computes the part of
+The router scores every expert of the model and keeps the ``k`` largest — by
+``sigmoid`` scores, the weights normalised over the chosen, or by logits, the
+weights a softmax over the chosen ``k`` (:func:`route_topk` has both
+scorings); this layer computes the part of
 the result that its own experts ``[first, first + count)`` give, for the
 (token, expert) pairs routed to them, and nothing for the others: on one chip
 of an expert-parallel group that is the chip's partial result, and no exchange
@@ -33,15 +35,24 @@ from . import grouped_matmul as _grouped
 __all__ = ["route_topk", "dropless_experts", "gated_ffn"]
 
 
-def route_topk(x, router_w, *, top_k: int, norm_topk: bool = True, scale: float = 1.0):
+def route_topk(x, router_w, *, top_k: int, norm_topk: bool = True, scale: float = 1.0, scoring: str = "sigmoid"):
     """``(weights [T, k] float32, experts [T, k] int32)`` over all of the
-    router's outputs: sigmoid scores in float32, the ``k`` largest, their
-    weights normalised over the chosen and scaled."""
+    router's outputs, scored in float32 one of two ways. ``"sigmoid"``: the
+    ``k`` largest sigmoid scores, their weights normalised over the chosen
+    (``norm_topk``). ``"softmax_topk"``: the ``k`` largest logits, their
+    weights a softmax over those ``k`` logits (which sum to one: ``norm_topk``
+    says nothing there). Either way times ``scale``."""
+    if scoring not in ("sigmoid", "softmax_topk"):
+        raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax_topk'")
     with jax.named_scope("moe_router"):
-        scores = jax.nn.sigmoid(jnp.matmul(x, router_w, preferred_element_type=jnp.float32))
-        w, idx = jax.lax.top_k(scores, int(top_k))
-        if norm_topk:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        logits = jnp.matmul(x, router_w, preferred_element_type=jnp.float32)
+        if scoring == "softmax_topk":
+            top, idx = jax.lax.top_k(logits, int(top_k))
+            w = jax.nn.softmax(top, axis=-1)
+        else:
+            w, idx = jax.lax.top_k(jax.nn.sigmoid(logits), int(top_k))
+            if norm_topk:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
         return w * scale, idx.astype(jnp.int32)
 
 

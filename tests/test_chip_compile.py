@@ -270,6 +270,13 @@ _PARENT_PROGRAMS = {
         "chunk_core": ("db726409c4a62105", 3398),
         "chunk_final_core": ("2ed8e86a3afcb6a9", 3889),
     },
+    # PR 36, the cell's first programs: what a later refactor of ``models/granite_moe_hybrid.py``, of what it imports
+    # from ``models/solar_open2.py`` or of ``ops/ssd.py`` has to leave as it is
+    "granite-4.0-h-small.serve-rag": {
+        "decode_fn": ("abb8184198c4473d", 4461),
+        "chunk_core": ("15b82d8de732f403", 5278),
+        "chunk_final_core": ("b40b91cb20d6aace", 5664),
+    },
     "gpt2-medium.train": {
         "_step": ("af707415b346d11f", 1303),
     },
@@ -301,6 +308,11 @@ _PARENT_SCOPES = {
         "chunk_core": {"unscoped": 1156, "embed": 1, "norm": 459, "linear_proj": 99, "linear_core": 752, "linear_out": 93, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 51, "moe_routed": 435, "moe_shared": 45},
         "chunk_final_core": {"unscoped": 1279, "embed": 1, "norm": 565, "linear_proj": 100, "linear_core": 796, "linear_out": 124, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 68, "moe_routed": 580, "moe_shared": 60, "head_loss": 2},
     },
+    "granite-4.0-h-small.serve-rag": {
+        "decode_fn": {"unscoped": 1168, "embed": 4, "norm": 376, "ssm_proj": 54, "ssm_conv": 405, "ssm_core": 369, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1530, "moe_shared": 120, "attn_qkv": 6, "attn_core": 2, "attn_out": 1, "head_loss": 4},
+        "chunk_core": {"unscoped": 1706, "embed": 4, "norm": 342, "ssm_proj": 54, "ssm_conv": 412, "ssm_core": 826, "ssm_norm": 152, "ssm_out": 8, "moe_router": 153, "moe_routed": 1278, "moe_shared": 108, "attn_qkv": 4, "cache_write": 18, "attn_core": 99, "attn_out": 2},
+        "chunk_final_core": {"unscoped": 1808, "embed": 4, "norm": 375, "ssm_proj": 54, "ssm_conv": 414, "ssm_core": 873, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1420, "moe_shared": 120, "attn_qkv": 4, "cache_write": 18, "attn_core": 99, "attn_out": 2, "head_loss": 4},
+    },
     "gpt2-medium.train": {
         "_step": {"unscoped": 370, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 37, "head_loss": 47, "optimizer": 439},
     },
@@ -321,7 +333,7 @@ def _fingerprint(lowered_text):
 # Every ``jax.named_scope`` a by-part metric reads (PERF.md §3; ``benchmark/families/*.py:PART_OF_SCOPE``).
 _SCOPES = {"norm", "attn_qkv", "attn_core", "attn_out", "mlp", "embed", "head_loss", "cache_write", "cache_read",
            "optimizer", "amp_cast", "linear_proj", "linear_core", "linear_out", "moe_router", "moe_routed", "moe_shared",
-           "mla_q", "mla_kv", "rope", "mla_core", "mla_out"}
+           "mla_q", "mla_kv", "rope", "mla_core", "mla_out", "ssm_proj", "ssm_conv", "ssm_core", "ssm_norm", "ssm_out"}
 _LOC_NAME = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
 _LOC_USE = re.compile(r" loc\((#loc\d*)\)$", re.M)
 
@@ -517,6 +529,56 @@ def test_gigachat3_5_programs_compile_for_v5e_at_the_cells_shapes(as_tpu, one_ch
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory.temp_size_in_bytes
 
 
+_GRANITE = "granite-4.0-h-small.serve-rag"
+
+
+def _granite_lowered(one_chip):
+    """The Granite cell's programs: 40 slots x 8,192, chunk 1,024."""
+    return _share_lowered(_GRANITE, "granite_moe_hybrid", "GraniteMoeHybrid", "granite-4.0-h-small.json", one_chip, 40, 8192)
+
+
+@pytest.mark.parametrize("program", ["decode_fn", "chunk_core", "chunk_final_core"])
+def test_granite_moe_hybrid_programs_compile_for_v5e_at_the_cells_shapes(as_tpu, one_chip, program):
+    """The programs of ``granite-4.0-h-small.serve-rag`` (40 slots x 8,192, chunk 1,024, one chip's share at the published
+    widths, bf16, abstract arguments) compile for the described chip: the attention layer's decode through ``decode_attn``
+    by group (8 key/value heads a slot, 4 query heads each); two grouped matmuls an expert layer through
+    ``ops/grouped_matmul.py`` at D 4,096 and width 768 (400 pairs over a router of 72 in the decode program, 10,240 in a
+    chunk: one selection a program's shapes, none through XLA's); the state-space scan and step through the registry
+    (one implementation each, ``lax``: ``picked`` counts the shapes); every slot buffer updated in place; and the chunk
+    programs' temporaries beside the 12.4 GB held inside the chip's 16 GB."""
+    cfg, decoder, lowered = _granite_lowered(one_chip)
+    B, S = 40, 8192
+    lowered, picked = lowered[program]
+    compiled = lowered.compile()
+    # one selection a kernel a set of shapes: the final chunk's are the chunk's, selected when that was lowered
+    once = 0 if program == "chunk_final_core" else 1
+    new = "ssd_step" if program == "decode_fn" else "ssd_chunked"
+    want = {"kernels.grouped_matmul.picked": once, f"kernels.{new}.picked": once}
+    if program == "decode_fn":
+        want["kernels.decode_attention.picked"] = 1
+    assert {k: v for k, v in picked.items() if v} == {k: v for k, v in want.items() if v}
+    calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+    layers = cfg.num_hidden_layers - (program == "chunk_core")      # an intermediate chunk's last expert layer feeds nothing
+    grouped = [line for line in calls if "%moe_grouped_" in line]
+    assert len(grouped) == 2 * layers and not any("ragged-dot" in line for line in calls)
+    assert all("/moe_routed/" in line for line in grouped)
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
+    print(f"{_GRANITE} {program}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB; {picked}; "
+          f"tiles {sorted({line.split('%moe_grouped_')[1].split('.')[0].split(' ')[0] for line in grouped})}")
+    assert held == 40 * 71_759_360
+    # 9.51 GB of weights + 2.87 GB of slots; an intermediate chunk takes neither the last layer's experts nor the final norm
+    assert (11.6e9 if program == "chunk_core" else 12.3e9) < memory.argument_size_in_bytes < 12.5e9
+    assert memory.alias_size_in_bytes >= held
+    if program == "decode_fn":
+        attn = [line for line in calls if "decode_attn" in line]
+        assert len(attn) == len(cfg.gqa_layers) and all("/attn_core/" in line for line in attn)
+        assert memory.temp_size_in_bytes < 0.3e9, memory.temp_size_in_bytes
+    else:
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory.temp_size_in_bytes
+
+
 def test_engine_imports_no_private_function_of_a_model():
     import ast
     import inspect
@@ -651,14 +713,14 @@ def test_zero2_step_gathers_the_cast_and_scatters_the_gradients_on_v5e(as_tpu, t
 
 _TRAIN, _TRAIN4 = "gpt2-medium.train", "cerebras-gpt-1.3b.train-zero2mp2"
 _PROGRAMS = ([(cell, name) for cell in sorted(_DECODE) for name in ("decode_fn", "chunk_core", "chunk_final_core", "prefill_core")]
-             + [(cell, name) for cell in (_SOLAR, _GIGA) for name in ("decode_fn", "chunk_core", "chunk_final_core")]
+             + [(cell, name) for cell in (_SOLAR, _GIGA, _GRANITE) for name in ("decode_fn", "chunk_core", "chunk_final_core")]
              + [(_TRAIN, "_step"), (_TRAIN4, "_step")])
 
 
 @pytest.mark.parametrize("cell,program", _PROGRAMS, ids=[f"{c}-{n}" for c, n in _PROGRAMS])
 def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_chip, cell, program):
     """Each cell's program lowers to the text it lowered to at 094436e (the GigaChat cell's: at PR 34, which brought
-    them), and the operations under each
+    them; the Granite cell's: at PR 36), and the operations under each
     ``jax.named_scope`` the by-part metrics read are as many as they were: the hash does not see a scope's name, the
     metrics see nothing else. The four-chip cell's step is ``test_distributed_step``'s ``sharding2xmp2`` layout, the
     one-chip train cell's ``test_train_step``'s."""
@@ -668,6 +730,8 @@ def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_ch
         lowered = _solar_lowered(one_chip)[2][program][0]
     elif cell == _GIGA:
         lowered = _giga_lowered(one_chip)[2][program][0]
+    elif cell == _GRANITE:
+        lowered = _granite_lowered(one_chip)[2][program][0]
     elif cell == _TRAIN:
         lowered = _train_lowered(one_chip)
     else:
