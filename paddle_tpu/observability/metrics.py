@@ -261,6 +261,7 @@ KERNEL_COUNTERS: Tuple[str, ...] = (
     "kernels.moe.picked", "kernels.moe.fallback",
     "kernels.decode_attention.picked", "kernels.decode_attention.fallback",
     "kernels.grouped_matmul.picked", "kernels.grouped_matmul.fallback",
+    "kernels.moe_combine.picked", "kernels.moe_combine.fallback",
 )
 
 # SPMD sharding analyzer (paddle_tpu.analysis.spmd, FLAGS_shard_check):

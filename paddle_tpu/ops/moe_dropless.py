@@ -23,7 +23,12 @@ pairs over the router's width); everywhere else ``lax.ragged_dot``, which XLA
 compiles for the TPU to a kernel of the same visiting order with 512-row
 tiles (PERF.md §6, PR 30 and PR 31, have why neither is ``ops/moe_pallas.py``,
 whose kernel pads every expert's run to a capacity). The pairs go back to
-token order by a gather and a weighted sum over each token's ``k``.
+token order through the ``moe_combine`` registry entry
+(``ops/moe_combine.py``): on the TPU, for a float32 result with no mesh, a
+kernel that reads the held pairs' rows once and adds each, weighted, into its
+token's row; everywhere else a slot-major gather and a sum over the major
+axis. Rows of no run are left out by selection (they are unspecified), and
+``[T, k, D]`` is never formed: ``k = 10`` pads to 16 sublanes there.
 """
 from __future__ import annotations
 
@@ -31,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from . import grouped_matmul as _grouped
+from .moe_combine import combine
 
 __all__ = ["route_topk", "dropless_experts", "gated_ffn"]
 
@@ -81,7 +87,10 @@ def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held, n_experts=
     weights; ``held = (first, count)``; ``n_experts`` the router's width
     (``count`` if not given: the layer holds them all), from which the
     grouped matmul takes the run it should expect; ``limit`` clamps the gated
-    product's two factors (:func:`_gated`). Returns ``(y [T, D]
+    product's two factors (:func:`_gated`). The sorted rows go back to token
+    order as ``sum_j weights[t, j] * row(t, j)`` in float32 over the held
+    pairs of each token, the others' rows selected out, never multiplied
+    (``ops/moe_combine.py``). Returns ``(y [T, D]
     float32, stats int32[2])`` with ``stats = (pairs routed to a held expert,
     held experts with at least one pair)``."""
     first, count = int(held[0]), int(held[1])
@@ -100,10 +109,8 @@ def dropless_experts(x, weights, experts, w_gate_up, w_down, *, held, n_experts=
         f = w_down.shape[-2]
         a = _gated(h, f, limit).astype(x.dtype)
         y = matmul(a, w_down, sizes, expected_run=expected_run)
-        # back to token order: pair p sits at row inverse[p]; rows past the
-        # held pairs belong to no run, so whatever they hold is masked out
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
-        y = jnp.take(y, inverse, axis=0).reshape(T, k, -1)
-        y = jnp.sum(jnp.where(mine[..., None], y * weights[..., None], 0.0), axis=1)
+        # back to token order: row r holds pair order[r], the held pairs' rows first; the rows past them belong to
+        # no run, so whatever they hold is left out
+        y = combine(y, order, mine, weights)
         stats = jnp.stack([jnp.sum(mine.astype(jnp.int32)), jnp.sum((sizes > 0).astype(jnp.int32))])
     return y, stats

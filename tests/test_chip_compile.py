@@ -185,6 +185,24 @@ def _cache_shaped_ops(hlo_text, L, B, H, S, dh):
     return found
 
 
+def _custom_calls(hlo_text, name=""):
+    """The ``custom-call`` instructions whose own name starts with ``name`` (one that takes such a call's result is
+    not one)."""
+    return [line for line in hlo_text.splitlines()
+            if "custom-call(" in line and line.split(" = ")[0].split()[-1].startswith("%" + name)]
+
+
+def _ops_of_result(hlo_text, dtype, dims):
+    """``{opcode: count}`` of the instructions one of whose results is ``dtype[dims]`` (fused computations'
+    instructions included: a value inside a fusion is still a value the chip forms)."""
+    want, found = f"{dtype}[{','.join(str(d) for d in dims)}]", {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is not None and want in m.group(1):
+            found[m.group(2)] = found.get(m.group(2), 0) + 1
+    return found
+
+
 @pytest.mark.parametrize("cell", sorted(_DECODE))
 def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
     """The decode forward (``_slot_decode_forward``, the body of the engine's
@@ -245,6 +263,15 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
 # gains, under ``amp_cast``, a sharding constraint on each of the six split parameters' bf16 cast (the gather) and one on
 # each of their gradients (the scatter to the owner) — ``amp_cast`` 32 -> 44, twelve lines; their casts moved out of
 # the differentiated function with them. No other scope's count moved; ``gpt2-medium.train`` has no mesh and is as it was.
+# PR 37 re-pinned the nine programs of Solar's, GigaChat's and Granite's cells and nothing else: the routed experts' way
+# back to token order (``ops/moe_dropless.py``) is one ``moe_combine`` call a layer (``ops/moe_combine.py``) where it was
+# a scatter for the inverse order, a gather of the float32 ``[T k, D]`` result, a reshape to ``[T, k, D]`` and a masked
+# weighted sum over ``k``. ``moe_routed`` falls by 13 to 15 operations an expert layer (Granite 1530 -> 1400, 1278 ->
+# 1161, 1420 -> 1290; Solar 612 -> 560, 426 -> 387, 568 -> 516; GigaChat 624 -> 572, 435 -> 396, 580 -> 528) and
+# ``unscoped`` by the bodies of the ``_take`` and ``_where`` helpers that ``jax.numpy`` outlines once a program, which
+# carry no scope path and which nothing calls at those shapes any more (Granite 1168 -> 1097, 1706 -> 1639, 1808 ->
+# 1737; Solar 610 -> 563, 896 -> 853, 1011 -> 964; GigaChat 752 -> 705, 1156 -> 1113, 1279 -> 1232). No other scope's
+# count moved; the GPT serving programs and both training steps are byte for byte what they were.
 _PARENT_PROGRAMS = {
     "cerebras-gpt-1.3b.serve-longgen": {
         "decode_fn": ("c1b2b9e757190693", 3277),
@@ -259,23 +286,24 @@ _PARENT_PROGRAMS = {
         "prefill_core": ("8bdd3a355668f227", 4575),
     },
     "solar-open2-250b.serve-reasoning": {
-        "decode_fn": ("f442acc65555ca7d", 2030),
-        "chunk_core": ("b34ae03bffc9098e", 2374),
-        "chunk_final_core": ("6151ba245647615e", 2777),
+        "decode_fn": ("3aa7e33f2c334eb3", 1921),
+        "chunk_core": ("e10abb061a4a6ef9", 2284),
+        "chunk_final_core": ("c5672ff9be3f51ee", 2668),
     },
-    # PR 34, the cell's first programs: what a later refactor of ``models/gigachat3_5.py`` or of the ops it shares with
-    # Solar's (``ops/delta_rule.py``, ``ops/moe_dropless.py``) has to leave as it is
+    # PR 34, the cell's first programs (PR 37: the experts' way back): what a later refactor of ``models/gigachat3_5.py``
+    # or of the ops it shares with Solar's (``ops/delta_rule.py``, ``ops/moe_dropless.py``) has to leave as it is
     "gigachat3.5-432b-a28b.serve-longdoc": {
-        "decode_fn": ("968730bb3bdb7bbc", 2897),
-        "chunk_core": ("db726409c4a62105", 3398),
-        "chunk_final_core": ("2ed8e86a3afcb6a9", 3889),
+        "decode_fn": ("e5b34fb18caa72c6", 2788),
+        "chunk_core": ("ce38f98d9969d2f1", 3308),
+        "chunk_final_core": ("d4d9aa9abef9299c", 3780),
     },
-    # PR 36, the cell's first programs: what a later refactor of ``models/granite_moe_hybrid.py``, of what it imports
-    # from ``models/solar_open2.py`` or of ``ops/ssd.py`` has to leave as it is
+    # PR 36, the cell's first programs (PR 37: the experts' way back): what a later refactor of
+    # ``models/granite_moe_hybrid.py``, of what it imports from ``models/solar_open2.py`` or of ``ops/ssd.py`` has to
+    # leave as it is
     "granite-4.0-h-small.serve-rag": {
-        "decode_fn": ("abb8184198c4473d", 4461),
-        "chunk_core": ("15b82d8de732f403", 5278),
-        "chunk_final_core": ("b40b91cb20d6aace", 5664),
+        "decode_fn": ("e2259e07a63ac9ae", 4238),
+        "chunk_core": ("4ee46cefbf68329d", 5074),
+        "chunk_final_core": ("3e199cca4ffe3ad1", 5441),
     },
     "gpt2-medium.train": {
         "_step": ("af707415b346d11f", 1303),
@@ -299,19 +327,19 @@ _PARENT_SCOPES = {
         "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
     },
     "solar-open2-250b.serve-reasoning": {
-        "decode_fn": {"unscoped": 610, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 612, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
-        "chunk_core": {"unscoped": 896, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 426, "moe_shared": 36, "linear_proj": 92, "linear_core": 553, "linear_out": 38},
-        "chunk_final_core": {"unscoped": 1011, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 568, "moe_shared": 48, "linear_proj": 102, "linear_core": 597, "linear_out": 57, "head_loss": 2},
+        "decode_fn": {"unscoped": 563, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 560, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
+        "chunk_core": {"unscoped": 853, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 387, "moe_shared": 36, "linear_proj": 92, "linear_core": 553, "linear_out": 38},
+        "chunk_final_core": {"unscoped": 964, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 516, "moe_shared": 48, "linear_proj": 102, "linear_core": 597, "linear_out": 57, "head_loss": 2},
     },
     "gigachat3.5-432b-a28b.serve-longdoc": {
-        "decode_fn": {"unscoped": 752, "embed": 1, "norm": 566, "linear_proj": 100, "linear_core": 404, "linear_out": 124, "mlp": 14, "mla_q": 35, "mla_kv": 27, "rope": 46, "mla_core": 3, "mla_out": 17, "moe_router": 68, "moe_routed": 624, "moe_shared": 60, "head_loss": 2},
-        "chunk_core": {"unscoped": 1156, "embed": 1, "norm": 459, "linear_proj": 99, "linear_core": 752, "linear_out": 93, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 51, "moe_routed": 435, "moe_shared": 45},
-        "chunk_final_core": {"unscoped": 1279, "embed": 1, "norm": 565, "linear_proj": 100, "linear_core": 796, "linear_out": 124, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 68, "moe_routed": 580, "moe_shared": 60, "head_loss": 2},
+        "decode_fn": {"unscoped": 705, "embed": 1, "norm": 566, "linear_proj": 100, "linear_core": 404, "linear_out": 124, "mlp": 14, "mla_q": 35, "mla_kv": 27, "rope": 46, "mla_core": 3, "mla_out": 17, "moe_router": 68, "moe_routed": 572, "moe_shared": 60, "head_loss": 2},
+        "chunk_core": {"unscoped": 1113, "embed": 1, "norm": 459, "linear_proj": 99, "linear_core": 752, "linear_out": 93, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 51, "moe_routed": 396, "moe_shared": 45},
+        "chunk_final_core": {"unscoped": 1232, "embed": 1, "norm": 565, "linear_proj": 100, "linear_core": 796, "linear_out": 124, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 68, "moe_routed": 528, "moe_shared": 60, "head_loss": 2},
     },
     "granite-4.0-h-small.serve-rag": {
-        "decode_fn": {"unscoped": 1168, "embed": 4, "norm": 376, "ssm_proj": 54, "ssm_conv": 405, "ssm_core": 369, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1530, "moe_shared": 120, "attn_qkv": 6, "attn_core": 2, "attn_out": 1, "head_loss": 4},
-        "chunk_core": {"unscoped": 1706, "embed": 4, "norm": 342, "ssm_proj": 54, "ssm_conv": 412, "ssm_core": 826, "ssm_norm": 152, "ssm_out": 8, "moe_router": 153, "moe_routed": 1278, "moe_shared": 108, "attn_qkv": 4, "cache_write": 18, "attn_core": 99, "attn_out": 2},
-        "chunk_final_core": {"unscoped": 1808, "embed": 4, "norm": 375, "ssm_proj": 54, "ssm_conv": 414, "ssm_core": 873, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1420, "moe_shared": 120, "attn_qkv": 4, "cache_write": 18, "attn_core": 99, "attn_out": 2, "head_loss": 4},
+        "decode_fn": {"unscoped": 1097, "embed": 4, "norm": 376, "ssm_proj": 54, "ssm_conv": 405, "ssm_core": 369, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1400, "moe_shared": 120, "attn_qkv": 6, "attn_core": 2, "attn_out": 1, "head_loss": 4},
+        "chunk_core": {"unscoped": 1639, "embed": 4, "norm": 342, "ssm_proj": 54, "ssm_conv": 412, "ssm_core": 826, "ssm_norm": 152, "ssm_out": 8, "moe_router": 153, "moe_routed": 1161, "moe_shared": 108, "attn_qkv": 4, "cache_write": 18, "attn_core": 99, "attn_out": 2},
+        "chunk_final_core": {"unscoped": 1737, "embed": 4, "norm": 375, "ssm_proj": 54, "ssm_conv": 414, "ssm_core": 873, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1290, "moe_shared": 120, "attn_qkv": 4, "cache_write": 18, "attn_core": 99, "attn_out": 2, "head_loss": 4},
     },
     "gpt2-medium.train": {
         "_step": {"unscoped": 370, "amp_cast": 32, "embed": 31, "norm": 210, "attn_qkv": 22, "attn_out": 21, "mlp": 73, "attn_core": 37, "head_loss": 47, "optimizer": 439},
@@ -462,14 +490,17 @@ def test_solar_open2_decode_program_compiles_for_v5e_at_the_cells_shapes(as_tpu,
     compiled = lowered.compile()
     if program == "decode_fn":
         assert picked["kernels.decode_attention.picked"] == 1
-    assert {k: v for k, v in picked.items() if "grouped_matmul" in k} == {
-        "kernels.grouped_matmul.picked": 1, "kernels.grouped_matmul.fallback": 0}
-    calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+    assert {k: v for k, v in picked.items() if "grouped_matmul" in k or "moe_combine" in k} == {
+        "kernels.grouped_matmul.picked": 1, "kernels.grouped_matmul.fallback": 0,
+        "kernels.moe_combine.picked": 1, "kernels.moe_combine.fallback": 0}
+    text = compiled.as_text()
+    calls = _custom_calls(text)
     # an intermediate chunk returns the buffers only, so the last layer's experts, which feed none, are not compiled
     tm, layers = (32, cfg.num_hidden_layers) if program == "decode_fn" else (128, cfg.num_hidden_layers - 1)
-    assert sum(f"%moe_grouped_{tm}" in line for line in calls) == 2 * layers
+    assert len(_custom_calls(text, f"moe_grouped_{tm}")) == 2 * layers
+    assert len(_custom_calls(text, "moe_combine")) == layers        # the way back to token order: one call a layer (PR 37)
     assert not any("ragged-dot" in line for line in calls)
-    routed = [line for line in calls if "moe_grouped" in line]
+    routed = _custom_calls(text, "moe_")
     assert all("/moe_routed/" in line for line in routed), routed[0]      # the scope the by-part readers take it by
     memory = compiled.memory_analysis()
     held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
@@ -505,14 +536,15 @@ def test_gigachat3_5_programs_compile_for_v5e_at_the_cells_shapes(as_tpu, one_ch
     once = 0 if program == "chunk_final_core" else 1
     new = "mla_decode" if program == "decode_fn" else "mla_prefill"
     assert {k: v for k, v in picked.items() if v} == {k: v for k, v in {
-        "kernels.grouped_matmul.picked": once, f"kernels.{new}.picked": once,
+        "kernels.grouped_matmul.picked": once, "kernels.moe_combine.picked": once, f"kernels.{new}.picked": once,
         "kernels.rope.picked": 2 * once}.items() if v}                                       # the queries' shape and the key's
-    calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+    text = compiled.as_text()
+    calls = _custom_calls(text)
     experts = len(cfg.expert_layers) - (program == "chunk_core")     # an intermediate chunk's last expert layer feeds nothing
     tm = 16 if program == "decode_fn" else 128
-    assert sum(f"%moe_grouped_{tm}" in line for line in calls) == 2 * experts
+    assert len(_custom_calls(text, f"moe_grouped_{tm}")) == 2 * experts and len(_custom_calls(text, "moe_combine")) == experts
     assert not any("ragged-dot" in line for line in calls)
-    assert all("/moe_routed/" in line for line in calls if "moe_grouped" in line)
+    assert all("/moe_routed/" in line for line in _custom_calls(text, "moe_"))
     memory = compiled.memory_analysis()
     held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
     print(f"{_GIGA} {program}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, aliased "
@@ -553,15 +585,24 @@ def test_granite_moe_hybrid_programs_compile_for_v5e_at_the_cells_shapes(as_tpu,
     # one selection a kernel a set of shapes: the final chunk's are the chunk's, selected when that was lowered
     once = 0 if program == "chunk_final_core" else 1
     new = "ssd_step" if program == "decode_fn" else "ssd_chunked"
-    want = {"kernels.grouped_matmul.picked": once, f"kernels.{new}.picked": once}
+    want = {"kernels.grouped_matmul.picked": once, "kernels.moe_combine.picked": once, f"kernels.{new}.picked": once}
     if program == "decode_fn":
         want["kernels.decode_attention.picked"] = 1
     assert {k: v for k, v in picked.items() if v} == {k: v for k, v in want.items() if v}
-    calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+    text = compiled.as_text()
+    calls = _custom_calls(text)
     layers = cfg.num_hidden_layers - (program == "chunk_core")      # an intermediate chunk's last expert layer feeds nothing
-    grouped = [line for line in calls if "%moe_grouped_" in line]
+    grouped = _custom_calls(text, "moe_grouped_")
     assert len(grouped) == 2 * layers and not any("ragged-dot" in line for line in calls)
-    assert all("/moe_routed/" in line for line in grouped)
+    assert len(_custom_calls(text, "moe_combine")) == layers
+    assert all("/moe_routed/" in line for line in _custom_calls(text, "moe_"))
+    if program != "decode_fn":
+        # the way back to token order (PR 37): the second grouped matmul's float32 [T k, D] result is read by the one
+        # ``moe_combine`` call and by nothing else, and [T, k, D] — ten sublanes padded to sixteen, a 268-MB relayout
+        # and a reduction across sublanes — is never formed, inside a fusion or out
+        assert _ops_of_result(text, "f32", (1024, 10, 4096)) == {}
+        assert "tensor<1024x10x4096xf32>" not in lowered.as_text()
+        assert _ops_of_result(text, "f32", (10240, 4096)) == {"custom-call": layers}
     memory = compiled.memory_analysis()
     held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
     print(f"{_GRANITE} {program}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, aliased "
@@ -719,8 +760,8 @@ _PROGRAMS = ([(cell, name) for cell in sorted(_DECODE) for name in ("decode_fn",
 
 @pytest.mark.parametrize("cell,program", _PROGRAMS, ids=[f"{c}-{n}" for c, n in _PROGRAMS])
 def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_chip, cell, program):
-    """Each cell's program lowers to the text it lowered to at 094436e (the GigaChat cell's: at PR 34, which brought
-    them; the Granite cell's: at PR 36), and the operations under each
+    """Each cell's program lowers to the text it lowered to at 094436e (the three hybrid cells': at PR 37, which
+    changed the experts' way back to token order in all nine), and the operations under each
     ``jax.named_scope`` the by-part metrics read are as many as they were: the hash does not see a scope's name, the
     metrics see nothing else. The four-chip cell's step is ``test_distributed_step``'s ``sharding2xmp2`` layout, the
     one-chip train cell's ``test_train_step``'s."""
