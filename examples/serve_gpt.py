@@ -103,7 +103,7 @@ def main():
           f"(chunk + final + fused step + prefix insert/extract)")
     print(f"  prefix cache: {ps['hits']} hits / {ps['misses']} misses, "
           f"{ps['entries']} chunks ({ps['bytes_used'] // 1024} KiB), "
-          f"stall p99 {max(r.stall_seconds for r in done2.values()) * 1e3:.2f} ms")
+          f"longest token gap {max(r.max_gap_seconds or 0.0 for r in done2.values()) * 1e3:.2f} ms")
 
     # 5) (--fleet) the fault-tolerant fleet: replica kill mid-stream,
     #    requeue onto the survivor, exactly-once bitwise completions

@@ -107,10 +107,12 @@ class _PrefillJob:
     owns, how far the cache is written (``next_pos``), how many tokens the
     prefix cache supplied, and — once the final chunk ran — the sampled
     first token (``pending``: the final program ran and its first token is
-    still on the device, for the next :meth:`DecodeEngine.prefill_step`)."""
+    still on the device, for the next :meth:`DecodeEngine.prefill_step`) and
+    the engine's count of prefill programs with that one (``programs``: what
+    is dispatched after it runs after the first token exists)."""
 
     __slots__ = ("slot", "prompt", "n", "eos", "limit", "seed",
-                 "next_pos", "reused_tokens", "done", "first", "more", "pending")
+                 "next_pos", "reused_tokens", "done", "first", "more", "pending", "programs")
 
     def __init__(self, slot, prompt, n, eos, limit, seed):
         self.slot = slot
@@ -125,6 +127,7 @@ class _PrefillJob:
         self.first: Optional[int] = None
         self.more: Optional[bool] = None
         self.pending = None        # (first, more) on the device, not yet pulled
+        self.programs = 0          # DecodeEngine.prefill_programs as the program that samples the first token was dispatched
 
     def chunks_left(self, chunk: Optional[int]) -> int:
         """Model dispatches still needed to finish this prefill."""
@@ -140,13 +143,16 @@ class _DecodeInFlight:
     the program's int32 ``report`` (tokens, which slots emitted them, which
     stay active, the decoder's counters), the slots the host has freed or
     admitted into since the launch (``touched``: the step's word on them is
-    another request's)."""
+    another request's), and the engine's running count of prefill programs
+    as it stood at the launch (``queued``: what the device had ahead of this
+    step)."""
 
-    __slots__ = ("report", "touched")
+    __slots__ = ("report", "touched", "queued")
 
-    def __init__(self, report, touched):
+    def __init__(self, report, touched, queued):
         self.report = report
         self.touched = touched
+        self.queued = queued
 
 
 class DecodeEngine:
@@ -329,6 +335,15 @@ class DecodeEngine:
             self._put_slot_consts()
         # run-ahead: the decode step launched and not yet pulled
         self._inflight: Optional[_DecodeInFlight] = None
+        # What the device ran between two decode steps, counted where it is queued: prefill programs dispatched so
+        # far (``infer.prefill``, ``infer.prefill_chunk``, final chunks too; a call that only pulls a deferred first
+        # token is none); the count as it stood at the launch of the last step pulled (``pulled_at``: the device runs
+        # one stream in launch order, so the difference of two is what ran between the two steps' ends); and when
+        # that step's tokens reached the host (the end of its ``infer.decode_sync``, on ``time.perf_counter_ns()``;
+        # 0 with FLAGS_monitor off). The scheduler reads them where it stamps the tokens.
+        self.prefill_programs = 0
+        self.pulled_at = 0
+        self.arrived_ns = 0
         self._spec_drafted = 0
         self._spec_accepted = 0
         self.last_stats = None    # the decoder's counters of the last decode dispatch (``n_stats`` int32)
@@ -829,6 +844,7 @@ class DecodeEngine:
                              jnp.asarray(ids), jnp.int32(n), jnp.int32(slot), jnp.int32(job.eos),
                              jnp.int32(job.limit), jnp.int32(job.seed)),
                     label=f"prefill/P{P}")
+            self.prefill_programs += 1  # noqa: PTA104 (host-side serving state)
             out = self._take_caches(out, spec)
             self._pos, self._tok, self._active, first, more = out  # noqa: PTA104 (host-side serving state)
             job.next_pos = n
@@ -843,6 +859,7 @@ class DecodeEngine:
                         "prefill_chunk", self._chunk_jit,
                         state + (jnp.asarray(ids), jnp.int32(slot), jnp.int32(job.next_pos)),
                         label=f"prefill_chunk/C{C}")
+                self.prefill_programs += 1  # noqa: PTA104 (host-side serving state)
                 self._take_caches(out if spec else (out,), spec)
                 job.next_pos += C
                 counter_inc("infer.prefill_chunk_dispatches")
@@ -863,10 +880,12 @@ class DecodeEngine:
                              jnp.int32(n - 1 - w), jnp.int32(n), jnp.int32(job.eos),
                              jnp.int32(job.limit), jnp.int32(job.seed)),
                     label=f"prefill_final/C{C}")
+            self.prefill_programs += 1  # noqa: PTA104 (host-side serving state)
             out = self._take_caches(out, spec)
             self._pos, self._tok, self._active, first, more = out  # noqa: PTA104 (host-side serving state)
             job.next_pos = n
             counter_inc("infer.prefill_chunk_dispatches")
+        job.programs = self.prefill_programs
         self._touch(slot)
         if self._inflight is not None:
             # until ``more`` is pulled the host takes the slot for active, as the device may
@@ -997,8 +1016,10 @@ class DecodeEngine:
         # the device already) and infer.decode_sync (the pull, which waits
         # for the device) — of this call's step, or running ahead of the
         # step before it: the end of infer.decode_sync is when the tokens
-        # this call returns reached the host.
+        # this call returns reached the host. ``queued``: the prefill count at
+        # the launch of the step this call pulls (None if it pulls none).
         with _span("infer.decode_step") as step_span:
+            queued = self.prefill_programs
             if spec:
                 from ..observability.metrics import gauge_set
 
@@ -1010,7 +1031,7 @@ class DecodeEngine:
                         label=f"spec_decode/K{self.spec_k}")
                 (self._cache, self._dcache,  # noqa: PTA104 (host-side serving state)
                  self._pos, self._tok, self._active, toks, emitted) = out  # noqa: PTA104 (host-side serving state)
-                with _span("infer.decode_sync"):
+                with _span("infer.decode_sync") as sync:
                     toks = np.asarray(toks)
                     emitted = np.asarray(emitted)
                     self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
@@ -1031,8 +1052,9 @@ class DecodeEngine:
                     if self._inflight is not None:
                         counter_inc("infer.decode_ahead")
                     step, self._inflight = self._inflight, step  # noqa: PTA104 (host-side serving state)
-                with _span("infer.decode_sync"):
+                with _span("infer.decode_sync") as sync:
                     toks, emitted, stats = self._collect_decode(step)
+                queued = None if step is None else step.queued
                 self._forget_idle_flight()
             else:
                 with _span("infer.decode_launch"):
@@ -1040,7 +1062,7 @@ class DecodeEngine:
                     carry = (self._cache, self._pos, self._tok, self._active)
                     out = self._dispatch(f"decode_x{depth}", self._fused(depth), (consts, carry))
                 (self._cache, self._pos, self._tok, self._active), ys = out  # noqa: PTA104 (host-side serving state)
-                with _span("infer.decode_sync"):
+                with _span("infer.decode_sync") as sync:
                     toks = np.asarray(ys[0])
                     emitted = np.asarray(ys[1])
                     if n_stats:
@@ -1048,6 +1070,11 @@ class DecodeEngine:
                     self._active_np = np.array(self._active)  # noqa: PTA104 (host-side serving state)
             counter_inc("infer.decode_dispatches")
             counter_inc("infer.tokens", int(emitted.sum()))
+            if queued is not None:
+                # for whoever stamps the pulled tokens: what was queued ahead of their step, and the instant they
+                # were on the host: the end of the pull, which the device trace has too
+                self.pulled_at = queued  # noqa: PTA104 (host-side serving state)
+                self.arrived_ns = sync.end_ns  # noqa: PTA104 (host-side serving state)
             if stats is not None:
                 self.last_stats = stats  # noqa: PTA104 (host-side serving state)
                 for name, value in zip(self._dec.stat_counters, stats):
@@ -1062,7 +1089,7 @@ class DecodeEngine:
         out = self._dispatch("decode", self._decode_jit,
                              (self._params, self._cache, self._pos, self._tok, self._active) + self._slot_consts)
         self._cache, self._pos, self._tok, self._active = out[:4]  # noqa: PTA104 (host-side serving state)
-        return _DecodeInFlight(out[4], np.zeros((self.max_batch_slots,), bool))
+        return _DecodeInFlight(out[4], np.zeros((self.max_batch_slots,), bool), self.prefill_programs)
 
     def _collect_decode(self, step: Optional[_DecodeInFlight]):
         """The collect half: pull a launched step's report (the one wait for

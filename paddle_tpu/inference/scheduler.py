@@ -10,11 +10,13 @@ Round 2 of the serving hot path rides the engine's three throughput knobs:
 
 - **chunked prefill** (``prefill_chunk``): an admission is a sequence of
   fixed-size chunk dispatches driven one per tick, INTERLEAVED with decode
-  — a 2k-token prompt no longer stalls every in-flight request for its
-  whole prefill. The time prefill dispatches spend while other slots hold
-  active decodes is the *stall*: tracked per request
-  (``Request.stall_seconds``, carried by the ``request`` run-log events) and
-  reported as p50/p99 by ``observability report``.
+  — a 2k-token prompt no longer holds every in-flight request's tokens back
+  for its whole prefill. What it does cost them is in the *token gap*: the
+  time between two consecutive tokens of one request, stamped where the
+  tokens arrive (``serving.itl_seconds``; the longest a request saw is its
+  ``max_gap_seconds``, carried by its ``finished`` run-log event), and each
+  tick's span record keeps its gaps with the prefill programs the device ran
+  inside each.
 - **fused decode** (``fuse=D``): one decode dispatch returns a ``[D, B]``
   token stack; the scheduler drains it in order, appending only tokens
   whose slot really emitted (finished slots self-deactivate in-graph).
@@ -32,10 +34,10 @@ its graceful degradation on both.
 Telemetry rides the PR-4 spine: every request emits ``request`` run-log
 events (``submitted`` → ``admitted`` → ``finished``, or ``cancelled``/
 ``deadline_exceeded``) with queue/prefill/
-decode/stall timings, the ``serving.*`` counters/gauges/histograms feed
+decode timings, the ``serving.*`` counters/gauges/histograms feed
 the metrics registry, and ``python -m paddle_tpu.observability report``
 renders a serving section (request rate, queue depth, latency/TTFT
-percentiles, prefix-hit rate, fused depth, stall percentiles) from the
+percentiles, prefix-hit rate, fused depth, token-gap percentiles) from the
 event stream.
 """
 from __future__ import annotations
@@ -45,6 +47,8 @@ from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
+
+from ..framework.flags import flag
 
 __all__ = ["Request", "ContinuousBatchingScheduler"]
 
@@ -74,11 +78,17 @@ Scheduler.cancel` (or the per-tick deadline sweep) reclaims it mid-flight.
         self.bucket: Optional[int] = None
         self.prefix_tokens = 0        # prompt rows supplied by the prefix cache
         self.prefill_chunks = 0       # model dispatches its prefill took
-        self.stall_seconds = 0.0      # prefill time spent while decode waited
         self.submitted_ts = time.perf_counter()
         self.admitted_ts: Optional[float] = None
         self.first_token_ts: Optional[float] = None
         self.finished_ts: Optional[float] = None
+        # the token gap, on time.perf_counter_ns() (0 while FLAGS_monitor is off): when the newest token reached the
+        # host (the first: the clock read of ``first_token_ts``; a later one: the end of the engine's pull), and the
+        # longest time between two consecutive tokens so far; and the engine's count of prefill programs with the one
+        # that sampled the first token: programs dispatched after it ran inside the gap to the second token
+        self.last_token_ns = 0
+        self.max_gap_ns = 0
+        self.prefilled_at = 0
 
     # -- derived timings (None until the request reaches that phase) -------
     @property
@@ -104,6 +114,12 @@ Scheduler.cancel` (or the per-tick deadline sweep) reclaims it mid-flight.
     @property
     def total_seconds(self):
         return None if self.finished_ts is None else self.finished_ts - self.submitted_ts
+
+    @property
+    def max_gap_seconds(self):
+        """The longest gap between two consecutive tokens the request saw
+        (None before its second token, or where nothing stamped them)."""
+        return self.max_gap_ns / 1e9 if self.max_gap_ns else None
 
     def deadline_expired(self, now: Optional[float] = None) -> bool:
         """True when the request carries a deadline and it has passed."""
@@ -140,6 +156,10 @@ class ContinuousBatchingScheduler:
         self.finished: Dict[int, Request] = {}    # rid -> request
         self.cancelled: Dict[int, Request] = {}   # rid -> cancelled/expired
         self._next_rid = 0
+        # of the last pull: when its tokens reached the host, on perf_counter_ns (-1: none stamped yet), and the engine's
+        # count of prefill programs at the launch of its step
+        self._arrived_ns = -1
+        self._pulled_programs = 0
 
     # ----------------------------------------------------------- lifecycle
     def submit(self, prompt, max_new_tokens: int = 16, eos_token_id: Optional[int] = None,
@@ -278,9 +298,8 @@ class ContinuousBatchingScheduler:
     def _prefill_tick(self) -> None:
         """ONE prefill dispatch per mid-prefill admission: in chunked mode a
         C-token chunk, in bucketed mode the whole padded prompt. Decode runs
-        between ticks, so a long admission interleaves instead of stalling
-        the stream; prefill time spent while decodes were waiting counts as
-        stall."""
+        between ticks, so a long admission interleaves instead of holding
+        the stream."""
         from ..observability import runlog as _runlog
         from ..observability import trace as _trace
         from ..observability.metrics import counter_inc, gauge_set, observe
@@ -288,7 +307,6 @@ class ContinuousBatchingScheduler:
         for slot in list(self.prefilling):
             r = self.prefilling[slot]
             job = self._jobs[slot]
-            decode_waiting = bool(self.running)
             # the last chunk ran a tick ago and left its first token on the
             # device (a decode step was in flight): this call only pulls it
             pull_only = job.pending is not None
@@ -301,11 +319,13 @@ class ContinuousBatchingScheduler:
                     _trace.span_event("serving.prefill_chunk", trace_id=r.trace_id,
                                       seconds=dt, id=r.rid, slot=slot,
                                       chunk=r.prefill_chunks, done=job.pending is not None or bool(done))
-            if decode_waiting:
-                r.stall_seconds += dt  # noqa: PTA104 (host-side serving loop)
             if not done:
                 continue
-            r.first_token_ts = time.perf_counter()  # noqa: PTA104 (host-side serving loop)
+            now_ns = time.perf_counter_ns()
+            r.first_token_ts = now_ns / 1e9  # noqa: PTA104 (host-side serving loop)
+            if flag("FLAGS_monitor"):
+                r.last_token_ns = now_ns  # noqa: PTA104 (host-side serving loop)
+                r.prefilled_at = getattr(job, "programs", 0)  # noqa: PTA104 (host-side serving loop)
             r.tokens.append(job.first)  # noqa: PTA104 (host-side serving loop)
             del self.prefilling[slot], self._jobs[slot]
             counter_inc("serving.requests_admitted")
@@ -315,8 +335,7 @@ class ContinuousBatchingScheduler:
             _runlog.emit("request", id=r.rid, status="admitted", component="serving",
                          slot=slot, bucket=r.bucket, queue_depth=len(self.queue),
                          queue_seconds=r.queue_seconds, seconds=r.prefill_seconds,
-                         prefix_tokens=r.prefix_tokens, chunks=r.prefill_chunks,
-                         stall_seconds=r.stall_seconds, trace=r.trace_id)
+                         prefix_tokens=r.prefix_tokens, chunks=r.prefill_chunks, trace=r.trace_id)
             if job.more:
                 r.status = "running"  # noqa: PTA104 (host-side serving loop, never traced)
                 self.running[slot] = r  # noqa: PTA104 (host-side serving loop)
@@ -337,6 +356,8 @@ class ContinuousBatchingScheduler:
         observe("serving.latency_seconds", r.total_seconds)
         gauge_set("serving.active_slots", len(self.running))
         extra = {}
+        if r.max_gap_ns:
+            extra["max_gap_seconds"] = r.max_gap_seconds  # noqa: PTA104 (host-side serving loop)
         if getattr(self.engine, "spec_k", 0):
             stats = self.engine.spec_stats()
             extra["spec_k"] = stats["spec_k"]  # noqa: PTA104 (host-side serving loop)
@@ -346,7 +367,7 @@ class ContinuousBatchingScheduler:
                      queue_seconds=r.queue_seconds, prefill_seconds=r.prefill_seconds,
                      decode_seconds=r.decode_seconds, total_seconds=r.total_seconds,
                      ttft_seconds=r.ttft_seconds, fuse=self.engine.fuse,
-                     prefix_tokens=r.prefix_tokens, stall_seconds=r.stall_seconds,
+                     prefix_tokens=r.prefix_tokens,
                      kv_bytes_per_slot=getattr(
                          self.engine, "kv_bytes_per_slot", lambda: 0)(),
                      trace=r.trace_id, **extra)
@@ -384,11 +405,36 @@ class ContinuousBatchingScheduler:
         and token pull) and ``infer.sched.drain`` (token appends, finishes,
         ledger GC, SLO hook; ``slots`` = slots that decoded). A request's
         path through the decode phase is these tick spans between its first
-        token and its finish — no per-tick event names the requests."""
+        token and its finish.
+
+        **The token gap.** A token that the drain appends arrived when the
+        engine's pull ended (``engine.arrived_ns``: the end of its
+        ``infer.decode_sync``, no clock read of the scheduler's), and its
+        gap is that instant less the request's last (``Request.
+        last_token_ns``; the first token's is the clock read of
+        ``first_token_ts``). Slots that decoded in the tick before share one
+        gap and a slot whose last token was its first has its own, so a tick
+        has a handful of distinct gaps; tokens that one pull brings together
+        (a ``fuse`` > 1 stack, a draft's accepted run) are 0 apart. With
+        each gap goes the number of prefill programs the device ran inside
+        it, from the engine's running count (the device runs one stream in
+        launch order): for the shared gap what the engine dispatched between
+        the launches of the two pulled steps (``engine.pulled_at`` then and
+        now), and for a request's first gap what it dispatched after the
+        program that sampled the first token (its own prefill ran before the
+        gap began). Once a tick each distinct gap goes to
+        ``serving.itl_seconds`` with its count, and the tick's span record is
+        noted with ``gaps`` = the distinct ``[gap_ns, count, chunks inside]``.
+        With FLAGS_monitor off nothing is stamped, observed or noted: the two
+        integer comparisons a token that the drain then still makes find
+        nothing to do."""
         from ..observability import slo as _slo
         from ..observability import span as _span
+        from ..observability.metrics import observe
 
-        with _span("infer.sched.step"):
+        engine = self.engine
+        monitored = flag("FLAGS_monitor")
+        with _span("infer.sched.step") as tick:
             before = set(self.finished)
             before_cancelled = set(self.cancelled)
             with _span("infer.sched.admit"):
@@ -398,15 +444,44 @@ class ContinuousBatchingScheduler:
                 self._prefill_tick()
             decoded = len(self.running)
             if decoded:
-                toks, emitted, active = self.engine.decode_step(ahead=True)
+                toks, emitted, active = engine.decode_step(ahead=True)
+            gaps = {}           # (gap in ns, prefill programs the device ran inside it) -> tokens of this tick with that gap
             with _span("infer.sched.drain", slots=decoded):
                 if decoded:
-                    toks = np.atleast_2d(toks)
-                    emitted = np.atleast_2d(emitted)
-                    for d in range(toks.shape[0]):
+                    # when these tokens reached the host: 0 where nothing stamps (FLAGS_monitor off, an engine without the
+                    # field); and the engine's count of prefill programs as it launched the step they are of
+                    arrived = getattr(engine, "arrived_ns", 0) if monitored else 0
+                    pulled = getattr(engine, "pulled_at", 0)
+                    # a slot that decoded in the pull before too was stamped with that pull's arrival: all such share one
+                    # gap, counted here and taken once; only a slot whose last token was its first has a gap of its own
+                    since, shared = self._arrived_ns, 0
+                    common = arrived - since
+                    for tok_row, emitted_row in zip(np.atleast_2d(toks).tolist(), np.atleast_2d(emitted).tolist()):
                         for slot, r in self.running.items():  # noqa: PTA102 (host-side serving loop)
-                            if emitted[d, slot]:
-                                r.tokens.append(int(toks[d, slot]))  # noqa: PTA104 (host-side serving loop)
+                            if emitted_row[slot]:
+                                r.tokens.append(tok_row[slot])  # noqa: PTA104 (host-side serving loop)
+                                last = r.last_token_ns
+                                if last == since:
+                                    shared += 1
+                                    r.last_token_ns = arrived  # noqa: PTA104 (host-side serving loop)
+                                    if common > r.max_gap_ns:
+                                        r.max_gap_ns = common  # noqa: PTA104 (host-side serving loop)
+                                elif arrived and last:
+                                    gap = arrived - last
+                                    r.last_token_ns = arrived  # noqa: PTA104 (host-side serving loop)
+                                    if gap > r.max_gap_ns:
+                                        r.max_gap_ns = gap  # noqa: PTA104 (host-side serving loop)
+                                    # since its first token: what was dispatched after its last prefill program (a gap of 0:
+                                    # a later token of one pull's stack, which nothing ran before)
+                                    own = (gap, pulled - r.prefilled_at if gap else 0)
+                                    gaps[own] = gaps.get(own, 0) + 1  # noqa: PTA104 (host-side serving loop)
+                    if arrived:
+                        if shared:      # between the two pulls: what the engine queued between their steps' launches
+                            both = (common, pulled - self._pulled_programs)
+                            gaps[both] = gaps.get(both, 0) + shared  # noqa: PTA104 (host-side serving loop)
+                        self._arrived_ns, self._pulled_programs = arrived, pulled  # noqa: PTA104 (host-side serving loop)
+                    for (gap, _), n in gaps.items():  # noqa: PTA102 (host-side serving loop)
+                        observe("serving.itl_seconds", gap / 1e9, n)
                     for slot, r in list(self.running.items()):  # noqa: PTA102 (host-side serving loop)
                         if not active[slot]:
                             self._finish(r)
@@ -418,6 +493,8 @@ class ContinuousBatchingScheduler:
                 # flag check per tick until FLAGS_slo (or an explicit install)
                 # arms it
                 _slo.on_tick()
+            if monitored:
+                tick.note(gaps=[[gap, gaps[gap, inside], inside] for gap, inside in sorted(gaps)])
         return done
 
     def _gc_ledgers(self, protect=()) -> None:

@@ -11,7 +11,7 @@ Prints, from one structured run log (see :mod:`.runlog`):
   ``rollback``/``loss_scale`` events,
 - a serving section (request rate, queue depth, prefill/decode time split,
   latency p50/p99 and time-to-first-token, prefix-cache hit rate, fused
-  decode depth, chunked-prefill stall percentiles, cancellations and
+  decode depth, token-gap percentiles, cancellations and
   deadline expiries) when the run produced ``request`` events (the
   continuous-batching scheduler's stream),
 - a serving-fleet section (replicas alive/dead with death reasons,
@@ -305,7 +305,7 @@ def _analyze_serving(reqs: List[dict]) -> dict:
                       if isinstance(ev.get(field), (int, float)))
             split[field.replace("_seconds", "")] = tot  # noqa: PTA104 (host-side report printer)
         out["phase_split_seconds"] = split  # noqa: PTA104 (host-side report printer)
-    # serving hot-path round 2: prefix reuse / fused depth / prefill stall
+    # serving hot-path round 2: prefix reuse / fused depth
     admitted = by_status.get("admitted", [])
     prefixed = [ev for ev in admitted if isinstance(ev.get("prefix_tokens"), int)]
     if prefixed:
@@ -333,14 +333,14 @@ def _analyze_serving(reqs: List[dict]) -> dict:
            if isinstance(ev.get("kv_bytes_per_slot"), int)]
     if kvb:
         out["kv_cache"] = {"bytes_per_slot": max(kvb)}  # noqa: PTA104 (host-side report printer)
-    stalls = sorted(ev["stall_seconds"] for ev in admitted
-                    if isinstance(ev.get("stall_seconds"), (int, float)))
-    if stalls:
-        out["prefill_stall"] = {  # noqa: PTA104 (host-side report printer)
-            "p50_seconds": _percentile(stalls, 50),
-            "p99_seconds": _percentile(stalls, 99),
-            "max_seconds": stalls[-1],
-            "total_seconds": sum(stalls),
+    # the token gap (time between two consecutive tokens of one request): each finished request's own longest. The
+    # distribution over every gap is the registry's serving.itl_seconds, on the exporter and not in the run log.
+    longest = sorted(ev["max_gap_seconds"] for ev in finished if isinstance(ev.get("max_gap_seconds"), (int, float)))
+    if longest:
+        out["token_gap"] = {  # noqa: PTA104 (host-side report printer)
+            "longest_p50_seconds": _percentile(longest, 50),
+            "longest_p95_seconds": _percentile(longest, 95),
+            "longest_max_seconds": longest[-1],
         }
     return out
 
@@ -716,11 +716,11 @@ def print_report(path: str, a: dict) -> None:
         kv = sv.get("kv_cache")
         if kv:
             print(f"    kv cache: {kv['bytes_per_slot']} bytes/slot")  # noqa: PTA105 (host-side report printer)
-        stall = sv.get("prefill_stall")
-        if stall:
-            print(f"    prefill stall: p50 {stall['p50_seconds'] * 1e3:.2f} ms   "  # noqa: PTA105 (host-side report printer)
-                  f"p99 {stall['p99_seconds'] * 1e3:.2f} ms   "
-                  f"total {stall['total_seconds']:.4f}s")
+        gap = sv.get("token_gap")
+        if gap:
+            print(f"    token gap, a request's longest: p50 {gap['longest_p50_seconds'] * 1e3:.2f} ms   "  # noqa: PTA105 (host-side report printer)
+                  f"p95 {gap['longest_p95_seconds'] * 1e3:.2f} ms   "
+                  f"max {gap['longest_max_seconds'] * 1e3:.2f} ms   (every gap: serving.itl_seconds)")
         if sv.get("cancelled") or sv.get("deadline_exceeded"):
             print(f"    reclaimed: {sv.get('cancelled', 0)} cancelled, "  # noqa: PTA105 (host-side report printer)
                   f"{sv.get('deadline_exceeded', 0)} deadline-expired")

@@ -50,9 +50,9 @@ _CREATE_LOCK = threading.Lock()
 class Histogram:
     """Bounded histogram: fixed bucket upper bounds + running aggregates.
 
-    ``observe`` is the hot path: a linear scan over <=20 floats (cheaper
-    than bisect's function-call overhead at this size) and four scalar
-    updates. No allocation, no lock — single-writer-per-GIL-slice safe.
+    ``observe`` is the hot path: a linear scan over the bounds (20 by
+    default; cheaper than bisect's function-call overhead at this size) and
+    four scalar updates. No allocation, no lock — single-writer-per-GIL-slice safe.
     """
 
     __slots__ = ("bounds", "bucket_counts", "count", "sum", "min", "max",
@@ -72,17 +72,19 @@ class Histogram:
         # is a lie when the whole distribution sits above it)
         self.overflow_min = math.inf
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, n: int = 1) -> None:
+        """Take ``value``, ``n`` times over (one scan for a value that many
+        observations share: the token gap of every slot of a decode step)."""
         i = 0
         for b in self.bounds:
             if value <= b:
                 break
             i += 1
-        self.bucket_counts[i] += 1
+        self.bucket_counts[i] += n
         if i == len(self.bounds) and value < self.overflow_min:
             self.overflow_min = value
-        self.count += 1
-        self.sum += value
+        self.count += n
+        self.sum += value * n
         if value < self.min:
             self.min = value
         if value > self.max:
@@ -323,6 +325,9 @@ RECSYS_COUNTERS: Tuple[str, ...] = (
 # "is tracing expensive" is answerable from the same scrape.
 OBS_COUNTERS: Tuple[str, ...] = (
     "trace.traces", "trace.spans",
+    # span records pushed out of the full in-memory ring (spans.RING_CAPACITY): a window read back through
+    # spans.recent() is whole only if this did not move while it ran
+    "trace.spans_evicted",
     "flightrec.dumps",
     "runlog.rotations", "runlog.gc_removed",
     "measured.persists",
@@ -375,6 +380,9 @@ KNOWN_GAUGES: Tuple[str, ...] = (
 KNOWN_HISTOGRAMS: Tuple[str, ...] = (
     "serving.ttft_seconds",
     "serving.queue_seconds", "serving.latency_seconds",
+    # the gap between two consecutive tokens of one request, stamped where the tokens arrive (the end of the engine's
+    # infer.decode_sync): one observation a gap, taken once a tick for each distinct gap with its count
+    "serving.itl_seconds",
     "fleet.latency_seconds",
     # network ingress (PR 20): wall time of one HTTP request end-to-end
     # and time-to-first-streamed-chunk as the client sees them
@@ -384,6 +392,13 @@ KNOWN_HISTOGRAMS: Tuple[str, ...] = (
     # series that says what the monitor itself costs
     "slo.eval_seconds",
 )
+
+
+# Series that want another ladder than DEFAULT_BUCKETS. A token gap is promised in milliseconds, and half a decade
+# a bucket would put every gap of a cell into one or two: eight buckets a decade (steps of a third) from 0.1 ms to 10 s.
+_BOUNDS: Dict[str, Tuple[float, ...]] = {
+    "serving.itl_seconds": tuple(10.0 ** (e / 8.0) for e in range(-32, 9)),
+}
 
 
 # -------------------------------------------------------------------- gauges
@@ -403,20 +418,20 @@ def histogram(name: str, bounds: Optional[Iterable[float]] = None) -> Histogram:
         with _CREATE_LOCK:
             h = _HISTOGRAMS.get(name)
             if h is None:
-                h = _HISTOGRAMS[name] = Histogram(bounds)
+                h = _HISTOGRAMS[name] = Histogram(bounds if bounds is not None else _BOUNDS.get(name))
     return h
 
 
 declare_histogram = histogram
 
 
-def observe(name: str, value: float) -> None:
-    """Record ``value`` into the bounded histogram ``name`` (hot path: one
-    dict hit + one bucket update once the series exists)."""
+def observe(name: str, value: float, n: int = 1) -> None:
+    """Record ``value`` (``n`` times) into the bounded histogram ``name``
+    (hot path: one dict hit + one bucket update once the series exists)."""
     h = _HISTOGRAMS.get(name)
     if h is None:
         h = histogram(name)
-    h.observe(value)
+    h.observe(value, n)
 
 
 def histograms(prefix: str = "") -> Dict[str, Histogram]:

@@ -37,9 +37,14 @@ from . import metrics
 
 __all__ = ["span", "Span", "recent", "self_time", "RING_CAPACITY"]
 
-# A 51-s window of the serving cells is under 10k spans (about 900 ticks of
-# eight); the ring keeps several windows.
-RING_CAPACITY = 65536
+# A serving tick is eight spans, and one or two more for each prefill chunk it
+# dispatches. The busiest 51-s window is the longgen cell's: 6,861-6,897 ticks
+# since the tick runs one step ahead (PERF.md section 5, PR 33), about 55,000
+# spans; the chat cell's is 4,845-4,855 ticks. The ring holds four of the
+# busiest, so a decode step several times shorter still leaves a whole window
+# in it, and what falls out is counted (``trace.spans_evicted``): a reader can
+# tell a window that lost its first ticks from a whole one.
+RING_CAPACITY = 262144
 _RING: deque = deque(maxlen=RING_CAPACITY)
 _IDS = itertools.count(1)
 
@@ -85,7 +90,7 @@ class Span:
 
     def note(self, **attrs):
         """Keep a few more small values on the record (what the section counted)."""
-        self.attrs = {**(self.attrs or {}), **attrs}  # noqa: PTA104 (host-side, never traced)
+        self.attrs = {**self.attrs, **attrs} if self.attrs else attrs  # noqa: PTA104 (host-side, never traced)
 
     def _link(self):
         """Take parent and trace from the innermost open entry; a span of a
@@ -126,6 +131,8 @@ class Span:
 
     def _record(self):
         """The one exit path: ring, histogram, run-log event."""
+        if len(_RING) == RING_CAPACITY:
+            metrics.counter_inc("trace.spans_evicted")
         _RING.append(self)
         metrics.observe(self.name, (self.end_ns - self.start_ns) / 1e9)
         if isinstance(self.span_id, str):
@@ -144,6 +151,7 @@ class _NullSpan:
     __slots__ = ()
     name = ""
     trace_id = span_id = parent_id = seconds = attrs = None
+    start_ns = end_ns = 0
     error = False
 
     def __enter__(self):

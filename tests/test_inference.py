@@ -6,6 +6,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -465,7 +466,8 @@ def test_scheduler_chunked_prefill_interleaves_with_decode():
     """A long admission in chunked mode runs one chunk per tick, and the
     already-decoding request keeps emitting tokens BETWEEN those chunk
     dispatches — prefill no longer stalls the stream. Tokens stay bitwise
-    equal to isolated runs; stall accounting lands on the request."""
+    equal to isolated runs; what the chunks cost the short request is in its
+    own longest token gap."""
     paddle.seed(44)
     m = GPTForPretraining(GPTConfig.tiny())
     m.eval()
@@ -482,6 +484,7 @@ def test_scheduler_chunked_prefill_interleaves_with_decode():
     sched = ContinuousBatchingScheduler(mk())
     r_short = sched.submit(short, max_new_tokens=10)
     sched.step()  # short admitted (single final chunk) + first decode
+    t_long = time.perf_counter_ns()
     r_long = sched.submit(long, max_new_tokens=6)
     progress = []
     while sched.prefilling or sched.queue:
@@ -495,13 +498,25 @@ def test_scheduler_chunked_prefill_interleaves_with_decode():
     # the short request gained tokens across >=2 ticks of the long prefill
     assert len(progress) >= 2 and progress[-1] > progress[0]
     assert done[r_long].prefill_chunks >= 5
-    assert done[r_long].stall_seconds > 0  # its chunks ran while decode waited
+    # the short request's longest gap between two tokens spans the long admission's chunk: while the long prompt was
+    # prefilling only the short request decoded, so the gaps that the ticks' span records keep with one chunk inside
+    # are its own — each ended by a pull (the end of ``infer.decode_sync``) of a call that began after the gap did
+    from paddle_tpu.observability import spans
+
+    recs = [s for s in spans.recent(since_ns=t_long) if s.end_ns <= done[r_short].finished_ts * 1e9]
+    step_of = {s.parent_id: s for s in recs if s.name == "infer.decode_step"}
+    pulled = {s.parent_id: s for s in recs if s.name == "infer.decode_sync"}
+    held = [(gap, s) for s in recs if s.name == "infer.sched.step" for gap, n, inside in s.attrs["gaps"] if inside == 1]
+    assert len(held) >= 3 and not any(inside > 1 for s in recs if s.name == "infer.sched.step" for _, _, inside in s.attrs["gaps"])
+    assert all(gap >= pulled[step_of[s.span_id].span_id].end_ns - step_of[s.span_id].start_ns > 0 for gap, s in held)
+    assert done[r_short].max_gap_ns >= max(gap for gap, _ in held)
+    assert done[r_short].max_gap_seconds < done[r_short].decode_seconds and done[r_long].max_gap_seconds > 0
 
 
 def test_scheduler_fused_decode_drains_token_stacks():
     """The scheduler drains [D, B] fused token stacks in order: outputs
     bitwise equal to the unfused scheduler, fewer decode dispatches, and
-    the report surfaces fuse depth + prefill stall + prefix-hit rate."""
+    the report surfaces fuse depth + token gap + prefix-hit rate."""
     from paddle_tpu import profiler
     from paddle_tpu.observability import monitor
     from paddle_tpu.observability.__main__ import analyze
@@ -530,7 +545,8 @@ def test_scheduler_fused_decode_drains_token_stacks():
     assert counts["infer.decode_dispatches"] <= 10, counts
     sv = analyze(monitor().events())["serving"]
     assert sv["fuse_depths"] == [3]
-    assert "prefill_stall" in sv
+    # three tokens a pull: the gaps inside a stack are 0, the gap between two pulls is not
+    assert 0 < sv["token_gap"]["longest_p50_seconds"] <= sv["token_gap"]["longest_p95_seconds"] <= sv["token_gap"]["longest_max_seconds"]
     assert sv["prefix_cache"]["hit_rate"] >= 0.0
 
 
