@@ -27,15 +27,26 @@ The chunkwise form (inner chunk ``C``, 64 by default) never divides by a
 cumulative decay: the products of ``k_t``, ``q_t`` with ``k_s`` are taken with
 ``exp(g_t - g_s)`` (``g`` the running sum of ``log_alpha`` inside the chunk),
 which is at most 1 for ``s <= t``, so a strong decay underflows to 0 instead
-of overflowing. The ``u`` of a chunk solve a unit lower-triangular system; it
-is solved once for all chunks together (the WY form: ``u = U - W S_0`` with
-``W``, ``U`` free of the state) by forward substitution in float32, and only
-the three small products with the carried state run chunk after chunk.
-Everything here is float32 at ``"highest"`` matmul precision: on the TPU a
-float32 product otherwise runs in one bfloat16 pass, and the state is what
-carries a sequence's history.
+of overflowing. The ``u`` of a chunk solve a unit lower-triangular system
+``(I + m) u = ...``; it is solved once for all chunks together (the WY form:
+``u = U - W S_0`` with ``W``, ``U`` free of the state) by its inverse in
+float32, and only the three small products with the carried state run chunk
+after chunk. The inverse is built by halving (``_unit_lower_inverse``): the
+two diagonal blocks' inverses and one product pair for the block below them,
+all blocks of a size in one batch, so ``log2(C)`` dependent steps where row
+substitution takes ``C``. Not by the doubling product
+``(I - m)(I + m^2)(I + m^4)...``, exact on paper because ``m`` is nilpotent:
+with keys that share a direction the powers of ``m`` grow like binomial
+coefficients before they cancel, and in float32 the product is off by
+factors of 1e9 and more at ``C`` = 64 where halving and row substitution both
+agree with a float64 solve to 1e-6 (``tests/test_solar_open2.py``).
+Everything here is float32, on the VPU or at ``"highest"`` matmul precision:
+on the TPU a float32 matmul otherwise runs in one bfloat16 pass, and the state
+is what carries a sequence's history.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +54,7 @@ import jax.numpy as jnp
 __all__ = ["delta_rule_step", "delta_rule_chunked"]
 
 _HI = jax.lax.Precision.HIGHEST
+_MXU_FROM = 16    # blocks of so many rows and more are multiplied by matmuls (measured on the v5e: PERF.md, PR 39)
 
 
 def _per_value_head(a, heads: int, axis: int):
@@ -66,19 +78,34 @@ def delta_rule_step(q, k, v, log_alpha, beta, state):
     return jnp.sum(state * q[..., None], axis=-2), state
 
 
-def _solve_unit_lower(m, rhs):
-    """``x`` with ``(I + m) x = rhs`` for strictly lower-triangular ``m``
-    ``[..., C, C]`` and ``rhs`` ``[..., C, n]``, by forward substitution: row
-    ``t`` needs rows ``< t`` only, and rows not yet written are zero."""
-    c = m.shape[-2]
+_mxu_matmul = functools.partial(jnp.matmul, precision=_HI)
 
-    def row(t, x):
-        m_t = jax.lax.dynamic_slice_in_dim(m, t, 1, axis=-2)                        # [..., 1, C]
-        r_t = jax.lax.dynamic_slice_in_dim(rhs, t, 1, axis=-2)                      # [..., 1, n]
-        x_t = r_t - jnp.matmul(m_t, x, precision=_HI)
-        return jax.lax.dynamic_update_slice_in_dim(x, x_t, t, axis=-2)
 
-    return jax.lax.fori_loop(0, c, row, jnp.zeros_like(rhs))
+def _vpu_matmul(a, b):
+    """``a @ b`` as a broadcast product and a sum: exact float32, and for blocks
+    under ``_MXU_FROM`` rows faster than a matmul that fills a corner of a tile."""
+    return jnp.sum(a[..., :, :, None] * b[..., None, :, :], axis=-2)
+
+
+def _unit_lower_inverse(m):
+    """``(I + m)^-1`` for strictly lower-triangular ``m`` ``[..., C, C]`` by
+    halving: ``[[A, 0], [L, B]]^-1 = [[A^-1, 0], [-B^-1 L A^-1, B^-1]]``, the
+    two diagonal blocks inverted as one batch (one after the other where ``C``
+    is odd), down to 1 x 1. ``log2(C)`` levels of two products each; a row of
+    zeros in ``m`` gives that row of the identity exactly."""
+    c = m.shape[-1]
+    if c == 1:
+        return jnp.ones_like(m)
+    h = c // 2
+    if c % 2:
+        top, bot = _unit_lower_inverse(m[..., :h, :h]), _unit_lower_inverse(m[..., h:, h:])
+    else:
+        both = _unit_lower_inverse(jnp.stack([m[..., :h, :h], m[..., h:, h:]], axis=-3))
+        top, bot = both[..., 0, :, :], both[..., 1, :, :]
+    matmul = _vpu_matmul if h < _MXU_FROM else _mxu_matmul
+    low = -matmul(bot, matmul(m[..., h:, :h], top))
+    upper = jnp.zeros(m.shape[:-2] + (h, c - h), m.dtype)
+    return jnp.concatenate([jnp.concatenate([top, upper], axis=-1), jnp.concatenate([low, bot], axis=-1)], axis=-2)
 
 
 def delta_rule_chunked(q, k, v, log_alpha, beta, state, *, chunk: int = 64):
@@ -98,12 +125,14 @@ def delta_rule_chunked(q, k, v, log_alpha, beta, state, *, chunk: int = 64):
     q, k, v, log_alpha = (a.reshape(H, n, c, a.shape[-1]) for a in (q, k, v, log_alpha))
     beta = beta.reshape(H, n, c)
 
-    g = jnp.cumsum(log_alpha, axis=2)                                               # [H, n, C, dk], <= 0
+    scalar_decay = log_alpha.shape[-1] == 1 and dk > 1
+    # [H, n, C, dk], <= 0; one decay a head is summed with the rows as the minor axis: over [..., C, 1] the chip may pad a row to a tile
+    g = jnp.cumsum(log_alpha[..., 0], axis=2)[..., None] if scalar_decay else jnp.cumsum(log_alpha, axis=2)
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     # exp(g_t - g_s) for s <= t, 0 above the diagonal; never over 1
     rel = jnp.where((s_idx <= t_idx)[None, None, :, :, None], g[:, :, :, None, :] - g[:, :, None, :, :], -jnp.inf)
-    if log_alpha.shape[-1] == 1 and dk > 1:
+    if scalar_decay:
         # one decay a head: it leaves the sum over the channels, which is then a matmul ([C, C, dk] is never made)
         k_t, pair = jnp.swapaxes(k, -1, -2), jnp.exp(rel[..., 0])
         a_kk = jnp.matmul(k, k_t, precision=_HI) * pair                               # [H, n, C, C]: G_t/G_s k_t . k_s
@@ -115,8 +144,8 @@ def delta_rule_chunked(q, k, v, log_alpha, beta, state, *, chunk: int = 64):
     strict = (s_idx < t_idx)[None, None]
     m = jnp.where(strict, beta[..., None] * a_kk, 0.0)
     decay = jnp.exp(g)
-    wu = _solve_unit_lower(m, jnp.concatenate([beta[..., None] * k * decay, beta[..., None] * v], axis=-1))
-    w, u0 = wu[..., :dk], wu[..., dk:]                                              # u = u0 - w S_0
+    inv = _unit_lower_inverse(m)                                                    # inv @ r is the x with (I + m) x = r
+    w, u0 = _mxu_matmul(inv, beta[..., None] * k * decay), _mxu_matmul(inv, beta[..., None] * v)   # u = u0 - w S_0
     g_end = g[:, :, -1:, :]                                                         # [H, n, 1, dk]
     k_tail = k * jnp.exp(g_end - g)                                                 # Diag(G_C/G_s) k_s
     q_in = q * decay

@@ -272,6 +272,16 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
 # carry no scope path and which nothing calls at those shapes any more (Granite 1168 -> 1097, 1706 -> 1639, 1808 ->
 # 1737; Solar 610 -> 563, 896 -> 853, 1011 -> 964; GigaChat 752 -> 705, 1156 -> 1113, 1279 -> 1232). No other scope's
 # count moved; the GPT serving programs and both training steps are byte for byte what they were.
+# PR 39 re-pinned ``chunk_core`` and ``chunk_final_core`` of Solar's and GigaChat's cells and nothing else: the chunkwise
+# delta rule (``ops/delta_rule.py``) inverts its unit lower-triangular matrix by halving, in straight-line batched
+# products, where it ran 64 rows of forward substitution in a ``while``; it multiplies the inverse into ``w`` and ``u0``
+# as two products where one solve took the two concatenated; and where a head has one decay (GigaChat) the running sum of
+# its logarithm is taken with the rows as the minor axis. ``linear_core`` gains 131 operations a delta-rule layer in
+# Solar (553 -> 946, 597 -> 990) and 134 in GigaChat (752 -> 1288, 796 -> 1332), ``unscoped`` loses the 31 of the loop's
+# body, which carried no scope path (Solar 853 -> 760, 964 -> 871; GigaChat 1113 -> 989, 1232 -> 1108); the texts grow by
+# 96 and 99 lines a layer (2284 -> 2572, 2668 -> 2956; 3308 -> 3704, 3780 -> 4176). No other scope's count moved; both
+# cells' ``decode_fn`` (``delta_rule_step``), Granite's three programs, every GPT program and both training steps are
+# byte for byte what they were. ``test_a_delta_rule_layer_of_a_chunk_program_loops_...`` keeps the 64-trip loop out.
 _PARENT_PROGRAMS = {
     "cerebras-gpt-1.3b.serve-longgen": {
         "decode_fn": ("c1b2b9e757190693", 3277),
@@ -287,15 +297,16 @@ _PARENT_PROGRAMS = {
     },
     "solar-open2-250b.serve-reasoning": {
         "decode_fn": ("3aa7e33f2c334eb3", 1921),
-        "chunk_core": ("e10abb061a4a6ef9", 2284),
-        "chunk_final_core": ("c5672ff9be3f51ee", 2668),
+        "chunk_core": ("3f17368804500c83", 2572),
+        "chunk_final_core": ("666a74b2a9dad466", 2956),
     },
-    # PR 34, the cell's first programs (PR 37: the experts' way back): what a later refactor of ``models/gigachat3_5.py``
-    # or of the ops it shares with Solar's (``ops/delta_rule.py``, ``ops/moe_dropless.py``) has to leave as it is
+    # PR 34, the cell's first programs (PR 37: the experts' way back; PR 39: the chunk programs' delta-rule solve): what a
+    # later refactor of ``models/gigachat3_5.py`` or of the ops it shares with Solar's (``ops/delta_rule.py``,
+    # ``ops/moe_dropless.py``) has to leave as it is
     "gigachat3.5-432b-a28b.serve-longdoc": {
         "decode_fn": ("e5b34fb18caa72c6", 2788),
-        "chunk_core": ("ce38f98d9969d2f1", 3308),
-        "chunk_final_core": ("d4d9aa9abef9299c", 3780),
+        "chunk_core": ("0b7248241d4da690", 3704),
+        "chunk_final_core": ("b00d62003595727e", 4176),
     },
     # PR 36, the cell's first programs (PR 37: the experts' way back): what a later refactor of
     # ``models/granite_moe_hybrid.py``, of what it imports from ``models/solar_open2.py`` or of ``ops/ssd.py`` has to
@@ -328,13 +339,13 @@ _PARENT_SCOPES = {
     },
     "solar-open2-250b.serve-reasoning": {
         "decode_fn": {"unscoped": 563, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 560, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
-        "chunk_core": {"unscoped": 853, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 387, "moe_shared": 36, "linear_proj": 92, "linear_core": 553, "linear_out": 38},
-        "chunk_final_core": {"unscoped": 964, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 516, "moe_shared": 48, "linear_proj": 102, "linear_core": 597, "linear_out": 57, "head_loss": 2},
+        "chunk_core": {"unscoped": 760, "embed": 1, "norm": 133, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 51, "moe_routed": 387, "moe_shared": 36, "linear_proj": 92, "linear_core": 946, "linear_out": 38},
+        "chunk_final_core": {"unscoped": 871, "embed": 1, "norm": 168, "attn_qkv": 13, "cache_write": 18, "cache_read": 12, "attn_core": 28, "attn_out": 3, "moe_router": 68, "moe_routed": 516, "moe_shared": 48, "linear_proj": 102, "linear_core": 990, "linear_out": 57, "head_loss": 2},
     },
     "gigachat3.5-432b-a28b.serve-longdoc": {
         "decode_fn": {"unscoped": 705, "embed": 1, "norm": 566, "linear_proj": 100, "linear_core": 404, "linear_out": 124, "mlp": 14, "mla_q": 35, "mla_kv": 27, "rope": 46, "mla_core": 3, "mla_out": 17, "moe_router": 68, "moe_routed": 572, "moe_shared": 60, "head_loss": 2},
-        "chunk_core": {"unscoped": 1113, "embed": 1, "norm": 459, "linear_proj": 99, "linear_core": 752, "linear_out": 93, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 51, "moe_routed": 396, "moe_shared": 45},
-        "chunk_final_core": {"unscoped": 1232, "embed": 1, "norm": 565, "linear_proj": 100, "linear_core": 796, "linear_out": 124, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 68, "moe_routed": 528, "moe_shared": 60, "head_loss": 2},
+        "chunk_core": {"unscoped": 989, "embed": 1, "norm": 459, "linear_proj": 99, "linear_core": 1288, "linear_out": 93, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 51, "moe_routed": 396, "moe_shared": 45},
+        "chunk_final_core": {"unscoped": 1108, "embed": 1, "norm": 565, "linear_proj": 100, "linear_core": 1332, "linear_out": 124, "mlp": 14, "mla_q": 32, "mla_kv": 27, "rope": 46, "cache_write": 8, "mla_core": 73, "mla_out": 13, "moe_router": 68, "moe_routed": 528, "moe_shared": 60, "head_loss": 2},
     },
     "granite-4.0-h-small.serve-rag": {
         "decode_fn": {"unscoped": 1097, "embed": 4, "norm": 376, "ssm_proj": 54, "ssm_conv": 405, "ssm_core": 369, "ssm_norm": 171, "ssm_out": 9, "moe_router": 170, "moe_routed": 1400, "moe_shared": 120, "attn_qkv": 6, "attn_core": 2, "attn_out": 1, "head_loss": 4},
@@ -761,7 +772,8 @@ _PROGRAMS = ([(cell, name) for cell in sorted(_DECODE) for name in ("decode_fn",
 @pytest.mark.parametrize("cell,program", _PROGRAMS, ids=[f"{c}-{n}" for c, n in _PROGRAMS])
 def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_chip, cell, program):
     """Each cell's program lowers to the text it lowered to at 094436e (the three hybrid cells': at PR 37, which
-    changed the experts' way back to token order in all nine), and the operations under each
+    changed the experts' way back to token order in all nine; Solar's and GigaChat's chunk programs: at PR 39, which
+    changed the chunkwise delta rule's solve), and the operations under each
     ``jax.named_scope`` the by-part metrics read are as many as they were: the hash does not see a scope's name, the
     metrics see nothing else. The four-chip cell's step is ``test_distributed_step``'s ``sharding2xmp2`` layout, the
     one-chip train cell's ``test_train_step``'s."""
@@ -779,3 +791,19 @@ def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_ch
         lowered = _distributed_lowered(topo, _LAYOUTS["sharding2xmp2"])
     assert _fingerprint(lowered.as_text()) == _PARENT_PROGRAMS[cell][program]
     assert _scope_counts(lowered) == _PARENT_SCOPES[cell][program]
+
+
+@pytest.mark.parametrize("cell,program", [(c, p) for c in (_SOLAR, _GIGA) for p in ("chunk_core", "chunk_final_core")],
+                         ids=lambda v: v)
+def test_a_delta_rule_layer_of_a_chunk_program_loops_over_its_inner_chunks_and_over_nothing_else(as_tpu, one_chip, cell, program):
+    """PR 39: the chunkwise delta rule (``ops/delta_rule.py``) inverts its unit lower-triangular matrix by halving, in
+    straight-line batched products. Until then every such layer of a chunk program held a second ``while``, of 64 trips
+    — a row of forward substitution each — which was 4.9 ms a layer on the chip at GigaChat's 1,024 (head, inner chunk)
+    pairs. What loops now is the scan over the 1,024 / 64 inner chunks, once a layer, and in GigaChat's programs the
+    latent layer's walk over the blocks of its context, whose trip count is an argument."""
+    cfg, _, lowered = (_solar_lowered if cell == _SOLAR else _giga_lowered)(one_chip)
+    text = lowered[program][0].as_text()
+    linear = cfg.num_hidden_layers - len(cfg.gqa_layers if cell == _SOLAR else cfg.full_attention_layers)
+    fixed = [int(n) for n in re.findall(r"stablehlo\.while\(.*\n\s*cond \{\n\s*%\S+ = stablehlo\.constant dense<(\d+)> : tensor<i32>", text)]
+    assert fixed == [1024 // 64] * linear, fixed
+    assert text.count("stablehlo.while(") == linear + (cell == _GIGA)           # the parent: twice ``linear`` and the same one

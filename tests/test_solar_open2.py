@@ -11,7 +11,7 @@ import pytest
 from benchmark.harness import manifest
 from paddle_tpu.inference import DecodeEngine
 from paddle_tpu.models import solar_open2 as so2
-from paddle_tpu.ops.delta_rule import delta_rule_chunked, delta_rule_step
+from paddle_tpu.ops.delta_rule import _unit_lower_inverse, delta_rule_chunked, delta_rule_step
 from paddle_tpu.ops.moe_dropless import dropless_experts, gated_ffn, route_topk
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -147,30 +147,69 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference_layer(family, model
 
 
 # ------------------------------------------------ (c) chunkwise delta rule = the recurrence
-@pytest.mark.parametrize("tokens,chunk,beta_hi,decay_hi", [(192, 64, 2.0, 1.6), (192, 64, 1.0, 0.05), (48, 16, 2.0, 8.0),
-                                                           (64, 64, 2.0, 0.5)])
-def test_chunkwise_delta_rule_is_the_token_by_token_recurrence(tokens, chunk, beta_hi, decay_hi):
-    """Including ``beta > 1`` (negative eigenvalues), a decay strong enough to
-    underflow a cumulative product, and a state carried over three chunks."""
+def _delta_rule_inputs(tokens, chunk, beta_hi, decay_hi, cos):
+    """Unit queries and keys whose neighbours lie at a cosine of ``cos`` (a shared direction as long as a noise row, and
+    nothing drawn for it at 0); ``beta`` up to ``beta_hi``, ``-log_alpha`` up to ``decay_hi``."""
     rng = np.random.default_rng(tokens + chunk)
     H, dk, dv = 3, 16, 24
     unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
-    q, k = (unit(rng.normal(size=(H, tokens, dk))).astype(np.float32) for _ in range(2))
+    shared = lambda: np.sqrt(cos * dk) * unit(rng.normal(size=(H, 1, dk))) if cos else 0.0  # noqa: E731
+    q, k = (unit(shared() + np.sqrt(1 - cos) * rng.normal(size=(H, tokens, dk))).astype(np.float32) for _ in range(2))
     v = rng.normal(size=(H, tokens, dv)).astype(np.float32)
     log_alpha = -rng.uniform(1e-3, decay_hi, size=(H, tokens, dk)).astype(np.float32)
     beta = rng.uniform(0, beta_hi, size=(H, tokens)).astype(np.float32)
     state = rng.normal(size=(H, dk, dv)).astype(np.float32)
+    return q, k, v, log_alpha, beta, state
+
+
+@pytest.mark.parametrize("tokens,chunk,beta_hi,decay_hi,cos,bound", [
+    (192, 64, 2.0, 1.6, 0.0, 1e-5), (192, 64, 1.0, 0.05, 0.0, 1e-5), (48, 16, 2.0, 8.0, 0.0, 1e-5), (64, 64, 2.0, 0.5, 0.0, 1e-5),
+    # keys that share a direction, hardly any decay: over five seeds row substitution (the parent's) reads 4e-7 to 1.2e-6 here and
+    # the halved inverse 5e-7 to 1.3e-6, where the doubling product (I - m)(I + m^2)(I + m^4)... reads 7e-6, 2e-4, 8e9 and 6e27
+    (48, 16, 2.0, 0.01, 0.5, 2e-6), (48, 16, 2.0, 0.01, 0.9, 2e-6), (192, 64, 2.0, 0.01, 0.5, 2e-6), (192, 64, 2.0, 0.01, 0.9, 2e-6)])
+def test_chunkwise_delta_rule_is_the_token_by_token_recurrence(tokens, chunk, beta_hi, decay_hi, cos, bound):
+    """Including ``beta > 1`` (negative eigenvalues), a decay strong enough to
+    underflow a cumulative product, a state carried over three chunks, and
+    neighbouring keys at a cosine of ``cos``: the powers of the chunk's
+    triangular matrix then grow before they vanish."""
+    q, k, v, log_alpha, beta, state = _delta_rule_inputs(tokens, chunk, beta_hi, decay_hi, cos)
     s, outs = jnp.asarray(state), []
     for t in range(tokens):
         o, s = delta_rule_step(q[:, t], k[:, t], v[:, t], log_alpha[:, t], beta[:, t], s)
         outs.append(np.asarray(o))
     o2, s2 = delta_rule_chunked(q, k, v, log_alpha, beta, state, chunk=chunk)
-    assert _rel(o2, np.stack(outs, 1)) < 1e-5 and _rel(s2, s) < 1e-5
+    assert _rel(o2, np.stack(outs, 1)) < bound and _rel(s2, s) < bound
     if beta_hi > 1:
         assert float(beta.max()) > 1.0
+    if cos:
+        assert abs(float(np.sum(k[:, 1:] * k[:, :-1], axis=-1).mean()) - cos) < 0.05
     # alpha = 1 and beta = 0 leave the state bitwise alone
     _, s3 = delta_rule_chunked(q, k, v, np.zeros_like(log_alpha), np.zeros_like(beta), state, chunk=chunk)
     np.testing.assert_array_equal(np.asarray(s3), state)
+
+
+@pytest.mark.parametrize("c", [16, 32, 64, 24])
+def test_the_halved_inverse_of_a_chunks_unit_triangular_matrix_is_the_float64_solve(c):
+    """``(I + m)^-1`` as the chunkwise form builds ``m`` (``beta`` 1 to 2, keys
+    at a neighbour cosine of 0.9), over two batch axes, times a right-hand side:
+    ``numpy.linalg.solve`` in float64. 24 halves to 3, an odd block."""
+    rng = np.random.default_rng(c)
+    batch, n = (3, 2), 40
+    k = np.sqrt(0.9) * rng.normal(size=batch + (1, 16)) + np.sqrt(0.1) * rng.normal(size=batch + (c, 16))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = rng.uniform(1.0, 2.0, size=batch + (c, 1))
+    m = np.tril(beta * (k @ np.swapaxes(k, -1, -2)), -1)
+    rhs = rng.normal(size=batch + (c, n))
+    want = np.linalg.solve(np.eye(c) + m, rhs)
+    inv = _unit_lower_inverse(jnp.asarray(m, jnp.float32))
+    assert inv.shape == batch + (c, c) and inv.dtype == jnp.float32
+    assert not np.asarray(jnp.triu(inv, 1)).any() and (np.asarray(jnp.diagonal(inv, axis1=-2, axis2=-1)) == 1.0).all()
+    got = jnp.matmul(inv, jnp.asarray(rhs, jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    assert _rel(got, want) < 2e-6
+    # a row of m that is zero (a padded position: beta = 0) is that row of the identity, exactly
+    m[..., c // 2 + 1, :] = 0.0
+    inv = np.asarray(_unit_lower_inverse(jnp.asarray(m, jnp.float32)))
+    np.testing.assert_array_equal(inv[..., c // 2 + 1, :], np.broadcast_to(np.eye(c, dtype=np.float32)[c // 2 + 1], batch + (c,)))
 
 
 # ------------------------------------------------ (d) slots
