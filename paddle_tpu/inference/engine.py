@@ -38,9 +38,11 @@ forwards over them. GPT (``models/gpt.py:GPTDecoder``), Solar Open 2
 (``models/solar_open2.py:SolarOpen2Decoder``), GigaChat 3.5
 (``models/gigachat3_5.py:GigaChat35Decoder``, whose cached rows are latents
 ``[B, S, rank + rope]`` with no head axis) and Granite 4.0-H
-(``models/granite_moe_hybrid.py:GraniteMoeHybridDecoder``) are its clients;
-for a model with recurrent state the engine refuses a prefix cache, a draft
-and an int8 cache.
+(``models/granite_moe_hybrid.py:GraniteMoeHybridDecoder``) and EvaByte
+(``models/evabyte.py:EvaByteDecoder``, whose slots hold a window ring of exact
+rows and a table of chunk summaries) are its clients; for a model with
+recurrent state, or rows that cannot be rebuilt, the engine refuses a prefix
+cache, a draft and an int8 cache.
 
 The slot buffers (and the slot state) are donated, so what the engine
 *holds* stays flat for its life. Whether a program also updates them in
@@ -327,6 +329,9 @@ class DecodeEngine:
         # live on the device (``_slot_consts``) and are copied there where
         # they change — admission, reset — not with every dispatch
         self._active_np = np.zeros((B,), bool)
+        # each slot's position as the host knows it: set where its first token's program is dispatched, advanced by
+        # each decode launch for the slots the host takes as active (what ``Decoder.step_notes`` counts from)
+        self._pos_np = np.zeros((B,), np.int64)
         self._occupied = np.zeros((B,), bool)
         self._eos = np.full((B,), -1, np.int32)
         self._limit = np.zeros((B,), np.int32)
@@ -854,7 +859,8 @@ class DecodeEngine:
                 # intermediate chunk: KV writes only, no logits work
                 ids = job.prompt[job.next_pos:job.next_pos + C][None]
                 state = self._program_state()
-                with _span("infer.prefill_chunk"):
+                with _span("infer.prefill_chunk") as chunk_span:
+                    self._note(chunk_span, self._dec.chunk_notes(job.next_pos, C))
                     out = self._dispatch(
                         "prefill_chunk", self._chunk_jit,
                         state + (jnp.asarray(ids), jnp.int32(slot), jnp.int32(job.next_pos)),
@@ -872,7 +878,8 @@ class DecodeEngine:
             ids = np.zeros((1, C), np.int32)
             ids[0, :n - w] = job.prompt[w:n]
             state = self._program_state()
-            with _span("infer.prefill_chunk"):
+            with _span("infer.prefill_chunk") as chunk_span:
+                self._note(chunk_span, self._dec.chunk_notes(job.next_pos, n - job.next_pos))
                 out = self._dispatch(
                     "prefill_final", self._chunk_final_jit,
                     state + (self._pos, self._tok, self._active,
@@ -886,6 +893,7 @@ class DecodeEngine:
             job.next_pos = n
             counter_inc("infer.prefill_chunk_dispatches")
         job.programs = self.prefill_programs
+        self._pos_np[slot] = n
         self._touch(slot)
         if self._inflight is not None:
             # until ``more`` is pulled the host takes the slot for active, as the device may
@@ -1020,6 +1028,8 @@ class DecodeEngine:
         # the launch of the step this call pulls (None if it pulls none).
         with _span("infer.decode_step") as step_span:
             queued = self.prefill_programs
+            self._note(step_span, self._dec.step_notes(self._pos_np, self._active_np))
+            self._pos_np += depth * self._active_np
             if spec:
                 from ..observability.metrics import gauge_set
 
@@ -1083,6 +1093,12 @@ class DecodeEngine:
                 step_span.note(**{n.rsplit(".", 1)[-1]: int(v) for n, v in zip(self._dec.stat_counters, stats)})
         return toks, emitted, self._active_np.copy()
 
+    @staticmethod
+    def _note(span, attrs: dict) -> None:
+        """What the decoder counted, on a span record (nothing to note: no call)."""
+        if attrs:
+            span.note(**attrs)
+
     def _launch_decode(self) -> _DecodeInFlight:
         """The launch half of a depth-1 step: dispatch the decode program on
         the carry and keep its report for whoever pulls it."""
@@ -1137,6 +1153,7 @@ class DecodeEngine:
         self._tok = jnp.zeros((B,), jnp.int32)
         self._active = jnp.zeros((B,), bool)
         self._active_np[:] = False
+        self._pos_np[:] = 0
         self._occupied[:] = False
         self._eos[:] = -1
         self._limit[:] = 0

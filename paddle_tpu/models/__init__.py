@@ -34,3 +34,7 @@ from .granite_moe_hybrid import (  # noqa: F401
     GraniteMoeHybridConfig,
     GraniteMoeHybridForCausalLM,
 )
+from .evabyte import (  # noqa: F401
+    EvaByteConfig,
+    EvaByteForCausalLM,
+)

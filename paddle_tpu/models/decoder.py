@@ -31,6 +31,10 @@ not shifted back over rows already written).
 
 ``n_stats`` int32 counters may come back from ``decode`` (the routed experts'
 load); the engine pulls them in the same transfer as the step's tokens.
+``step_notes(positions, active)`` and ``chunk_notes(start, rows)`` are what a
+decoder counts on the host, from the slots' positions as the engine knows them
+(no device pull): the engine notes them on the ``infer.decode_step`` and
+``infer.prefill_chunk`` span records.
 
 The token samplers live here too: they are the engine's, not a model's.
 """
@@ -72,6 +76,16 @@ class Decoder:
 
     def buffer_specs(self, slots: int, rows: int, kv_dtype=None) -> Tuple[BufferSpec, ...]:
         raise NotImplementedError
+
+    def step_notes(self, positions, active) -> dict:
+        """Attributes of a decode step launched with the slots at
+        ``positions`` (host int array ``[B]``) where ``active``."""
+        return {}
+
+    def chunk_notes(self, start: int, rows: int) -> dict:
+        """Attributes of a prefill program over ``rows`` prompt rows from
+        ``start``."""
+        return {}
 
     def alloc(self, slots: int, rows: int, kv_dtype=None):
         """The buffers of ``buffer_specs``, zeroed, as a tuple in that order."""

@@ -13,8 +13,8 @@ Note: the ``flash_attention`` *function* is reached as
 the submodule name existing imports rely on.
 """
 from . import (  # noqa: F401
-    decode_attention, flash_attention, flash_attention_flat, grouped_matmul, layer_norm, mla_attention, moe_pallas,
-    registry, rope, ssd,
+    decode_attention, eva_attention, flash_attention, flash_attention_flat, grouped_matmul, layer_norm, mla_attention,
+    moe_pallas, registry, rope, ssd,
 )
 from .flash_attention import flash_attention_available, flash_attention_qkv  # noqa: F401
 from .flash_attention_flat import flash_flat, flash_flat_gqa, flash_packed  # noqa: F401
@@ -30,7 +30,7 @@ from .registry import (  # noqa: F401
 )
 
 __all__ = [
-    "decode_attention", "flash_attention", "flash_attention_flat", "grouped_matmul", "layer_norm", "mla_attention",
+    "decode_attention", "eva_attention", "flash_attention", "flash_attention_flat", "grouped_matmul", "layer_norm", "mla_attention",
     "moe_pallas", "registry", "rope", "ssd",
     "flash_attention_available", "flash_attention_qkv",
     "flash_flat", "flash_flat_gqa", "flash_packed",

@@ -1,12 +1,22 @@
-"""Rotary positions: the YaRN frequency table and the rotation of interleaved
-pairs, in plain ``jax.numpy``.
+"""Rotary positions: the YaRN frequency table and the rotation of pairs, in
+plain ``jax.numpy``, with the pairs taken either of two ways.
 
-A vector of ``dim`` channels at position ``t`` is rotated pair by pair: pair
-``i`` is channels ``(2i, 2i + 1)`` (``rope_interleave``) and turns by the angle
-``t * inv_freq[i]``:
+A vector of ``dim`` channels at position ``t`` is rotated pair by pair, pair
+``i`` turning by the angle ``t * inv_freq[i]``. **Interleaved** pairs
+(``rope_interleave``; GigaChat 3.5's latent attention) are channels ``(2i, 2i +
+1)``:
 
     y[2i]     = x[2i] cos - x[2i + 1] sin
     y[2i + 1] = x[2i + 1] cos + x[2i] sin
+
+**Half-split** pairs (``rotate_half``, the Llama lineage; EvaByte) are channels
+``(i, i + dim / 2)``:
+
+    y[i]           = x[i] cos - x[i + dim / 2] sin
+    y[i + dim / 2] = x[i + dim / 2] cos + x[i] sin
+
+Both are registered under the kernel ``rope`` (``lax_interleaved``,
+``lax_half_split``); :func:`rotate` picks by its ``pairs`` argument.
 
 ``inv_freq`` is YaRN's (arXiv:2309.00071, as DeepSeek-V3's modelling code
 computes it): ``theta^(-2i/dim)`` for the pairs that turn more than
@@ -29,7 +39,7 @@ import numpy as np
 
 from . import registry
 
-__all__ = ["yarn_inv_freq", "yarn_mscale", "rope_angles", "apply_rope", "rotate"]
+__all__ = ["yarn_inv_freq", "yarn_mscale", "rope_angles", "apply_rope", "apply_rope_half", "rotate"]
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float = 1.0, beta_fast: float = 32.0, beta_slow: float = 1.0,
@@ -71,11 +81,27 @@ def apply_rope(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rotate(x, cos, sin):
-    """:func:`apply_rope` through the registry (``kernels.rope.picked``)."""
-    return registry.select("rope", x, cos, sin).fn(x, cos, sin)
+def apply_rope_half(x, cos, sin):
+    """``x [..., dim]`` with its half-split pairs ``(i, i + dim / 2)``
+    rotated; otherwise as :func:`apply_rope`."""
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+def rotate(x, cos, sin, pairs: str = "interleaved"):
+    """The rotation through the registry (``kernels.rope.picked``):
+    ``pairs`` ``"interleaved"`` (:func:`apply_rope`) or ``"half"``
+    (:func:`apply_rope_half`)."""
+    if pairs not in ("interleaved", "half"):
+        raise ValueError(f"pairs {pairs!r}: 'interleaved' or 'half'")
+    kw = {} if pairs == "interleaved" else {"pairs": pairs}
+    return registry.select("rope", x, cos, sin, **kw).fn(x, cos, sin)
 
 
 registry.define_kernel("rope")
-registry.register("rope", "lax_interleaved", apply_rope,
+registry.register("rope", "lax_interleaved", apply_rope, available=lambda x, cos, sin, pairs="interleaved": pairs == "interleaved",
                   doc="interleaved-pair rotation in jax.numpy, float32 inside (any device, any dtype)")
+registry.register("rope", "lax_half_split", apply_rope_half, available=lambda x, cos, sin, pairs="interleaved": pairs == "half",
+                  doc="half-split-pair rotation (rotate_half) in jax.numpy, float32 inside (any device, any dtype)")

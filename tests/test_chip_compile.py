@@ -631,6 +631,56 @@ def test_granite_moe_hybrid_programs_compile_for_v5e_at_the_cells_shapes(as_tpu,
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory.temp_size_in_bytes
 
 
+_EVA = "evabyte-6.5b.serve-bytegen"
+
+
+def _eva_lowered(one_chip):
+    """The EvaByte cell's programs: 16 slots x 32,768 bytes, chunk 1,024."""
+    return _share_lowered(_EVA, "evabyte", "EvaByte", "evabyte-6.5b.json", one_chip, 16, 32768)
+
+
+@pytest.mark.parametrize("program", ["decode_fn", "chunk_core", "chunk_final_core"])
+def test_evabyte_programs_compile_for_v5e_at_the_cells_shapes(as_tpu, one_chip, program):
+    """The programs of ``evabyte-6.5b.serve-bytegen`` (16 slots x 32,768 bytes, chunk 1,024, pipeline stage 0 at the
+    published widths, bf16, abstract arguments) compile for the described chip: the decode program's EVA core is one
+    ``eva_decode`` call a layer, scoped ``eva_core``, and no other operation makes, copies or re-lays-out a buffer the
+    shape of a ring or a table (a layer of one, or all of one: both are ``[16, 32, 2,048, 128]`` a layer) — so nothing
+    but the kernel, which streams the live tiles alone, reads one whole; the chunk programs read the ring and the table
+    a block at a time; every slot buffer is updated in place; and the temporaries sit beside the 11.83 GB held."""
+    cfg, decoder, lowered = _eva_lowered(one_chip)
+    B, S = 16, 32768
+    L, H, d = cfg.num_hidden_layers, cfg.num_attention_heads, cfg.head_dim
+    lowered, picked = lowered[program]
+    compiled = lowered.compile()
+    # one selection a kernel a set of shapes (the queries' and the keys' rotation share theirs); the final chunk's are
+    # the chunk's, selected when that was lowered
+    once = 0 if program == "chunk_final_core" else 1
+    want = {"kernels.rope.picked": once}
+    if program == "decode_fn":
+        want["kernels.eva_decode.picked"] = 1
+    assert {k: v for k, v in picked.items() if v} == {k: v for k, v in want.items() if v}
+    text = compiled.as_text()
+    held = sum(int(np.prod(spec.shape)) * jnp.dtype(spec.dtype).itemsize for spec in decoder.buffer_specs(B, S))
+    assert held == 16 * 536_870_912
+    memory = compiled.memory_analysis()
+    print(f"{_EVA} {program}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB; {picked}")
+    # 3.24 GB of weights + 8.59 GB of rings and tables; an intermediate chunk takes neither the final norm nor the head
+    assert 11.8e9 < memory.argument_size_in_bytes < 11.9e9
+    assert memory.alias_size_in_bytes >= held
+    touched = _cache_shaped_ops(text, L, B, H, 2048, d)
+    if program == "decode_fn":
+        eva = _custom_calls(text, "eva_decode")
+        assert len(eva) == L and all("/eva_core/" in line for line in eva), eva[:1]
+        assert touched.get("custom-call") == L and set(touched) <= _CACHE_MAY_PASS_THROUGH, touched
+        assert memory.temp_size_in_bytes < 0.2e9, memory.temp_size_in_bytes
+    else:
+        # each layer's rows and summaries go into the four buffers in place, and the blocked attention's loops carry them
+        assert touched.get("dynamic-update-slice") == 4 * L, touched
+        assert set(touched) <= _CACHE_MAY_PASS_THROUGH | {"dynamic-update-slice", "while"}, touched
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory.temp_size_in_bytes
+
+
 def test_engine_imports_no_private_function_of_a_model():
     import ast
     import inspect
