@@ -582,7 +582,10 @@ def _serve_block(lp, h, mix, *, num_heads):
     with jax.named_scope("norm"):
         x1 = _layer_norm(h, n1w, n1b)
     with jax.named_scope("attn_qkv"):
-        qkv = (x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, d // num_heads)
+        # the product is formed whole before it is cut into q, k, v and heads: XLA otherwise folds that split and the
+        # transpose into the matmul's output layout and re-lays the weight to match, so each layer's slice of the
+        # stacked qkv weight is copied out into VMEM every step (24 x 25 MB a decode step at width 2,048)
+        qkv = jax.lax.optimization_barrier(x1 @ qkvw + qkvb).reshape(b, s, 3, num_heads, d // num_heads)
         q = jnp.swapaxes(qkv[:, :, 0], 1, 2)  # [b, H, s, dh]
         k = jnp.swapaxes(qkv[:, :, 1], 1, 2)
         v = jnp.swapaxes(qkv[:, :, 2], 1, 2)

@@ -203,16 +203,15 @@ def _ops_of_result(hlo_text, dtype, dims):
     return found
 
 
-@pytest.mark.parametrize("cell", sorted(_DECODE))
-def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
-    """The decode forward (``_slot_decode_forward``, the body of the engine's
-    ``decode_fn``) at the two serving cells' shapes, bf16, cache donated,
-    abstract arguments only. It compiles for the described chip with one
-    ``decode_attn`` call a layer; its temporaries stay under a quarter of the
-    cache (the lax program: 1.2x and 1.3x); and nothing in the optimized
-    program but that call makes, copies, scatters into or re-lays-out a
-    buffer the shape of a cache layer or of the cache — at head size 64, where
-    the device stores S in the lanes, as at 128."""
+_DECODE_COMPILED = {}     # cell -> (the decode step compiled, the decode kernel's counters its lowering left)
+
+
+def _decode_step_compiled(cell, one_chip):
+    """The decode forward (``_slot_decode_forward``, the body of the engine's ``decode_fn``) and an argmax at a serving
+    cell's shapes, bf16, cache donated, abstract arguments only, compiled for the described chip once for this file's
+    tests."""
+    if cell in _DECODE_COMPILED:
+        return _DECODE_COMPILED[cell]
     from paddle_tpu.models.gpt import _slot_decode_forward
     from paddle_tpu.observability import metrics
 
@@ -232,7 +231,21 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
         stack, one_chip((L,), jnp.int32), one_chip((V, D), bf), one_chip((S, D), bf),
         one_chip((D,), bf), one_chip((D,), bf), one_chip((B,), jnp.int32), cache, cache,
         one_chip((B,), jnp.int32), one_chip((B,), jnp.bool_)).compile()
-    counts = metrics.counters("kernels.decode_attention.")
+    _DECODE_COMPILED[cell] = compiled, metrics.counters("kernels.decode_attention.")
+    return _DECODE_COMPILED[cell]
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE))
+def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
+    """The decode step (``_decode_step_compiled``) compiles for the described
+    chip with one ``decode_attn`` call a layer; its temporaries stay under a
+    quarter of the cache (the lax program: 1.2x and 1.3x); and nothing in the
+    optimized program but that call makes, copies, scatters into or
+    re-lays-out a buffer the shape of a cache layer or of the cache — at head
+    size 64, where the device stores S in the lanes, as at 128."""
+    L, B, H, S, D = (_DECODE[cell][k] for k in "LBHSD")
+    dh = D // H
+    compiled, counts = _decode_step_compiled(cell, one_chip)
     assert counts["kernels.decode_attention.picked"] == 1 and not counts.get("kernels.decode_attention.fallback")
     text = compiled.as_text()
     assert sum("custom-call(" in line and "decode_attn" in line for line in text.splitlines()) == L
@@ -243,6 +256,28 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
     touched = _cache_shaped_ops(text, L, B, H, S, dh)
     assert touched.get("custom-call") == L
     assert set(touched) <= _CACHE_MAY_PASS_THROUGH, touched
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE))
+def test_decode_step_reads_each_qkv_weight_where_it_lies_on_v5e(as_tpu, one_chip, cell):
+    """The decode step's qkv matmuls read each layer's slice of the stacked ``[L, D, 3D]`` weight where it lies in HBM.
+    Until the product was formed whole before its split into q, k, v and heads (``_serve_block``), XLA folded that split
+    and the transpose after it into the matmul's output layout and re-laid the weight to match: every layer's qkv weight
+    was sliced out into VMEM (``S(1)``) by multi-output fusions, slice-starts and ``ConcatBitcast`` calls and copied
+    again into ``[3D, D]``, every step (longgen: 24 x 25.2 MB). Now the one value a layer of
+    a qkv weight's shape is the stacked parameter's slice that the matmul's own fusion reads — a ``bitcast`` of the
+    slice inside the ``fusion`` that wraps it — in HBM, in the stored order; and at longgen's shapes the program moves
+    little more than its arguments (the parent: 1.65x)."""
+    L, D = _DECODE[cell]["L"], _DECODE[cell]["D"]
+    compiled, _ = _decode_step_compiled(cell, one_chip)
+    text = compiled.as_text()
+    assert _ops_of_result(text, "bf16", (3 * D, D)) == {}
+    assert _ops_of_result(text, "bf16", (D, 3 * D)) == {"bitcast": L, "fusion": L}
+    assert not re.search(rf"bf16\[{D},{3 * D}\]\{{[^}}]*S\(1\)", text)
+    assert "ConcatBitcast" not in text and " slice-start(" not in text
+    if cell == "cerebras-gpt-1.3b.serve-longgen":
+        moved = compiled.cost_analysis()["bytes accessed"]
+        assert moved <= 1.10 * compiled.memory_analysis().argument_size_in_bytes, moved
 
 
 # What each cell's programs lower to for the described chip: sha256 of the StableHLO text, and its lines. The GPT
@@ -282,18 +317,23 @@ def test_decode_step_touches_the_cache_once_on_v5e(as_tpu, one_chip, cell):
 # 96 and 99 lines a layer (2284 -> 2572, 2668 -> 2956; 3308 -> 3704, 3780 -> 4176). No other scope's count moved; both
 # cells' ``decode_fn`` (``delta_rule_step``), Granite's three programs, every GPT program and both training steps are
 # byte for byte what they were. ``test_a_delta_rule_layer_of_a_chunk_program_loops_...`` keeps the 64-trip loop out.
+# Last, the eight programs of the two GPT serving cells were re-pinned, and nothing else: ``_serve_block`` forms the qkv
+# product whole behind an ``optimization_barrier`` before it is cut into q, k, v and heads, so that XLA no longer folds
+# that split into the matmul's output layout and re-lays each layer's weight to match. ``attn_qkv`` gains the barrier,
+# one operation a layer (432 -> 456; ``chunk_core`` 429 -> 453), and each text 24 lines. No other scope's count moved;
+# every other program is byte for byte what it was.
 _PARENT_PROGRAMS = {
     "cerebras-gpt-1.3b.serve-longgen": {
-        "decode_fn": ("c1b2b9e757190693", 3277),
-        "chunk_core": ("a62eab5d420e6b87", 4747),
-        "chunk_final_core": ("bda3c0fbf1929d7c", 4988),
-        "prefill_core": ("d0d2618f83915cc7", 4575),
+        "decode_fn": ("b02a995193741a98", 3301),
+        "chunk_core": ("3c56facf54c91a1f", 4771),
+        "chunk_final_core": ("e7943466e024bb55", 5012),
+        "prefill_core": ("e80e0d81f8cd1f1d", 4599),
     },
     "gpt2-medium.serve-chat": {
-        "decode_fn": ("464871152afee3f3", 3421),
-        "chunk_core": ("72c1ac6ef53f24dc", 4747),
-        "chunk_final_core": ("83884956b5b900e0", 4988),
-        "prefill_core": ("8bdd3a355668f227", 4575),
+        "decode_fn": ("89699f9b67988ebf", 3445),
+        "chunk_core": ("a28049e1ee380dfb", 4771),
+        "chunk_final_core": ("c17038f0d7f5e4bc", 5012),
+        "prefill_core": ("f04111e91e471fed", 4599),
     },
     "solar-open2-250b.serve-reasoning": {
         "decode_fn": ("3aa7e33f2c334eb3", 1921),
@@ -326,16 +366,16 @@ _PARENT_PROGRAMS = {
 
 _PARENT_SCOPES = {
     "cerebras-gpt-1.3b.serve-longgen": {
-        "decode_fn": {"unscoped": 624, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
-        "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 429, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
-        "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
-        "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
+        "decode_fn": {"unscoped": 624, "embed": 3, "norm": 1221, "attn_qkv": 456, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
+        "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 453, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
+        "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 456, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
+        "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 456, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
     },
     "gpt2-medium.serve-chat": {
-        "decode_fn": {"unscoped": 768, "embed": 3, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
-        "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 429, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
-        "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
-        "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 432, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
+        "decode_fn": {"unscoped": 768, "embed": 3, "norm": 1221, "attn_qkv": 456, "attn_out": 216, "mlp": 720, "attn_core": 48, "head_loss": 1},
+        "chunk_core": {"unscoped": 916, "embed": 7, "norm": 1175, "attn_qkv": 453, "attn_out": 207, "mlp": 690, "cache_read": 280, "cache_write": 390, "attn_core": 644},
+        "chunk_final_core": {"unscoped": 1023, "embed": 7, "norm": 1223, "attn_qkv": 456, "attn_out": 216, "mlp": 720, "cache_read": 288, "cache_write": 390, "attn_core": 672, "head_loss": 3},
+        "prefill_core": {"unscoped": 952, "embed": 7, "norm": 1221, "attn_qkv": 456, "attn_out": 216, "mlp": 720, "cache_read": 96, "cache_write": 246, "attn_core": 672, "head_loss": 1},
     },
     "solar-open2-250b.serve-reasoning": {
         "decode_fn": {"unscoped": 563, "embed": 1, "norm": 169, "attn_qkv": 13, "attn_core": 2, "attn_out": 4, "moe_router": 68, "moe_routed": 560, "moe_shared": 48, "linear_proj": 102, "linear_core": 294, "linear_out": 57, "head_loss": 2},
@@ -823,7 +863,8 @@ _PROGRAMS = ([(cell, name) for cell in sorted(_DECODE) for name in ("decode_fn",
 def test_program_is_the_parents_by_fingerprint_and_by_scope(as_tpu, topo, one_chip, cell, program):
     """Each cell's program lowers to the text it lowered to at 094436e (the three hybrid cells': at PR 37, which
     changed the experts' way back to token order in all nine; Solar's and GigaChat's chunk programs: at PR 39, which
-    changed the chunkwise delta rule's solve), and the operations under each
+    changed the chunkwise delta rule's solve; the GPT serving cells': since ``_serve_block`` forms the qkv product whole
+    before its split), and the operations under each
     ``jax.named_scope`` the by-part metrics read are as many as they were: the hash does not see a scope's name, the
     metrics see nothing else. The four-chip cell's step is ``test_distributed_step``'s ``sharding2xmp2`` layout, the
     one-chip train cell's ``test_train_step``'s."""
